@@ -360,7 +360,9 @@ def bench_kernel_wavefront(full: bool = False):
     _emit("kernel/wavefront_ref", dt * 1e6, f"R={R};S={S};cells_per_s={cells/dt:.3g}")
 
     u = jnp.asarray([float(inst.u_turn)], l.dtype)
-    pf = lambda: ltsp_dp_tables(l[None], r[None], x[None], nl[None], u, S=S)
+    pf = lambda: ltsp_dp_tables(
+        l[None], r[None], x[None], nl[None], u, S=S, interpret=True
+    )
     T, _ = pf()  # compile (single trace: one retrace total, not R)
     t0 = time.perf_counter()
     T, C = pf()
@@ -456,15 +458,22 @@ def bench_hetero_batch(full: bool = False):
     B = len(insts)
     buckets = plan_buckets([rescale_instance(i)[0] for i in insts])
 
-    padded = ltsp_solve_batch(insts, bucketed=False)  # compile
-    bucketed = ltsp_solve_batch(insts, bucketed=True)  # compile (per bucket)
+    padded = ltsp_solve_batch(insts, interpret=True, bucketed=False)  # compile
+    # compile (per bucket)
+    bucketed = ltsp_solve_batch(insts, interpret=True, bucketed=True)
     assert padded == bucketed, "bucketing changed results"
     for inst, (cost, dets) in zip(insts, bucketed):
-        assert (cost, dets) == ltsp_solve_instance(inst), "batch != per-instance"
+        assert (cost, dets) == ltsp_solve_instance(inst, interpret=True), (
+            "batch != per-instance"
+        )
         assert cost == dp_schedule(inst)[0] == evaluate_detours(inst, dets)
 
-    dt_pad = _median_time(lambda: ltsp_solve_batch(insts, bucketed=False))
-    dt_buck = _median_time(lambda: ltsp_solve_batch(insts, bucketed=True))
+    dt_pad = _median_time(
+        lambda: ltsp_solve_batch(insts, interpret=True, bucketed=False)
+    )
+    dt_buck = _median_time(
+        lambda: ltsp_solve_batch(insts, interpret=True, bucketed=True)
+    )
     speedup = dt_pad / dt_buck
     _emit("solver/hetero_padded", dt_pad * 1e6 / B, f"B={B};R_max={max(i.n_req for i in insts)}")
     _emit(

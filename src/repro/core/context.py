@@ -22,7 +22,8 @@ a dozen signatures.  :class:`ExecutionContext` bundles all of it:
 * ``numeric_policy`` — what to do when an instance fails the int32 device
   magnitude guard *after* gcd/shift rescaling: ``"strict"`` raises (default),
   ``"f64"`` falls back to an exact float64 interpret-mode table for just the
-  failing instances (exact while every table value stays below 2**53);
+  failing instances (exact while every table value stays below 2**53;
+  under ``backend="pallas"`` such an instance raises instead);
 * ``budget`` — an optional :class:`ComputeBudget` making solver compute a
   *priced* resource for the serving loop: how much virtual time one DP cell
   costs (so dispatches charge their solve work into the timeline), the

@@ -25,7 +25,9 @@ Three numeric modes:
   exactly representable, so within :func:`_check_f64_safe`'s bound the
   result is still bit-identical to the python DP; beyond it the guard raises
   either way.  Selected via ``ExecutionContext.numeric_policy``; the default
-  ``"strict"`` keeps the old raise.
+  ``"strict"`` keeps the old raise.  A compiled (``interpret=False``) solve
+  that would need the fallback raises instead of quietly running the
+  interpreter.
 * ``float32`` (oracle-comparison default, exact for values < 2**24) — used by
   the seed-compatible :func:`ltsp_dp_table`/:func:`ltsp_opt` wrappers that the
   kernel tests diff against :mod:`.ref`.
@@ -71,6 +73,7 @@ from __future__ import annotations
 import math
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -251,6 +254,26 @@ def _check_int32_safe(instances: list[Instance]) -> None:
             )
 
 
+def _guard(scaled: list[Instance], numeric_policy: str, interpret: bool) -> list[int]:
+    """Run the numeric-policy magnitude guards over rescaled instances and
+    return the indices that need the float64 route (empty unless
+    ``numeric_policy="f64"``).  The float64 route exists only in the
+    interpreter, so a compiled solve that would need it raises."""
+    if numeric_policy != "f64":
+        _check_int32_safe(scaled)
+        return []
+    wide = [i for i, s in enumerate(scaled) if _table_bound(s) >= 2**31]
+    _check_f64_safe([scaled[i] for i in wide])
+    if wide and not interpret:
+        s = scaled[wide[0]]
+        raise ValueError(
+            f"instance needs the float64 route (m={s.m}, n={s.n}, "
+            f"R={s.n_req}), which runs only in the interpreter: use "
+            f"backend='pallas-interpret' or backend='python'"
+        )
+    return wide
+
+
 def _check_f64_safe(instances: list[Instance]) -> None:
     """Exactness-domain guard for the float64 fallback (< 2**53)."""
     for inst in instances:
@@ -294,7 +317,8 @@ def traceback_detours(choice: np.ndarray, mult: np.ndarray) -> list[tuple[int, i
 def ltsp_solve_instance(
     inst: Instance,
     span: int | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     cand_tile: int = DEFAULT_CAND_TILE,
     disjoint: bool = False,
     numeric_policy: str = "strict",
@@ -363,7 +387,8 @@ def _solve_packed(
 def ltsp_solve_batch(
     instances: list[Instance],
     span: int | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     bucketed: bool = True,
     cand_tile: int = DEFAULT_CAND_TILE,
     disjoint: bool = False,
@@ -384,10 +409,14 @@ def ltsp_solve_batch(
     reproduces the seed behaviour (every instance padded to the global batch
     maxima, one launch) and exists for A/B benchmarking.
 
+    ``interpret`` has no default: ``False`` runs the compiled Mosaic kernel,
+    ``True`` the Pallas interpreter.
+
     ``numeric_policy="f64"`` re-routes the (rare) instances that fail the
     int32 magnitude guard after gcd/shift rescaling through an exact float64
     **interpret** table instead of raising (see the module docstring); the
-    int32-safe majority still takes the int32 launches unchanged.
+    int32-safe majority still takes the int32 launches unchanged.  With
+    ``interpret=False`` such an instance raises before anything runs.
 
     ``capture=True`` changes the return to ``(results, stores)`` where
     ``stores[i]`` is a :class:`~repro.core.warm.DenseStore` snapshot of
@@ -406,19 +435,13 @@ def ltsp_solve_batch(
     pairs = [rescale_instance(inst) for inst in instances]
     scaled = [p[0] for p in pairs]
     gs = [p[1] for p in pairs]
-    if numeric_policy == "f64":
-        wide = [i for i, s in enumerate(scaled) if _table_bound(s) >= 2**31]
-        _check_f64_safe([scaled[i] for i in wide])
-    else:
-        wide = []
-        _check_int32_safe(scaled)
+    wide = _guard(scaled, numeric_policy, interpret)
     wide_set = set(wide)
     narrow = [i for i in range(len(instances)) if i not in wide_set]
 
     stores: list[DenseStore | None] = [None] * len(instances)
 
-    def solve(idxs, R_pad, S_pad, B_pad, dtype=jnp.int32, interp=None):
-        interp_eff = interpret if interp is None else interp
+    def solve(idxs, R_pad, S_pad, B_pad, dtype=jnp.int32):
         t0 = (
             time.perf_counter_ns()
             if profile is not None and profile.wall
@@ -429,7 +452,7 @@ def ltsp_solve_batch(
             [scaled[i] for i in idxs],
             [gs[i] for i in idxs],
             R_pad, S_pad, B_pad, span,
-            interp_eff, cand_tile,
+            interpret, cand_tile,
             disjoint=disjoint, dtype=dtype, capture=capture,
         )
         for i, st in zip(idxs, subs):
@@ -443,7 +466,7 @@ def ltsp_solve_batch(
             S_eff = _pad_s(max(s.n for s in sub) + 1 if S_pad is None else S_pad)
             profile.record(
                 signature=(
-                    R_eff, S_eff, B_eff, np.dtype(dtype).name, interp_eff,
+                    R_eff, S_eff, B_eff, np.dtype(dtype).name, interpret,
                     span, disjoint, cand_tile,
                 ),
                 n_instances=len(sub),
@@ -451,7 +474,7 @@ def ltsp_solve_batch(
                 S_pad=S_eff,
                 B_pad=B_eff,
                 real_cells=sum(s.n_req * s.n_req * (s.n + 1) for s in sub),
-                interpret=interp_eff,
+                interpret=interpret,
                 wall_ns=(
                     time.perf_counter_ns() - t0 if t0 is not None else None
                 ),
@@ -464,17 +487,13 @@ def ltsp_solve_batch(
     results: list[tuple[int, list[tuple[int, int]]] | None] = [None] * len(instances)
     if wide:
         # float64 is a correctness escape hatch for coprime byte-scale
-        # layouts, not a throughput path: interpret mode, one tight launch
-        # per instance, under a scoped x64 context (never enabled globally).
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        # layouts, not a throughput path: interpret mode (_guard refuses it
+        # compiled), one tight launch per instance, under a scoped x64
+        # context (never enabled globally).
+        with jax.enable_x64(True):
             for i in wide:
                 R_pad, S_pad = bucket_shape(scaled[i])
-                # interp=True: f64 is emulated on TPU, never compiled
-                [results[i]] = solve(
-                    [i], R_pad, S_pad, None, dtype=jnp.float64, interp=True
-                )
+                [results[i]] = solve([i], R_pad, S_pad, None, dtype=jnp.float64)
     if not narrow:
         return done(results)  # type: ignore[return-value]
     if not bucketed:  # seed behaviour: one launch padded to the batch maxima
@@ -500,7 +519,8 @@ def ltsp_solve_instance_warm(
     inst: Instance,
     span: int | None = None,
     warm: WarmState | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     cand_tile: int = DEFAULT_CAND_TILE,
     numeric_policy: str = "strict",
     profile=None,
@@ -518,7 +538,8 @@ def ltsp_solve_batch_warm(
     instances: list[Instance],
     warms: list[WarmState | None] | None = None,
     span: int | None = None,
-    interpret: bool = True,
+    *,
+    interpret: bool,
     bucketed: bool = True,
     cand_tile: int = DEFAULT_CAND_TILE,
     numeric_policy: str = "strict",
@@ -556,10 +577,7 @@ def ltsp_solve_batch_warm(
     # same guard discipline as the cold path (before any solving: a batch
     # never fails mid-flight)
     scaled = [rescale_instance(inst)[0] for inst in instances]
-    if numeric_policy == "f64":
-        _check_f64_safe([s for s in scaled if _table_bound(s) >= 2**31])
-    else:
-        _check_int32_safe(scaled)
+    _guard(scaled, numeric_policy, interpret)
 
     from ...core.dp import dp_schedule_warm
 
@@ -594,9 +612,7 @@ def ltsp_solve_batch_warm(
 # ---------------------------------------------------------------------------
 # value-only f32 wrappers (seed-compatible API, diffed against ref.py)
 # ---------------------------------------------------------------------------
-def ltsp_dp_table(
-    left, right, x, nl, u_turn: float, S: int, interpret: bool = True
-):
+def ltsp_dp_table(left, right, x, nl, u_turn: float, S: int, *, interpret: bool):
     """Dense single-instance DP table (f32) via the single-trace wavefront."""
     dtype = left.dtype
     T, _ = ltsp_dp_tables(
@@ -612,7 +628,7 @@ def ltsp_dp_table(
 
 
 def ltsp_opt(
-    left, right, x, nl, u_turn: float, m: float, S: int, interpret: bool = True
+    left, right, x, nl, u_turn: float, m: float, S: int, *, interpret: bool
 ):
     """Optimal LTSP objective (float): ``T[0, R-1, 0] + VirtualLB``."""
     T = ltsp_dp_table(left, right, x, nl, u_turn, S, interpret=interpret)
@@ -620,7 +636,7 @@ def ltsp_opt(
     return T[0, left.shape[0] - 1, 0] + virt
 
 
-def ltsp_opt_instance(inst: Instance, interpret: bool = True) -> float:
+def ltsp_opt_instance(inst: Instance, *, interpret: bool) -> float:
     """Convenience: exact-instance adapter (f32; exact for coords < 2**20)."""
     left, right, x, nl, S = prepare_arrays(inst)
     val = ltsp_opt(

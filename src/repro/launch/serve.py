@@ -140,6 +140,7 @@ from ..core.solver import (
 )
 from ..distributed.context import set_active_mesh
 from ..distributed.sharding import cache_pspecs, param_pspecs, to_shardings
+from ..kernels.compile_cache import enable_compile_cache
 from ..models.model import init_cache, init_model
 from ..serving.serve import make_serve_step
 from .train import _auto_mesh
@@ -685,6 +686,7 @@ def main() -> None:
     ap.add_argument("--tape-files", type=int, default=40)
     ap.add_argument("--tape-seed", type=int, default=20260731)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.serve_tape_queue:
         raise SystemExit(_serve_tape_queue(args))

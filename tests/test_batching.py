@@ -54,10 +54,10 @@ def test_bucketed_batch_bit_identical_to_per_instance_50_instances():
     assert len({i.n_req for i in insts}) > 5  # genuinely heterogeneous
     assert sum(i.u_turn > 0 for i in insts) >= 10
 
-    batched = ltsp_solve_batch(insts)
+    batched = ltsp_solve_batch(insts, interpret=True)
     assert len(plan_buckets([rescale_instance(i)[0] for i in insts])) >= 2
     for trial, (inst, (cost, dets)) in enumerate(zip(insts, batched)):
-        solo = ltsp_solve_instance(inst)
+        solo = ltsp_solve_instance(inst, interpret=True)
         assert (cost, dets) == solo, trial
         assert cost == dp_schedule(inst)[0], trial
         assert evaluate_detours(inst, dets) == cost, trial
@@ -65,8 +65,8 @@ def test_bucketed_batch_bit_identical_to_per_instance_50_instances():
 
 def test_bucketed_matches_seed_style_padded_launch(rng):
     insts = [_hetero_instance(rng) for _ in range(8)]
-    assert ltsp_solve_batch(insts, bucketed=True) == ltsp_solve_batch(
-        insts, bucketed=False
+    assert ltsp_solve_batch(insts, interpret=True, bucketed=True) == (
+        ltsp_solve_batch(insts, interpret=True, bucketed=False)
     )
 
 
@@ -82,7 +82,7 @@ def test_solver_engine_batch_goes_through_buckets(rng):
 # fast paths: empty and single-instance batches
 # ---------------------------------------------------------------------------
 def test_empty_batch_returns_empty():
-    assert ltsp_solve_batch([]) == []
+    assert ltsp_solve_batch([], interpret=True) == []
     assert solve_batch([], policy="dp", context=DEV) == []
     assert solve_batch([], policy="gs") == []
 
@@ -192,6 +192,17 @@ def test_f64_fallback_only_reroutes_guard_failures(rng):
     assert res[1].cost == dp_schedule(bad)[0]
     # the scoped x64 context never leaks into global jax state
     assert not jax.config.jax_enable_x64
+
+
+def test_f64_route_refused_by_compiled_backend(rng):
+    """The float64 route runs only in the interpreter: a compiled solve that
+    would need it raises before any launch, naming the two backends that can
+    run it, instead of quietly running the interpreter."""
+    batch = [_hetero_instance(rng), _coprime_instance()]
+    compiled = ExecutionContext(backend="pallas", numeric_policy="f64")
+    with pytest.raises(ValueError, match="pallas-interpret") as ei:
+        solve_batch(batch, policy="dp", context=compiled)
+    assert "python" in str(ei.value)
 
 
 def test_f64_guard_rejects_beyond_exactness_domain():

@@ -35,8 +35,8 @@ from repro.distributed.sharding import (
 from repro.launch.specs import abstract_params, input_specs
 from repro.configs.base import SHAPES
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_size(s, mesh):

@@ -35,7 +35,7 @@ def test_kernel_opt_equals_exact_dp(seed):
     rng = np.random.default_rng(seed)
     inst = _small_instance(rng, int(rng.integers(2, 10)))
     opt_exact, _ = dp_schedule(inst)
-    assert ltsp_opt_instance(inst) == float(opt_exact)
+    assert ltsp_opt_instance(inst, interpret=True) == float(opt_exact)
 
 
 def test_ref_opt_equals_exact_dp(rng):
@@ -59,8 +59,10 @@ def test_kernel_banded_scan_matches_full_tile(rng, cand_tile):
     u = jnp.asarray([float(inst.u_turn)], l.dtype)
     args = (l[None], r[None], x[None], nl[None], u)
     for span in (None, 3):
-        T_full, C_full = ltsp_dp_tables(*args, S=S, span=span)
-        T_band, C_band = ltsp_dp_tables(*args, S=S, span=span, cand_tile=cand_tile)
+        T_full, C_full = ltsp_dp_tables(*args, S=S, span=span, interpret=True)
+        T_band, C_band = ltsp_dp_tables(
+            *args, S=S, span=span, cand_tile=cand_tile, interpret=True
+        )
         np.testing.assert_array_equal(np.asarray(T_band), np.asarray(T_full))
         np.testing.assert_array_equal(np.asarray(C_band), np.asarray(C_full))
 
@@ -77,3 +79,71 @@ def test_kernel_s_padding_invariance(rng):
     np.testing.assert_array_equal(
         np.asarray(T1[..., : n + 1]), np.asarray(T2[..., : n + 1])
     )
+
+
+@pytest.mark.parametrize("cand_tile", [8, 16])
+def test_banded_scan_unaligned_band_starts_match_python_dp(cand_tile):
+    """R - 1 > cand_tile: chunk bases align down to 8 sublanes while the live
+    band starts at c = a + 1 (and at b - span under LOGDP), mostly unaligned.
+    The masked overhang must change neither the value nor the smallest-c
+    tie-break: tables equal the single-tile path, (cost, detours) the exact
+    python DP."""
+    import jax.numpy as jnp
+
+    from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+    from repro.kernels.ltsp_dp.ops import ltsp_solve_instance, prepare_batch
+
+    rng = np.random.default_rng(20261016)
+    inst = _small_instance(rng, 37)
+    left, right, x, nl, u, S = prepare_batch([inst], dtype=jnp.int32)
+    for span in (None, 11):
+        kw = dict(S=S, span=span, interpret=True)
+        T_one, C_one = ltsp_dp_tables(left, right, x, nl, u, **kw)
+        T_band, C_band = ltsp_dp_tables(left, right, x, nl, u, cand_tile=cand_tile, **kw)
+        np.testing.assert_array_equal(np.asarray(T_band), np.asarray(T_one))
+        np.testing.assert_array_equal(np.asarray(C_band), np.asarray(C_one))
+        assert ltsp_solve_instance(
+            inst, span=span, cand_tile=cand_tile, interpret=True
+        ) == dp_schedule(inst, span=span)
+
+
+@pytest.mark.parametrize("R, cand_tile", [(37, 8), (32, 12)])
+def test_compiled_banded_scan_refuses_unaligned_chunks(R, cand_tile):
+    """The compiled banded scan tells Mosaic its chunk bases are multiples of
+    8; a shape where that would not hold raises before anything is lowered."""
+    import jax.numpy as jnp
+
+    from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+
+    vec = jnp.zeros((1, R), jnp.int32)
+    with pytest.raises(ValueError, match="multiples of 8"):
+        ltsp_dp_tables(
+            vec, vec, vec, vec, jnp.zeros((1,), jnp.int32),
+            S=128, interpret=False, cand_tile=cand_tile,
+        )
+
+
+def test_skip_shift_clamps_at_last_skip_count():
+    """The skip term reads T[a, b-1, min(s + x_b, S-1)].  With multiplicities
+    large next to S, s + x_b passes S - 1 on many cells, where a wrapped
+    rotation would read T[a, b-1, s + x_b - S] instead: the kernel table must
+    equal the reference's clamped one everywhere, unreachable cells included."""
+    inst = make_instance(
+        [0, 7, 15, 30, 41], [5, 6, 9, 8, 3], [40, 1, 50, 2, 30], m=50, u_turn=3
+    )
+    l, r, x, nl, S = prepare_arrays(inst)
+    assert S == 128 and inst.n == 123
+    T_kernel = np.asarray(ltsp_dp_table(l, r, x, nl, float(inst.u_turn), S, interpret=True))
+    T_ref = np.asarray(ltsp_dp_table_ref(l, r, x, nl, float(inst.u_turn), S))
+    np.testing.assert_array_equal(T_kernel, T_ref)
+    # the clamp matters: somewhere the clamped and the wrapped reads differ
+    s = np.arange(S)
+    differs = False
+    for b in range(1, inst.n_req):
+        xb = int(inst.mult[b])
+        for a in range(b):
+            row = T_ref[a, b - 1]
+            differs |= bool(
+                np.any(row[np.minimum(s + xb, S - 1)] != row[(s + xb) % S])
+            )
+    assert differs

@@ -1,0 +1,74 @@
+"""Ahead-of-time compiles of the compiled wavefront for a described v5e chip.
+
+No chip is needed: the TPU compiler compiles ``ltsp_dp_tables(...,
+interpret=False)`` against a described ``v5e:2x2`` topology and refuses what
+the chip would refuse (illegal block shapes, unaligned dynamic slices, scoped
+VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
+median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
+disjoint variants, and the banded scan at a 16-row candidate tile.  Each
+program must fit the chip's 16 GB of HBM.
+
+The topology is described inside a module fixture, never at import time, so
+every test worker collects the same tests and only the worker that runs this
+file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+
+#: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the persistent
+    # cache without one; keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize(
+    "B, R, S, kw",
+    [
+        pytest.param(1, 256, 4096, {}, id="paper-median-B1-R256-S4096"),
+        pytest.param(4, 8, 128, {}, id="serving-B4-R8-S128"),
+        pytest.param(2, 64, 2048, {}, id="small-B2-R64-S2048"),
+        pytest.param(2, 64, 2048, {"cand_tile": 16}, id="banded-tile16-B2-R64"),
+        pytest.param(2, 32, 1024, {"span": 5}, id="logdp-span5-B2-R32"),
+        pytest.param(2, 64, 1024, {"disjoint": True}, id="simpledp-disjoint-B2-R64"),
+        pytest.param(1, 256, 4096, {"span": 40}, id="logdp-span40-B1-R256"),
+    ],
+)
+def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    args = [spec((B, R))] * 4 + [spec((B,))]
+    compiled = ltsp_dp_tables.lower(*args, S=S, interpret=False, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    used = (
+        ma.argument_size_in_bytes
+        + ma.output_size_in_bytes
+        + ma.temp_size_in_bytes
+        - ma.alias_size_in_bytes
+    )
+    # the outputs alone are T and C, B*R*R*S int32 each
+    assert ma.output_size_in_bytes >= 2 * B * R * R * S * 4
+    assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one v5e"
