@@ -1,0 +1,4 @@
+"""Share of the wavefront's device time that its recurrence's bytes need at
+the chip's HBM peak, in % (device trace, peaks.json)."""
+
+from layers import wavefront_roofline as read  # noqa: F401
