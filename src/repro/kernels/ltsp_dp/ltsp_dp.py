@@ -327,6 +327,7 @@ def ltsp_dp_wavefront(
             jax.ShapeDtypeStruct((B, R, 1, S), jnp.int32),
         ],
         interpret=interpret,
+        name="ltsp_wavefront",
         **kwargs,
     )(
         jnp.asarray(d, jnp.int32).reshape(1),
@@ -394,9 +395,10 @@ def ltsp_dp_tables(
             S=S, span=span, disjoint=disjoint, interpret=interpret,
             cand_tile=cand_tile,
         )
-        T = T.at[:, rr, rr + d, :].set(vals, mode="drop")
-        Tc = Tc.at[:, rr[1:] + d, rr[1:] - 1, :].set(vals[:, 1:], mode="drop")
-        C = C.at[:, rr, rr + d, :].set(chos, mode="drop")
+        with jax.named_scope("ltsp_scatter"):
+            T = T.at[:, rr, rr + d, :].set(vals, mode="drop")
+            Tc = Tc.at[:, rr[1:] + d, rr[1:] - 1, :].set(vals[:, 1:], mode="drop")
+            C = C.at[:, rr, rr + d, :].set(chos, mode="drop")
         return T, Tc, C
 
     T, _, C = jax.lax.fori_loop(1, R, body, (T, Tc, C))

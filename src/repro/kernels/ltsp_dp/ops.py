@@ -70,8 +70,8 @@ planner entirely (one tight launch, no grouping pass).
 
 from __future__ import annotations
 
+import contextlib
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +96,16 @@ __all__ = [
     "ltsp_solve_instance_warm",
     "ltsp_solve_batch_warm",
 ]
+
+
+#: the context every phase opens when no profile is attached: one shared
+#: object, so an unprofiled solve builds no span
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(profile, name: str, **args):
+    """``profile.span(name, **args)``, or :data:`_NO_SPAN` without a profile."""
+    return _NO_SPAN if profile is None else profile.span(name, **args)
 
 
 def _pad_s(S: int) -> int:
@@ -343,6 +353,7 @@ def _solve_packed(
     disjoint: bool = False,
     dtype=jnp.int32,
     capture: bool = False,
+    profile=None,
 ) -> tuple[list[tuple[int, list[tuple[int, int]]]], list[DenseStore | None]]:
     """One padded device launch; results refer to the *original* instances.
 
@@ -350,37 +361,72 @@ def _solve_packed(
     argmin planes into a :class:`~repro.core.warm.DenseStore` (kept in the
     launch's gcd-rescaled units together with ``g``, so lookups reconstruct
     original-unit values with python-int arithmetic).
+
+    With a ``profile`` the launch is recorded, and each phase runs inside
+    its span (see :class:`~repro.obs.KernelProfile`): ``ltsp.pack``,
+    ``ltsp.dispatch`` (returns before the device finishes; a cold shape
+    traces and compiles here), ``ltsp.device_wait``, ``ltsp.fetch_argmin``,
+    ``ltsp.fetch_root``, ``ltsp.traceback`` and ``ltsp.release``, where the
+    tables' last references go, so that freeing them is timed too.
     """
-    left, right, x, nl, u, S = prepare_batch(
-        scaled, dtype=dtype, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad
-    )
-    T, C = ltsp_dp_tables(
-        left, right, x, nl, u, S=S, span=span, disjoint=disjoint,
-        interpret=interpret, cand_tile=cand_tile,
-    )
-    R = left.shape[1]
-    C_host = np.asarray(C)
-    T_root = np.asarray(T[:, 0, R - 1, 0])
-    T_host = np.asarray(T) if capture else None
-    out = []
-    stores: list[DenseStore | None] = []
-    x_host = np.asarray(x)
-    for i, (inst, g) in enumerate(zip(originals, gs)):
-        dets = traceback_detours(C_host[i], x_host[i])
-        # padding only ever skips, so emitted detours stay within the real
-        # files; guard the invariant anyway.
-        assert all(b < inst.n_req for _, b in dets)
-        # the scaled table is exactly 1/g of the original's (see
-        # rescale_instance); VirtualLB comes from the original coordinates.
-        cost = g * int(T_root[i]) + virtual_lb(inst)
-        out.append((cost, dets))
+    with _span(profile, "ltsp.pack"):
+        left, right, x, nl, u, S = prepare_batch(
+            scaled, dtype=dtype, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad
+        )
+    B, R = left.shape
+    with _span(profile, "ltsp.dispatch", R=R, S=S, B=B, span=span):
+        T, C = ltsp_dp_tables(
+            left, right, x, nl, u, S=S, span=span, disjoint=disjoint,
+            interpret=interpret, cand_tile=cand_tile,
+        )
+    with _span(profile, "ltsp.device_wait"):
+        C.block_until_ready()
+    with _span(profile, "ltsp.fetch_argmin"):
+        C_host = np.asarray(C)
+        T_host = np.asarray(T) if capture else None
+    with _span(profile, "ltsp.fetch_root"):
+        T_root = np.asarray(T[:, 0, R - 1, 0])
+        x_host = np.asarray(x)
+    with _span(profile, "ltsp.traceback"):
+        out = []
+        stores: list[DenseStore | None] = []
+        for i, (inst, g) in enumerate(zip(originals, gs)):
+            dets = traceback_detours(C_host[i], x_host[i])
+            # padding only ever skips, so emitted detours stay within the
+            # real files; guard the invariant anyway.
+            assert all(b < inst.n_req for _, b in dets)
+            # the scaled table is exactly 1/g of the original's (see
+            # rescale_instance); VirtualLB comes from the original coordinates.
+            cost = g * int(T_root[i]) + virtual_lb(inst)
+            out.append((cost, dets))
+            if capture:
+                prefix = np.cumsum(inst.mult).tolist()
+                stores.append(
+                    DenseStore(T_host[i].copy(), C_host[i].copy(), g, inst.n, prefix)
+                )
+            else:
+                stores.append(None)
+    if profile is not None:
+        h2d = sum(a.nbytes for a in (left, right, x, nl, u))
+        d2h = C_host.nbytes + T_root.nbytes + x_host.nbytes
         if capture:
-            prefix = np.cumsum(inst.mult).tolist()
-            stores.append(
-                DenseStore(T_host[i].copy(), C_host[i].copy(), g, inst.n, prefix)
-            )
-        else:
-            stores.append(None)
+            d2h += T_host.nbytes
+    with _span(profile, "ltsp.release"):
+        del C_host, T_host, T, C
+    if profile is not None:
+        profile.record(
+            signature=(
+                R, S, B, np.dtype(dtype).name, interpret, span, disjoint, cand_tile,
+            ),
+            n_instances=len(scaled),
+            R_pad=R,
+            S_pad=S,
+            B_pad=B,
+            real_cells=sum(s.n_req * s.n_req * (s.n + 1) for s in scaled),
+            interpret=interpret,
+            h2d_bytes=h2d,
+            d2h_bytes=d2h,
+        )
     return out, stores
 
 
@@ -427,58 +473,34 @@ def ltsp_solve_batch(
     ``profile`` takes an optional :class:`~repro.obs.KernelProfile`: every
     device launch records its padded bucket shape, the exact
     real-vs-padded DP cell counts, whether its jit signature was cold, and
-    (when the profile captures wall time) the host wall time around the
-    launch — pure host-side accounting, results unchanged.
+    the exact bytes it moved each way; every phase opens a profiler span
+    (``ltsp.rescale`` and ``ltsp.guard`` here, the launch's own in
+    :func:`_solve_packed`) — host-side accounting, results unchanged.
     """
     if not instances:
         return ([], []) if capture else []
-    pairs = [rescale_instance(inst) for inst in instances]
-    scaled = [p[0] for p in pairs]
-    gs = [p[1] for p in pairs]
-    wide = _guard(scaled, numeric_policy, interpret)
+    with _span(profile, "ltsp.rescale"):
+        pairs = [rescale_instance(inst) for inst in instances]
+        scaled = [p[0] for p in pairs]
+        gs = [p[1] for p in pairs]
+    with _span(profile, "ltsp.guard"):
+        wide = _guard(scaled, numeric_policy, interpret)
     wide_set = set(wide)
     narrow = [i for i in range(len(instances)) if i not in wide_set]
 
     stores: list[DenseStore | None] = [None] * len(instances)
 
     def solve(idxs, R_pad, S_pad, B_pad, dtype=jnp.int32):
-        t0 = (
-            time.perf_counter_ns()
-            if profile is not None and profile.wall
-            else None
-        )
         out, subs = _solve_packed(
             [instances[i] for i in idxs],
             [scaled[i] for i in idxs],
             [gs[i] for i in idxs],
             R_pad, S_pad, B_pad, span,
             interpret, cand_tile,
-            disjoint=disjoint, dtype=dtype, capture=capture,
+            disjoint=disjoint, dtype=dtype, capture=capture, profile=profile,
         )
         for i, st in zip(idxs, subs):
             stores[i] = st
-        if profile is not None:
-            # mirror prepare_batch's padding defaults so the record reports
-            # the launch shape that actually ran
-            sub = [scaled[i] for i in idxs]
-            B_eff = len(sub) if B_pad is None else max(B_pad, len(sub))
-            R_eff = max(s.n_req for s in sub) if R_pad is None else R_pad
-            S_eff = _pad_s(max(s.n for s in sub) + 1 if S_pad is None else S_pad)
-            profile.record(
-                signature=(
-                    R_eff, S_eff, B_eff, np.dtype(dtype).name, interpret,
-                    span, disjoint, cand_tile,
-                ),
-                n_instances=len(sub),
-                R_pad=R_eff,
-                S_pad=S_eff,
-                B_pad=B_eff,
-                real_cells=sum(s.n_req * s.n_req * (s.n + 1) for s in sub),
-                interpret=interpret,
-                wall_ns=(
-                    time.perf_counter_ns() - t0 if t0 is not None else None
-                ),
-            )
         return out
 
     def done(results):

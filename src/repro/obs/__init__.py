@@ -25,8 +25,9 @@ through :class:`~repro.core.context.ExecutionContext` (``obs=`` field,
   depth, batch dispatches, retry backoff, fault events), and the fleet
   (routing, re-routes, outages, per-shard rollups).
 * :class:`~repro.obs.kernel.KernelProfile` — per-launch device records:
-  bucket shape, exact real-vs-padded cell counts (padding waste), and
-  compile-vs-execute wall time.
+  bucket shape, exact real-vs-padded cell counts (padding waste), exact
+  bytes moved each way, and the device path's ``ltsp.*`` phase spans,
+  written into any running ``jax.profiler`` session on the device's clock.
 * :mod:`~repro.obs.export` — a byte-deterministic JSONL span log, a
   Prometheus text snapshot whose integers match the report types exactly,
   and a Chrome ``trace_event`` JSON (one lane per drive, one process per
@@ -88,11 +89,11 @@ class Observability:
 
     @classmethod
     def enabled(cls, *, wall: bool = False) -> "Observability":
-        """A fully-armed bundle (wall-clock span stamps opt-in)."""
+        """A fully-armed bundle (the tracer's wall-clock stamps opt-in)."""
         return cls(
             tracer=Tracer(wall=wall),
             metrics=MetricsRegistry(),
-            kernel=KernelProfile(wall=wall),
+            kernel=KernelProfile(),
         )
 
     # -- no-op-safe recorders ----------------------------------------------

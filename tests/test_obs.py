@@ -198,7 +198,7 @@ def test_empty_bundle_recorders_are_noop_safe():
     obs.observe("h", 1)
     armed = Observability.enabled()
     assert armed.tracer is not None and armed.metrics is not None
-    assert armed.kernel is not None and not armed.kernel.wall
+    assert armed.kernel is not None
     armed.inc("c", 2)
     assert armed.metrics.counter("c") == 2
 
@@ -412,22 +412,23 @@ def test_kernel_profile_cold_vs_warm_and_waste():
     prof = KernelProfile(wall=False)
     sig = (4, 8, 2, "int32", True, 0, False, None)
     prof.record(signature=sig, n_instances=2, R_pad=4, S_pad=8, B_pad=2,
-                real_cells=100, interpret=True)
+                real_cells=100, interpret=True, h2d_bytes=136, d2h_bytes=1064)
     prof.record(signature=sig, n_instances=1, R_pad=4, S_pad=8, B_pad=2,
-                real_cells=40, interpret=True)
+                real_cells=40, interpret=True, h2d_bytes=136, d2h_bytes=1064)
     first, second = prof.launches
     assert first.cold and not second.cold  # same signature: compiled once
     assert first.padded_cells == 2 * 4 * 4 * 8 == 256
     assert first.waste == (156, 256)  # exact fraction, no floats
-    assert first.wall_ns is None
+    assert (first.h2d_bytes, first.d2h_bytes) == (136, 1064)
     s = prof.summary()
     assert s["n_launches"] == 2 and s["n_cold"] == 1
     assert s["real_cells"] == 140 and s["padded_cells"] == 512
     assert s["wasted_cells"] == 512 - 140
+    assert (s["h2d_bytes"], s["d2h_bytes"]) == (2 * 136, 2 * 1064)
 
 
 def test_kernel_profile_captures_device_launches():
-    obs = Observability.enabled(wall=True)  # compile/execute wall is opt-in
+    obs = Observability.enabled()
     lib = build_library()
     report = serve_trace(
         lib, build_trace(40), "batched", window=400_000, policy="dp",
@@ -442,7 +443,12 @@ def test_kernel_profile_captures_device_launches():
         wasted, padded = rec.waste  # exact fraction (wasted, padded)
         assert 0 <= wasted < padded
         assert rec.interpret
-        assert isinstance(rec.wall_ns, int) and rec.wall_ns > 0
+        # int32 packed arrays in; the argmin plane (and the value table when
+        # a warm start captures it), the roots and multiplicities out
+        B, R = rec.B_pad, rec.R_pad
+        assert rec.h2d_bytes == 4 * (4 * B * R + B)
+        assert rec.d2h_bytes - 4 * (B + B * R) in (
+            4 * rec.padded_cells, 8 * rec.padded_cells)
     assert prof.summary()["n_instances"] >= len(prof.launches)
     # a cold launch (first of its bucket signature) pays compilation; re-use
     # of the same bucket is marked warm
