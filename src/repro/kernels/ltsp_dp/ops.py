@@ -3,8 +3,11 @@ traceback, and a size-bucketed batch planner.
 
 The device path is a **complete solver**: :func:`ltsp_dp_tables` (one jitted
 wavefront, see :mod:`.ltsp_dp`) returns the value table *and* per-cell argmin
-planes; :func:`traceback_detours` replays the argmin planes on the host to
-reconstruct the optimal detour list, exactly like the Python DP's traceback.
+planes; :func:`~.walk.traceback_device` replays the argmin planes on the
+device to reconstruct the optimal detour list, exactly like the Python DP's
+traceback, so only the detours and root values cross to the host.
+:func:`traceback_detours` is the same walk on a host copy of a plane, the
+reference the device walk is tested against.
 
 Three numeric modes:
 
@@ -80,6 +83,7 @@ import numpy as np
 from ...core.instance import Instance, virtual_lb
 from ...core.warm import DenseStore, WarmState, WarmStats, align_warm, warm_from_instance
 from .ltsp_dp import DEFAULT_CAND_TILE, ltsp_dp_tables
+from .walk import traceback_device
 
 __all__ = [
     "prepare_arrays",
@@ -301,7 +305,9 @@ def traceback_detours(choice: np.ndarray, mult: np.ndarray) -> list[tuple[int, i
     Iterative pre-order walk from the root cell ``(0, R-1, 0)``: ``-1`` means
     "skip b" (descend to ``(a, b-1, s + x_b)``), ``c`` means detour ``(c, b)``
     (emit it, descend into its inner structure ``(c, b, s)``, then resume with
-    ``(a, c-1, s)``).  Matches the exact Python DP's emission order.
+    ``(a, c-1, s)``).  Matches the exact Python DP's emission order.  The
+    solver runs the same walk on the device (:func:`~.walk.traceback_device`);
+    this host copy is the reference the tests hold it to.
     """
     R = choice.shape[0]
     x = [int(v) for v in mult]
@@ -362,17 +368,25 @@ def _solve_packed(
     launch's gcd-rescaled units together with ``g``, so lookups reconstruct
     original-unit values with python-int arithmetic).
 
+    The detours come from :func:`~.walk.traceback_device` on both paths: the
+    tables stay on the device unless ``capture`` needs them on the host.
+
     With a ``profile`` the launch is recorded, and each phase runs inside
     its span (see :class:`~repro.obs.KernelProfile`): ``ltsp.pack``,
     ``ltsp.dispatch`` (returns before the device finishes; a cold shape
-    traces and compiles here), ``ltsp.device_wait``, ``ltsp.fetch_argmin``,
-    ``ltsp.fetch_root``, ``ltsp.traceback`` and ``ltsp.release``, where the
-    tables' last references go, so that freeing them is timed too.
+    traces and compiles here), ``ltsp.device_wait``, ``ltsp.fetch_argmin``
+    (the device walk and the copy of its outputs, and of both planes when
+    captured), ``ltsp.fetch_root`` (the root values' copy, started with the
+    walk's), ``ltsp.traceback`` (detour lists, costs, ``DenseStore``s) and
+    ``ltsp.release``, where the last references to the tables and the packed
+    inputs go, so that freeing them is timed too.
     """
     with _span(profile, "ltsp.pack"):
         left, right, x, nl, u, S = prepare_batch(
             scaled, dtype=dtype, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad
         )
+        if profile is not None:
+            h2d = sum(a.nbytes for a in (left, right, x, nl, u))
     B, R = left.shape
     with _span(profile, "ltsp.dispatch", R=R, S=S, B=B, span=span):
         T, C = ltsp_dp_tables(
@@ -382,16 +396,19 @@ def _solve_packed(
     with _span(profile, "ltsp.device_wait"):
         C.block_until_ready()
     with _span(profile, "ltsp.fetch_argmin"):
-        C_host = np.asarray(C)
+        walked = traceback_device(T, C, x)
+        for a in walked:
+            a.copy_to_host_async()
+        dets_host, n_dets, steps = (np.asarray(walked[k]) for k in (0, 1, 3))
+        C_host = np.asarray(C) if capture else None
         T_host = np.asarray(T) if capture else None
     with _span(profile, "ltsp.fetch_root"):
-        T_root = np.asarray(T[:, 0, R - 1, 0])
-        x_host = np.asarray(x)
+        T_root = np.asarray(walked[2])
     with _span(profile, "ltsp.traceback"):
         out = []
         stores: list[DenseStore | None] = []
         for i, (inst, g) in enumerate(zip(originals, gs)):
-            dets = traceback_detours(C_host[i], x_host[i])
+            dets = list(map(tuple, dets_host[i, : n_dets[i]].tolist()))
             # padding only ever skips, so emitted detours stay within the
             # real files; guard the invariant anyway.
             assert all(b < inst.n_req for _, b in dets)
@@ -407,12 +424,11 @@ def _solve_packed(
             else:
                 stores.append(None)
     if profile is not None:
-        h2d = sum(a.nbytes for a in (left, right, x, nl, u))
-        d2h = C_host.nbytes + T_root.nbytes + x_host.nbytes
+        d2h = dets_host.nbytes + n_dets.nbytes + T_root.nbytes + steps.nbytes
         if capture:
-            d2h += T_host.nbytes
+            d2h += C_host.nbytes + T_host.nbytes
     with _span(profile, "ltsp.release"):
-        del C_host, T_host, T, C
+        del C_host, T_host, T, C, walked, left, right, x, nl, u
     if profile is not None:
         profile.record(
             signature=(
@@ -426,6 +442,7 @@ def _solve_packed(
             interpret=interpret,
             h2d_bytes=h2d,
             d2h_bytes=d2h,
+            walk_steps=int(steps.sum()),
         )
     return out, stores
 

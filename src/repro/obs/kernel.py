@@ -16,8 +16,11 @@ attached through ``ExecutionContext.obs`` records one
   marks the first launch cold even though jax's jit cache may already hold
   it.)
 * ``h2d_bytes`` / ``d2h_bytes`` — the exact bytes the launch moved to the
-  device (the packed arrays) and back to the host (the argmin plane, the
-  root values, the multiplicities, and the value table when captured).
+  device (the packed arrays) and back to the host (the device traceback's
+  detours, detour counts, root values and step counts, and both dense
+  planes when captured);
+* ``walk_steps`` — the loop steps of the device traceback, summed over the
+  launch's rows (all-phantom padding rows included).
 
 Time comes from :meth:`KernelProfile.span`: the device path opens one
 ``jax.profiler.TraceAnnotation`` per phase (``ltsp.rescale`` …
@@ -48,6 +51,7 @@ class LaunchRecord:
     cold: bool
     h2d_bytes: int
     d2h_bytes: int
+    walk_steps: int = 0
 
     @property
     def waste(self) -> tuple[int, int]:
@@ -80,6 +84,7 @@ class KernelProfile:
         interpret: bool,
         h2d_bytes: int,
         d2h_bytes: int,
+        walk_steps: int = 0,
     ) -> None:
         cold = signature not in self._seen
         self._seen.add(signature)
@@ -95,6 +100,7 @@ class KernelProfile:
                 cold=cold,
                 h2d_bytes=h2d_bytes,
                 d2h_bytes=d2h_bytes,
+                walk_steps=walk_steps,
             )
         )
 
@@ -118,6 +124,7 @@ class KernelProfile:
             "wasted_cells": padded - real,
             "h2d_bytes": sum(r.h2d_bytes for r in self.launches),
             "d2h_bytes": sum(r.d2h_bytes for r in self.launches),
+            "walk_steps": sum(r.walk_steps for r in self.launches),
         }
 
     def __len__(self) -> int:

@@ -99,12 +99,16 @@ def test_the_byte_counters_equal_the_shapes_arithmetic(capture):
     B, R, S = rec.B_pad, rec.R_pad, rec.S_pad
     assert (B, R) == (4, 8)
     # int32 throughout: left, right, x, nl as [B, R] and u as [B] to the
-    # device; the argmin plane [B, R, R, S], the root values [B] and x back,
-    # and the value table [B, R, R, S] too when captured
+    # device; the device walk's detours [B, R, 2], detour counts, root values
+    # and step counts [B] back, and both planes [B, R, R, S] too when captured
     assert rec.h2d_bytes == 4 * (4 * B * R + B)
-    assert rec.d2h_bytes == 4 * ((1 + capture) * B * R * R * S + B + B * R)
+    assert rec.d2h_bytes == 4 * (2 * B * R + 3 * B + capture * 2 * B * R * R * S)
+    # each walk step consumes one file of 1..R-1, by a skip or a detour's
+    # start, so every row (the all-phantom one too) walks R - 1 steps
+    assert rec.walk_steps == B * (R - 1)
     summary = profile.summary()
-    assert (summary["h2d_bytes"], summary["d2h_bytes"]) == (rec.h2d_bytes, rec.d2h_bytes)
+    assert (summary["h2d_bytes"], summary["d2h_bytes"], summary["walk_steps"]) == (
+        rec.h2d_bytes, rec.d2h_bytes, rec.walk_steps)
 
 
 def test_without_a_profile_no_span_is_built(monkeypatch):

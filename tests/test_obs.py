@@ -443,12 +443,13 @@ def test_kernel_profile_captures_device_launches():
         wasted, padded = rec.waste  # exact fraction (wasted, padded)
         assert 0 <= wasted < padded
         assert rec.interpret
-        # int32 packed arrays in; the argmin plane (and the value table when
-        # a warm start captures it), the roots and multiplicities out
+        # int32 packed arrays in; the device walk's detours, detour counts,
+        # roots and step counts out (and both planes when a warm start
+        # captures them)
         B, R = rec.B_pad, rec.R_pad
         assert rec.h2d_bytes == 4 * (4 * B * R + B)
-        assert rec.d2h_bytes - 4 * (B + B * R) in (
-            4 * rec.padded_cells, 8 * rec.padded_cells)
+        assert rec.d2h_bytes - 4 * (2 * B * R + 3 * B) in (0, 8 * rec.padded_cells)
+        assert rec.walk_steps == B * (R - 1)
     assert prof.summary()["n_instances"] >= len(prof.launches)
     # a cold launch (first of its bucket signature) pays compilation; re-use
     # of the same bucket is marked warm

@@ -6,7 +6,8 @@ the chip would refuse (illegal block shapes, unaligned dynamic slices, scoped
 VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
 median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
 disjoint variants, and the banded scan at a 16-row candidate tile.  Each
-program must fit the chip's 16 GB of HBM.
+program must fit the chip's 16 GB of HBM.  The device traceback that walks
+the argmin plane compiles at the median bucket too, holding no copy of it.
 
 The topology is described inside a module fixture, never at import time, so
 every test worker collects the same tests and only the worker that runs this
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+from repro.kernels.ltsp_dp.walk import traceback_device
 
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
 V5E_HBM_BYTES = 16 * 10**9
@@ -72,3 +74,17 @@ def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
     # the outputs alone are T and C, B*R*R*S int32 each
     assert ma.output_size_in_bytes >= 2 * B * R * R * S * 4
     assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one v5e"
+
+
+def test_device_walk_compiles_for_v5e_without_a_copy_of_the_plane(one_chip):
+    B, R, S = 1, 256, 4096
+    plane = jax.ShapeDtypeStruct((B, R, R, S), jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((B, R), jnp.int32, sharding=one_chip)
+    ma = traceback_device.lower(plane, plane, x).compile().memory_analysis()
+    plane_bytes = B * R * R * S * 4  # 1 GiB
+    assert ma.argument_size_in_bytes >= 2 * plane_bytes
+    # detours [B, R, 2] and three [B] vectors: what crosses to the host
+    assert ma.output_size_in_bytes < 2**16
+    # the walk reads T and C in place: its temporaries are a few stack
+    # frames and scalars, not a plane
+    assert ma.temp_size_in_bytes < plane_bytes // 256, ma.temp_size_in_bytes
