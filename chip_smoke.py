@@ -91,14 +91,14 @@ def phase_a() -> None:
     """One paper-median tape, B = 1, through ``solve``."""
     from repro.core import ExecutionContext, evaluate_detours, solve
     from repro.core.verify import verify_schedule
-    from repro.kernels.ltsp_dp.ops import _table_bound, bucket_shape, rescale_instance
+    from repro.kernels.ltsp_dp.ops import _int32_admits, bucket_shape, rescale_instance
 
     u = _u_turns()["full_seg"]
     tapes = _dataset(u)
     idx = next(
         i for i, t in enumerate(tapes)
         if bucket_shape(t) == MEDIAN_BUCKET
-        and _table_bound(rescale_instance(t)[0]) < 2**31
+        and _int32_admits(rescale_instance(t)[0])
     )
     inst = tapes[idx]
     R, S = MEDIAN_BUCKET

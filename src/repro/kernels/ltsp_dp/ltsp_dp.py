@@ -80,13 +80,38 @@ Layout notes
   so instances up to R = 129 take the single-tile path, while large
   instances stream the band in 128-row tiles.
 * ``dtype`` is ``float32`` (exact for values < 2**24, the oracle-comparison
-  path), ``int32`` (exact for values < 2**31, the solver path), or
-  ``float64`` (exact for values < 2**53 — the interpret-only numeric fallback
-  in :mod:`.ops` for instances whose coprime byte-scale coordinates fail the
-  int32 guard even after gcd/shift rescaling).
+  path), ``int32`` (the solver path, exact under the guard of :mod:`.ops`,
+  see *Saturating sums* below), or ``float64`` (exact for values < 2**53 —
+  the interpret-only numeric fallback in :mod:`.ops` for instances whose
+  coprime byte-scale coordinates fail the int32 guard even after gcd/shift
+  rescaling).
 * The ``skip`` term needs the shifted gather ``row[min(s + x_b, S - 1)]``;
   ``x_b`` is a scalar per program, so it is a lane rotation by ``-x_b`` plus
   a mask that substitutes ``row[S - 1]`` where ``s + x_b`` passes the end.
+
+Saturating sums
+---------------
+An int32 table stores every value clipped to :data:`INT32_CAP`
+(``2**30 - 1``), so the two table values of a candidate add without
+wrapping.  Its two linear terms are one affine function of ``s`` per
+candidate row, and :func:`_add_sat` adds it clipped at ``big = 2**31 - 1``
+instead of wrapping; the skip's terms are added the same way.  A lane ``s``
+reads only lanes ``>= s`` of other cells (the skip reads ``s + x_b``, the
+candidates the same ``s``), and every cell a reader takes (the root and the
+cells the traceback or a warm start's ``DenseStore`` reads) has ``s <= n``.
+The guard of :mod:`.ops` admits an instance only when (i) every term and
+product the kernel forms at the lanes ``s <= n`` is below ``big``, so none
+wraps there, and (ii) every cell a reader takes is below ``INT32_CAP``.
+Those cells read only cells of their own kind, so by induction over the
+diagonals their operands are exact, each candidate and the skip read
+``min(true sum, big)``, and clipping is monotone and leaves values below
+``big`` alone: the clipped minimum is the true minimum, a candidate reads it
+exactly when its true sum is it, the smallest minimising ``c`` and the
+skip's tie win are unchanged, and the clip to ``INT32_CAP`` leaves the
+result alone.  The lanes past ``n`` of a padded launch may wrap and hold any
+value; they feed none of those cells.  ``big`` stays strictly above every
+cell a reader takes, so a masked candidate row never wins there.  Floats do
+not wrap: they add plainly and clip at infinity.
 """
 
 from __future__ import annotations
@@ -98,13 +123,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["wavefront_kernel", "ltsp_dp_wavefront", "ltsp_dp_tables"]
+__all__ = ["wavefront_kernel", "ltsp_dp_wavefront", "ltsp_dp_tables", "INT32_CAP"]
 
 #: default candidate-chunk height (sublane rows per banded-scan step).
 DEFAULT_CAND_TILE = 128
 
 #: sublane tile height: dynamic row windows start at multiples of this.
 _SUBLANES = 8
+
+#: int32 sums saturate here, and masked candidate rows read it
+_INT32_MAX = 2**31 - 1
+#: the largest value an int32 table stores: two of them add without wrapping
+INT32_CAP = _INT32_MAX // 2
+
+
+def _limits(dtype):
+    """``(big, cap)``: where sums clip and where stored values clip."""
+    if dtype == jnp.int32:
+        return jnp.asarray(_INT32_MAX, dtype), jnp.asarray(INT32_CAP, dtype)
+    inf = jnp.asarray(jnp.inf, dtype)
+    return inf, inf
+
+
+def _add_sat(x, y, big):
+    """``x + y`` clipped at ``big``: ``x + min(y, big - x)`` in integers,
+    which never wraps for ``x`` in ``[0, big]`` and ``y >= 0``; plain
+    addition in floats."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        return x + jnp.minimum(y, big - x)
+    return x + y
 
 
 def wavefront_kernel(
@@ -137,11 +184,9 @@ def wavefront_kernel(
     # b (cheap, garbage) and let the host-side scatter drop the result.
     b = jnp.minimum(a + d, R - 1)
     dtype = row_ref.dtype
-    # strictly above every candidate the guards in ops admit (< 2**31 for
-    # int32), so a masked row never beats a live one
-    big = jnp.asarray(
-        jnp.iinfo(jnp.int32).max if dtype == jnp.int32 else jnp.inf, dtype
-    )
+    # stored values are clipped to cap and sums to big, strictly above them,
+    # so a masked row never beats a live one (module docstring)
+    big, cap = _limits(dtype)
     two = jnp.asarray(2, dtype)
     base = i * R
 
@@ -161,10 +206,10 @@ def wavefront_kernel(
     last = jnp.max(jnp.where(lane == S - 1, row_bm1, -big), axis=1, keepdims=True)
     shifted = jnp.where(lane + x_b <= S - 1, rolled, last)
     svec = jax.lax.broadcasted_iota(dtype, (1, S), 1)
-    skip = (
-        shifted
-        + two * (r_b - r_bm1) * (svec + nl_a)
-        + two * (l_b - r_bm1) * x_b.astype(dtype)
+    skip = _add_sat(
+        _add_sat(shifted, two * (r_b - r_bm1) * (svec + nl_a), big),
+        two * (l_b - r_bm1) * x_b.astype(dtype),
+        big,
     )
 
     # ---------------- min over detour_c, banded to a < c <= b --------------
@@ -174,7 +219,8 @@ def wavefront_kernel(
     # another, i.e. cells with a > 0 may only skip; the 3-D table then
     # collapses to SIMPLEDP's 2-D recursion exactly, traceback included).
     # Table rows outside the wavefront are zeros (or stale values of other
-    # cells), so the masked rows compute harmless integers before the mask.
+    # cells), so the masked rows compute harmless integers before the mask;
+    # rows with c - 1 > b may go negative there, and the mask replaces them.
     c_min = a + 1
     if span is not None:  # LOGDP restriction: b - c <= span
         c_min = jnp.maximum(c_min, b - span)
@@ -188,13 +234,11 @@ def wavefront_kernel(
         t_right = col_ref[0, 0, pl.ds(k0, n_rows), :]  # T[c, b, s]
         r_cm1 = rk_ref[0, pl.ds(k0, n_rows), :]  # [n_rows, 1]
         nl_c = nk_ref[0, pl.ds(k0, n_rows), :]  # [n_rows, 1]
-        svec_d = jax.lax.broadcasted_iota(dtype, (n_rows, S), 1)
-        cand = (
-            t_left
-            + t_right
-            + two * (r_b - r_cm1) * (svec_d + nl_a)
-            + two * u * (svec_d + nl_c)
-        )
+        # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c) as
+        # one affine function of s per row: a multiply and an add per element
+        slope = two * (r_b - r_cm1 + u)
+        offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
+        cand = _add_sat(t_left + t_right, svec * slope + offset, big)
         cvec = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0) + (k0 + 1)
         cand = jnp.where((cvec >= c_min) & (cvec <= b), cand, big)
         cmin = jnp.min(cand, axis=0, keepdims=True)
@@ -233,7 +277,7 @@ def wavefront_kernel(
             (jnp.full((1, S), big, dtype), jnp.zeros((1, S), jnp.int32)),
         )
 
-    val_ref[0, 0] = jnp.minimum(skip, det)
+    val_ref[0, 0] = jnp.minimum(jnp.minimum(skip, det), cap)
     cho_ref[0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
 
 
@@ -379,6 +423,7 @@ def ltsp_dp_tables(
     # as ref.base_diagonal so the f32 path stays bit-identical to the oracle)
     svec = jnp.arange(S, dtype=dtype)
     base = 2 * (right - left)[:, :, None] * (svec[None, None, :] + nl[:, :, None])
+    base = jnp.minimum(base, _limits(dtype)[1])
     T = jnp.zeros((B, R, R, S), dtype)
     T = T.at[:, rr, rr, :].set(base)
     # Tc[i, b, a - 1] = T[i, a, b]; a = 0 never appears as a column term

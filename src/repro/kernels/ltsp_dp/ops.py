@@ -11,23 +11,28 @@ reference the device walk is tested against.
 
 Three numeric modes:
 
-* ``int32`` (solver default) — bit-exact while every table value fits in
-  int32.  Before the :func:`_check_int32_safe` magnitude guard runs,
-  :func:`rescale_instance` shifts each instance to its leftmost requested
-  byte and divides all coordinates (and the U-turn penalty) by their gcd —
-  every DP term is a coordinate *difference*, so the whole table scales by
-  exactly ``1/g`` and the argmin structure (ties included) is untouched.
-  Real cartridge layouts share the tape's block granularity, so byte
-  coordinates far beyond int32 rescale into range; the guard rejects only
-  genuinely coprime byte-scale layouts.
+* ``int32`` (solver default) — bit-exact when every cell a reader takes is
+  below ``2**30 - 1`` and every term the kernel forms at the lanes those
+  cells depend on below ``2**31 - 1`` (:func:`_int32_admits`): the kernel clips its tables and saturates its
+  sums instead of wrapping, so a candidate sum may pass int32 and still lose
+  the minimum exactly (see :mod:`.ltsp_dp`, *Saturating sums*).  Before the
+  :func:`_check_int32_safe` magnitude guard runs, :func:`rescale_instance`
+  shifts each instance to its leftmost requested byte and divides all
+  coordinates (and the U-turn penalty) by their gcd — every DP term is a
+  coordinate *difference*, so the whole table scales by exactly ``1/g`` and
+  the argmin structure (ties included) is untouched.  Real cartridge
+  layouts share the tape's block granularity, so byte coordinates far beyond
+  int32 rescale into range; the guard rejects only genuinely coprime
+  byte-scale layouts.
 * ``float64`` (``numeric_policy="f64"`` fallback, exact for values < 2**53) —
   instances the int32 guard rejects are re-solved through the same wavefront
   in float64 **interpret** mode (f64 is emulated on TPU VPUs, so the
   compiled backend is not offered; the fallback is a CPU-side escape hatch
   for the rare coprime layouts).  Integer table values below 2**53 are
-  exactly representable, so within :func:`_check_f64_safe`'s bound the
-  result is still bit-identical to the python DP; beyond it the guard raises
-  either way.  Selected via ``ExecutionContext.numeric_policy``; the default
+  exactly representable and rounding is monotone, so with the same two
+  bounds below 2**53 (:func:`_check_f64_safe`) the result is still
+  bit-identical to the python DP; beyond them the guard raises either way.
+  Selected via ``ExecutionContext.numeric_policy``; the default
   ``"strict"`` keeps the old raise.  A compiled (``interpret=False``) solve
   that would need the fallback raises instead of quietly running the
   interpreter.
@@ -82,7 +87,7 @@ import numpy as np
 
 from ...core.instance import Instance, virtual_lb
 from ...core.warm import DenseStore, WarmState, WarmStats, align_warm, warm_from_instance
-from .ltsp_dp import DEFAULT_CAND_TILE, ltsp_dp_tables
+from .ltsp_dp import DEFAULT_CAND_TILE, INT32_CAP, ltsp_dp_tables
 from .walk import traceback_device
 
 __all__ = [
@@ -239,18 +244,65 @@ def rescale_instance(inst: Instance) -> tuple[Instance, int]:
     return scaled, g
 
 
-def _table_bound(inst: Instance) -> int:
-    """Conservative bound on any candidate sum the kernel ever forms.
+#: the int32 route's limits: cells below the value the kernel's int32
+#: tables clip to, terms below int32's largest value (``ltsp_dp`` docstring,
+#: *Saturating sums*)
+_INT32_LIMITS = (INT32_CAP, 2**31 - 1)
+#: float64 holds every integer below 2**53 exactly
+_F64_LIMITS = (2**53, 2**53)
 
-    Expanding any cell's recursion, the ``2 Δr (s + n_l)`` movement terms
-    telescope to at most ``2n * 2m``, the base terms add at most ``2n * m``,
-    and at most R detours each add ``2 U * 2n`` — so every cell is below
-    ``2n (3m + R U)`` and every candidate sum below
-    ``2n (7m + (2R + 1) U)``; we bound with ``2n (8m + (2R + 2) U)``.
-    Callers pass :func:`rescale_instance` output, so ``m`` here is already the
-    gcd-reduced *requested span*.
+
+def _cell_bound(inst: Instance) -> int:
+    """Bound on every cell value a reader takes: ``4 n m``.
+
+    The readers are the traceback, from the root ``(0, R - 1, 0)`` down, and
+    a warm start's :class:`~repro.core.warm.DenseStore`, which admits
+    ``(a, b, s)`` when ``s + x_{a+1} + ... + x_b <= n``; the root's cone
+    keeps that, and every cell reads only cells that keep it.
+    ``T = min(skip, detours) <= skip``, so such a cell is at most its
+    all-skip chain down to the base cell ``(a, a)``.  Skipping file ``k``
+    adds ``2 (r_k - r_{k-1}) (s_k + n_l(a)) + 2 (l_k - r_{k-1}) x_k <=
+    2 (r_k - r_{k-1}) (s_k + x_k + n_l(a))`` and the base cell is ``2 (r_a -
+    l_a) (s_a + n_l(a))``, where ``s_k + x_k`` and ``s_a`` are at most ``n``
+    by the condition above and ``n_l(a) <= n``.  So the chain telescopes to
+    at most ``2 (n + n_l(a)) (r_b - l_a) <= 4 n m`` (``2 n m`` on the cells
+    the root reaches, where ``s_a + n_l(a) <= n``).  Callers pass
+    :func:`rescale_instance` output, so ``m`` is the gcd-reduced *requested
+    span*.  Phantom padding files (zero width and multiplicity at the last
+    coordinate) add nothing to a chain.
     """
-    return 2 * inst.n * (8 * inst.m + (2 * inst.n_req + 2) * inst.u_turn)
+    return 4 * inst.n * inst.m
+
+
+def _term_bound(inst: Instance) -> int:
+    """Bound on every term and product the kernel forms at a lane a reader
+    depends on: ``4 n (m + U)``.
+
+    A lane ``s`` reads only lanes ``>= s`` (the skip reads ``s + x_b``, the
+    candidates the same ``s``), and every cell a reader takes (see
+    :func:`_cell_bound`) has ``s <= n``; so the lanes ``s > n`` of a padded
+    launch feed none of them, and their terms may wrap.  The largest term is
+    a candidate's linear part ``2 (r_b - r_{c-1}) (s + n_l(a)) + 2 U (s +
+    n_l(c))``, formed as ``2 (r_b - r_{c-1} + U) s + 2 ((r_b - r_{c-1})
+    n_l(a) + U n_l(c))`` with ``s`` and every ``n_l`` at most ``n``; the base
+    and skip terms are ``2 d (s + n_l)`` and ``2 d x_b`` with ``d <= m``.
+    """
+    return 4 * inst.n * (inst.m + inst.u_turn)
+
+
+def _failed_bounds(inst: Instance, limits: tuple[int, int]) -> str:
+    """The bounds of ``inst`` that reach their limit (``limits`` = cell
+    limit, term limit), named with their values; empty when the instance is
+    admitted."""
+    bounds = (("cell-value bound 4nm", _cell_bound(inst), limits[0]),
+              ("term bound 4n (m + U)", _term_bound(inst), limits[1]))
+    return "; ".join(f"{name} = {v}, not below {lim}"
+                     for name, v, lim in bounds if v >= lim)
+
+
+def _int32_admits(inst: Instance) -> bool:
+    """Whether the int32 wavefront solves ``inst`` exactly."""
+    return not _failed_bounds(inst, _INT32_LIMITS)
 
 
 def _check_int32_safe(instances: list[Instance]) -> None:
@@ -258,13 +310,14 @@ def _check_int32_safe(instances: list[Instance]) -> None:
     genuinely overflows even at tape-block granularity (after gcd/shift
     rescaling)."""
     for inst in instances:
-        if _table_bound(inst) >= 2**31:
+        failed = _failed_bounds(inst, _INT32_LIMITS)
+        if failed:
             raise ValueError(
                 f"instance too large for the int32 device DP even after gcd "
-                f"rescaling (m={inst.m}, n={inst.n}, R={inst.n_req}): rescale "
-                f"coordinates to a coarser grain, use backend='python', or "
-                f"opt into the exact float64 interpret fallback with "
-                f"numeric_policy='f64'"
+                f"rescaling (m={inst.m}, n={inst.n}, R={inst.n_req}): "
+                f"{failed}; rescale coordinates to a "
+                f"coarser grain, use backend='python', or opt into the exact "
+                f"float64 interpret fallback with numeric_policy='f64'"
             )
 
 
@@ -276,13 +329,14 @@ def _guard(scaled: list[Instance], numeric_policy: str, interpret: bool) -> list
     if numeric_policy != "f64":
         _check_int32_safe(scaled)
         return []
-    wide = [i for i, s in enumerate(scaled) if _table_bound(s) >= 2**31]
+    wide = [i for i, s in enumerate(scaled) if not _int32_admits(s)]
     _check_f64_safe([scaled[i] for i in wide])
     if wide and not interpret:
         s = scaled[wide[0]]
         raise ValueError(
             f"instance needs the float64 route (m={s.m}, n={s.n}, "
-            f"R={s.n_req}), which runs only in the interpreter: use "
+            f"R={s.n_req}: {_failed_bounds(s, _INT32_LIMITS)}), "
+            f"which runs only in the interpreter: use "
             f"backend='pallas-interpret' or backend='python'"
         )
     return wide
@@ -291,11 +345,12 @@ def _guard(scaled: list[Instance], numeric_policy: str, interpret: bool) -> list
 def _check_f64_safe(instances: list[Instance]) -> None:
     """Exactness-domain guard for the float64 fallback (< 2**53)."""
     for inst in instances:
-        if _table_bound(inst) >= 2**53:
+        failed = _failed_bounds(inst, _F64_LIMITS)
+        if failed:
             raise ValueError(
                 f"instance too large even for the exact float64 device DP "
-                f"(m={inst.m}, n={inst.n}, R={inst.n_req}): integer table "
-                f"values would exceed 2**53; use backend='python'"
+                f"(m={inst.m}, n={inst.n}, R={inst.n_req}): {failed} "
+                f"(2**53); use backend='python'"
             )
 
 
@@ -463,7 +518,7 @@ def ltsp_solve_batch(
 
     Returns one ``(opt_cost, detours)`` per instance, in order.  ``opt_cost``
     is ``g * T[0, R_pad-1, 0] + VirtualLB`` taken from the gcd-rescaled int32
-    device table — exact under the :func:`_check_int32_safe` bound; detour
+    device table — exact under the :func:`_check_int32_safe` bounds; detour
     indices refer to each instance's own (unpadded) requested files.
 
     ``bucketed=True`` (default) launches one wavefront per

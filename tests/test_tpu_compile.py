@@ -5,7 +5,8 @@ interpret=False)`` against a described ``v5e:2x2`` topology and refuses what
 the chip would refuse (illegal block shapes, unaligned dynamic slices, scoped
 VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
 median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
-disjoint variants, and the banded scan at a 16-row candidate tile.  Each
+disjoint variants, and the banded scan at a 16-row candidate tile; and the
+paper's tail bucket (256, 8192), which the chip benchmark runs.  Each
 program must fit the chip's 16 GB of HBM.  The device traceback that walks
 the argmin plane compiles at the median bucket too, holding no copy of it.
 
@@ -55,6 +56,7 @@ def one_chip():
         pytest.param(2, 32, 1024, {"span": 5}, id="logdp-span5-B2-R32"),
         pytest.param(2, 64, 1024, {"disjoint": True}, id="simpledp-disjoint-B2-R64"),
         pytest.param(1, 256, 4096, {"span": 40}, id="logdp-span40-B1-R256"),
+        pytest.param(1, 256, 8192, {}, id="paper-tail-B1-R256-S8192"),
     ],
 )
 def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
