@@ -17,8 +17,8 @@ a dozen signatures.  :class:`ExecutionContext` bundles all of it:
 * ``bucketed`` — whether device batches go through the size-bucketed launch
   planner (``False`` reproduces the seed's single maximally-padded launch,
   kept for A/B benchmarking);
-* ``cand_tile`` — candidate-chunk height override for the banded wavefront
-  scan (``None`` = kernel default);
+* ``cand_tile`` — chunk-height override for the wavefront's band copies and
+  candidate scan (``None`` = kernel default; a LOGDP span sets its own);
 * ``numeric_policy`` — what to do when an instance fails the int32 device
   magnitude guard *after* gcd/shift rescaling: ``"strict"`` raises (default),
   ``"f64"`` falls back to an exact float64 interpret-mode table for just the

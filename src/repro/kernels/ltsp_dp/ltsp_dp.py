@@ -1,5 +1,5 @@
 """Pallas TPU kernel for the LTSP DP wavefront — single-trace, batched,
-traceback-capable, with a banded candidate scan and per-program DMA slices.
+traceback-capable, each program copying and scanning only its live band.
 
 TPU adaptation of the paper's CPU dynamic program (DESIGN.md §Hardware
 adaptation): the O(n_req) inner minimisation of ``detour_c`` is the compute
@@ -28,6 +28,14 @@ aligns the two terms of candidate ``c``: ``T[a, c - 1, s]`` and
 ``T[c, b, s]`` both sit at index ``k = c - 1`` of their row, so one
 8-aligned sublane window of ``k`` serves both.
 
+Both tables stay in HBM (``memory_space=pl.ANY``).  A program copies only
+the rows of its band (below) from ``T[i, a]`` and ``Tc[i, b]`` into two
+VMEM slots of ``[H, S]`` each, with ``pltpu.make_async_copy``: while chunk
+``j`` is scanned, chunk ``j + 1`` is in flight, and a program's last chunk
+starts the first chunk of the next live program in grid order, so no
+program waits for its first copy but the grid's first.  The skip's row
+``T[a, b - 1]`` is the band's last row and needs no copy of its own.
+
 Per-file scalars (``left/right/x/nl`` flattened to ``[B * R]``, and ``u``)
 ride as scalar-prefetch operands in SMEM, read by ``program_id``.  The two
 per-candidate vectors ``right[c - 1]`` and ``nl[c]`` come as ``[B, R, 1]``
@@ -40,25 +48,32 @@ A cell ``(a, b)`` on diagonal ``d = b - a`` has exactly ``d`` detour
 candidates ``c in (a, b]`` (fewer under a LOGDP span restriction; none on
 non-root cells under the SIMPLEDP ``disjoint=True`` restriction, which clips
 the candidate band to ``a == 0`` cells — forbidding detours inside detours
-collapses the table to SIMPLEDP's 2-D recursion exactly).  The kernel walks
-the live band in ``cand_tile``-row chunks: a ``fori_loop`` over chunk bases
-aligned down to a multiple of 8 (the sublane tile), each chunk masked to the
-live band and folded into a running ``(min, argmin)``.  The argmin of a chunk
-is ``min`` over ``where(cand == min, c, INT_MAX)`` — the smallest minimising
-``c``.  Chunks ascend in ``c`` and the fold improves strictly, so the result
-is the *smallest* minimising ``c`` — identical tie-breaking to the exact
-Python DP (skip wins ties against detours; among detours the smallest ``c``
-wins).  The last chunk base is clamped to ``R - cand_tile``; the overlap
-re-evaluates candidates the strict fold ignores.  When
-``R - 1 <= cand_tile`` the kernel statically takes one masked tile over
-every ``c`` (same arithmetic, no loop).
+collapses the table to SIMPLEDP's 2-D recursion exactly).  A program's band
+is the rows ``k = c - 1`` of those candidates and of the skip's row
+``k = b - 1``, from the first one aligned down to a multiple of 8 (the
+sublane tile) to ``b - 1`` (:func:`band_chunks`).  The kernel walks it in
+chunks of ``H`` rows (:func:`chunk_rows`: ``cand_tile``, or under a LOGDP
+span the one chunk of ``span + 8`` rows, rounded up to 8, that holds any
+band whole): a ``fori_loop`` over the chunks, each masked to the live
+candidates and folded into a running ``(min, argmin)``.  The argmin of a
+chunk is ``min`` over ``where(cand == min, c, INT_MAX)`` — the smallest
+minimising ``c``.  Chunks ascend in ``c`` and the fold improves strictly, so
+the result is the *smallest* minimising ``c`` — identical tie-breaking to
+the exact Python DP (skip wins ties against detours; among detours the
+smallest ``c`` wins).  The last chunk base is clamped to ``R - H``; the
+overlap re-evaluates candidates the strict fold ignores.  A program with
+``a + d >= R`` lies past the diagonal's end: its band is empty, it copies
+and computes nothing, and the scatter of :func:`ltsp_dp_tables` drops its
+output blocks.  :func:`band_copy_bytes` counts the bytes the copies move.
 
 ``dimension_semantics`` audit of the ``(B, R)`` grid: the batch dimension
 indexes independent instances and the window-start dimension indexes cells of
 *one* anti-diagonal, which only read diagonals ``< d`` (frozen in this launch)
 and write disjoint output blocks — no program on the grid observes another's
-write, so both dimensions are declared ``"parallel"``.  Compiled mode only;
-the interpreter ignores scheduling hints.
+write.  The copies run ahead across programs in grid order, though, so both
+dimensions are declared ``"arbitrary"``: a chip that split the grid over
+cores would leave a core's first program waiting on a copy nobody started.
+Compiled mode only; the interpreter runs the grid in order.
 
 The kernel additionally emits a per-cell **argmin plane** ``C[a, b, s]``
 (-1 = "skip b", else the winning detour start ``c``) so a host-side traceback
@@ -73,12 +88,12 @@ padding, see ``ops.prepare_batch``) are simply never traced back.
 
 Layout notes
 ------------
-* ``S`` must be a multiple of 128 (lane width).  When the banded scan runs
-  compiled, ``cand_tile`` and ``R`` must be multiples of 8 (sublane tile);
-  the power-of-two buckets of :mod:`.ops` are.
-* ``cand_tile`` is the candidate-chunk height (sublane axis); 128 by default
-  so instances up to R = 129 take the single-tile path, while large
-  instances stream the band in 128-row tiles.
+* ``S`` must be a multiple of 128 (lane width).  When the band runs
+  compiled in chunks shorter than ``R``, ``H`` and ``R`` must be multiples
+  of 8 (sublane tile); the power-of-two buckets of :mod:`.ops` are.
+* ``cand_tile`` is the chunk height ``H`` (sublane axis) of the exact DP's
+  band, at most ``R``: 32 by default, the fastest of 16, 32 and 64 on a
+  v5e at the buckets (256, 4096) and (256, 8192).
 * ``dtype`` is ``float32`` (exact for values < 2**24, the oracle-comparison
   path), ``int32`` (the solver path, exact under the guard of :mod:`.ops`,
   see *Saturating sums* below), or ``float64`` (exact for values < 2**53 —
@@ -120,13 +135,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["wavefront_kernel", "ltsp_dp_wavefront", "ltsp_dp_tables", "INT32_CAP"]
+__all__ = [
+    "wavefront_kernel",
+    "ltsp_dp_wavefront",
+    "ltsp_dp_tables",
+    "band_chunks",
+    "band_copy_bytes",
+    "chunk_rows",
+    "INT32_CAP",
+]
 
-#: default candidate-chunk height (sublane rows per banded-scan step).
-DEFAULT_CAND_TILE = 128
+#: default chunk height of the band (sublane rows per copy and scan step).
+DEFAULT_CAND_TILE = 32
 
 #: sublane tile height: dynamic row windows start at multiples of this.
 _SUBLANES = 8
@@ -154,6 +178,58 @@ def _add_sat(x, y, big):
     return x + y
 
 
+def chunk_rows(R: int, span: int | None, cand_tile: int) -> int:
+    """Height ``H`` of the band's chunks: ``cand_tile`` rows, or under a
+    LOGDP span the fewest multiple-of-8 rows that hold any band whole
+    (``span + 8``: ``span + 1`` rows from a start aligned down by up to 7),
+    so that every program copies and scans one chunk; at most ``R``."""
+    if span is not None:
+        cand_tile = -(-(span + _SUBLANES) // _SUBLANES) * _SUBLANES
+    return min(cand_tile, R)
+
+
+def _c_min(a, b, span: int | None, disjoint: bool, xp):
+    """The first live detour start of cell ``(a, b)``: the candidates are
+    ``c in [c_min, b]``, none where ``c_min > b``."""
+    c_min = a + 1
+    if span is not None:  # LOGDP restriction: b - c <= span
+        c_min = xp.maximum(c_min, b - span)
+    if disjoint:  # SIMPLEDP restriction: detours only at the root level
+        c_min = xp.where(a > 0, b + 1, c_min)
+    return c_min
+
+
+def band_chunks(a, d, R: int, H: int, span: int | None, disjoint: bool, xp=jnp):
+    """``(k_first, n_chunks)`` of the program at window start ``a`` on
+    diagonal ``d``: its band is the rows ``k = c - 1`` of the live candidates
+    ``c in [c_min, b]``, ``b = a + d``, and of the skip's row ``k = b - 1``,
+    from ``k_first`` (aligned down to the sublane tile) in ``n_chunks``
+    chunks of ``H`` rows, chunk ``j`` at ``min(k_first + j H, R - H)``.  A
+    dead program (``b >= R``) has none.  ``xp`` is ``jnp`` in the kernel and
+    ``numpy`` where the host counts the copies (:func:`band_copy_bytes`)."""
+    b = a + d
+    c_min = _c_min(a, b, span, disjoint, xp)
+    k_first = ((xp.minimum(c_min, b) - 1) // _SUBLANES) * _SUBLANES
+    n_chunks = xp.where(b < R, (b - k_first + H - 1) // H, 0)
+    return k_first, n_chunks
+
+
+@functools.lru_cache(maxsize=64)
+def band_copy_bytes(
+    B: int, R: int, S: int, itemsize: int, span: int | None, disjoint: bool,
+    cand_tile: int,
+) -> int:
+    """HBM bytes the wavefront's band copies of ``T`` and ``Tc`` move in one
+    launch of :func:`ltsp_dp_tables`: two ``[H, S]`` copies per chunk of
+    every live program, over the ``R - 1`` diagonals and ``B`` instances.
+    Cached per launch shape: a profiled solve records it after every launch,
+    and at R = 256 counting takes about a millisecond of host time."""
+    H = chunk_rows(R, span, cand_tile)
+    d, a = np.meshgrid(np.arange(1, R), np.arange(R), indexing="ij")
+    _, n_chunks = band_chunks(a, d, R, H, span, disjoint, xp=np)
+    return int(n_chunks.sum()) * B * 2 * H * S * itemsize
+
+
 def wavefront_kernel(
     # scalar-prefetch inputs (SMEM)
     d_ref,  # [1] int32 — current anti-diagonal
@@ -162,111 +238,138 @@ def wavefront_kernel(
     right_ref,  # [B * R] dtype
     x_ref,  # [B * R] int32
     nl_ref,  # [B * R] dtype
-    # tensor inputs (VMEM blocks)
-    row_ref,  # [1, 1, R, S] — T[i, a, :, :]
-    col_ref,  # [1, 1, R, S] — Tc[i, b, :, :]; Tc[i, b, k] = T[i, k + 1, b]
+    # tensor inputs
+    t_hbm,  # [B, R, R, S] in HBM — T
+    tc_hbm,  # [B, R, R, S] in HBM — Tc; Tc[i, b, k] = T[i, k + 1, b]
     rk_ref,  # [1, R, 1] dtype — right[i, k]   (= r_{c-1} at k = c - 1)
     nk_ref,  # [1, R, 1] dtype — nl[i, k + 1]  (= nl_c   at k = c - 1)
     # outputs
     val_ref,  # [1, 1, 1, S] — new T[a, a+d, :]
     cho_ref,  # [1, 1, 1, S] int32 — argmin plane (-1 = skip, else c)
+    # scratch
+    t_buf,  # [2, H, S] — two slots of band rows of T[i, a]
+    c_buf,  # [2, H, S] — the same rows of Tc[i, b]
+    sem,  # DMA semaphores [2 tables, 2 slots]
+    slot_ref,  # [1] int32 SMEM — slot of this program's first chunk
     *,
     S: int,
     span: int | None,
     disjoint: bool,
-    cand_tile: int,
 ):
     i = pl.program_id(0)
     a = pl.program_id(1)
-    R = row_ref.shape[2]
+    B, R = t_hbm.shape[0], t_hbm.shape[1]
+    H = t_buf.shape[1]
     d = d_ref[0]
-    # programs with a + d >= R are out of this diagonal: compute at a clamped
-    # b (cheap, garbage) and let the host-side scatter drop the result.
-    b = jnp.minimum(a + d, R - 1)
-    dtype = row_ref.dtype
+    dtype = t_buf.dtype
     # stored values are clipped to cap and sums to big, strictly above them,
     # so a masked row never beats a live one (module docstring)
     big, cap = _limits(dtype)
     two = jnp.asarray(2, dtype)
-    base = i * R
 
-    u = u_ref[i]
-    nl_a = nl_ref[base + a]
-    x_b = x_ref[base + b]
-    r_b = right_ref[base + b]
-    r_bm1 = right_ref[base + b - 1]
-    l_b = left_ref[base + b]
+    def band(win):
+        return band_chunks(win, d, R, H, span, disjoint)
 
-    # ---------------- skip(a, b, s) ----------------------------------------
-    # T[a, b-1, min(s + x_b, S-1)]: rotate the row left by x_b lanes, then
-    # put row[S-1] wherever s + x_b ran past the end (the clamp).
-    row_bm1 = row_ref[0, 0, pl.ds(b - 1, 1), :]  # [1, S]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-    rolled = pltpu.roll(row_bm1, (S - x_b) % S, 1)
-    last = jnp.max(jnp.where(lane == S - 1, row_bm1, -big), axis=1, keepdims=True)
-    shifted = jnp.where(lane + x_b <= S - 1, rolled, last)
-    svec = jax.lax.broadcasted_iota(dtype, (1, S), 1)
-    skip = _add_sat(
-        _add_sat(shifted, two * (r_b - r_bm1) * (svec + nl_a), big),
-        two * (l_b - r_bm1) * x_b.astype(dtype),
-        big,
-    )
+    def chunk_base(k_first, j):
+        # the last chunk is clamped into the table: it may overlap the one
+        # before, whose candidates the strict fold keeps
+        return pl.multiple_of(jnp.minimum(k_first + j * H, R - H), _SUBLANES)
 
-    # ---------------- min over detour_c, banded to a < c <= b --------------
-    # Live candidates: c in (a, b], further clipped to c >= b - span under a
-    # LOGDP restriction, and to the empty band on non-root cells under the
-    # SIMPLEDP restriction (disjoint detours = no detour may start inside
-    # another, i.e. cells with a > 0 may only skip; the 3-D table then
-    # collapses to SIMPLEDP's 2-D recursion exactly, traceback included).
-    # Table rows outside the wavefront are zeros (or stale values of other
-    # cells), so the masked rows compute harmless integers before the mask;
-    # rows with c - 1 > b may go negative there, and the mask replaces them.
-    c_min = a + 1
-    if span is not None:  # LOGDP restriction: b - c <= span
-        c_min = jnp.maximum(c_min, b - span)
-    if disjoint:  # SIMPLEDP restriction: detours only at the root level
-        c_min = jnp.where(a > 0, b + 1, c_min)
-
-    def chunk(k0, n_rows: int):
-        """Fold candidates ``c = k0 + 1 + j``, ``j in [0, n_rows)``, masked to
-        the live band: ``(min, smallest minimising c)``, each ``[1, S]``."""
-        t_left = row_ref[0, 0, pl.ds(k0, n_rows), :]  # T[a, c-1, s]
-        t_right = col_ref[0, 0, pl.ds(k0, n_rows), :]  # T[c, b, s]
-        r_cm1 = rk_ref[0, pl.ds(k0, n_rows), :]  # [n_rows, 1]
-        nl_c = nk_ref[0, pl.ds(k0, n_rows), :]  # [n_rows, 1]
-        # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c) as
-        # one affine function of s per row: a multiply and an add per element
-        slope = two * (r_b - r_cm1 + u)
-        offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
-        cand = _add_sat(t_left + t_right, svec * slope + offset, big)
-        cvec = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0) + (k0 + 1)
-        cand = jnp.where((cvec >= c_min) & (cvec <= b), cand, big)
-        cmin = jnp.min(cand, axis=0, keepdims=True)
-        # first minimiser == smallest c, matching the exact DP's ascending-c
-        # strict-improvement scan
-        carg = jnp.min(
-            jnp.where(cand == cmin, cvec, jnp.iinfo(jnp.int32).max),
-            axis=0,
-            keepdims=True,
+    def copies(inst, win, k0, slot):
+        """The two copies of program ``(inst, win)``'s chunk at row ``k0``."""
+        return (
+            pltpu.make_async_copy(
+                t_hbm.at[inst, win, pl.ds(k0, H)], t_buf.at[slot], sem.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                tc_hbm.at[inst, win + d, pl.ds(k0, H)], c_buf.at[slot],
+                sem.at[1, slot],
+            ),
         )
-        return cmin, carg
 
-    if R - 1 <= cand_tile:
-        # static path: one tile over k = c - 1 in [0, R); c = R never lives.
-        det, argc = chunk(0, R)
-    else:
-        # banded scan over cand_tile-row chunks from the live band's first
-        # row, aligned down to the sublane tile.
-        k_first = ((c_min - 1) // _SUBLANES) * _SUBLANES
-        n_chunks = jnp.where(
-            c_min <= b, (b - k_first + cand_tile - 1) // cand_tile, 0
-        )
+    # The copies run one chunk ahead of the scan, across programs too: the
+    # last chunk of a program starts the first chunk of the next live
+    # program in grid order, (i, a + 1), or (i + 1, 0) after the diagonal's
+    # last window.  Program (0, 0) is always live and starts its own.
+    @pl.when((i == 0) & (a == 0))
+    def _():
+        slot_ref[0] = 0
+        for cp in copies(0, 0, chunk_base(band(0)[0], 0), 0):
+            cp.start()
+
+    k_first, n_chunks = band(a)
+
+    # a dead program (a + d >= R) has no chunk: it copies and computes
+    # nothing, and the host-side scatter drops its output blocks
+    @pl.when(n_chunks > 0)
+    def _():
+        b = a + d
+        base = i * R
+        u = u_ref[i]
+        nl_a = nl_ref[base + a]
+        x_b = x_ref[base + b]
+        r_b = right_ref[base + b]
+        r_bm1 = right_ref[base + b - 1]
+        l_b = left_ref[base + b]
+        svec = jax.lax.broadcasted_iota(dtype, (1, S), 1)
+
+        # -------------- min over detour_c, banded to a < c <= b ------------
+        # Live candidates: c in [c_min, b] (module docstring).  Band rows
+        # outside them hold other cells' values, so they compute harmless
+        # integers before the mask replaces them.
+        c_min = _c_min(a, b, span, disjoint, jnp)
+
+        def chunk(slot, k0):
+            """Fold candidates ``c = k0 + 1 + j``, ``j in [0, H)``, masked to
+            the live band: ``(min, smallest minimising c)``, each ``[1, S]``."""
+            t_left = t_buf[slot]  # T[a, c-1, s]
+            t_right = c_buf[slot]  # T[c, b, s]
+            r_cm1 = rk_ref[0, pl.ds(k0, H), :]  # [H, 1]
+            nl_c = nk_ref[0, pl.ds(k0, H), :]  # [H, 1]
+            # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c)
+            # as one affine function of s per row: a multiply and an add per
+            # element
+            slope = two * (r_b - r_cm1 + u)
+            offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
+            cand = _add_sat(t_left + t_right, svec * slope + offset, big)
+            cvec = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) + (k0 + 1)
+            cand = jnp.where((cvec >= c_min) & (cvec <= b), cand, big)
+            cmin = jnp.min(cand, axis=0, keepdims=True)
+            # first minimiser == smallest c, matching the exact DP's
+            # ascending-c strict-improvement scan
+            carg = jnp.min(
+                jnp.where(cand == cmin, cvec, jnp.iinfo(jnp.int32).max),
+                axis=0,
+                keepdims=True,
+            )
+            return cmin, carg
+
+        s0 = slot_ref[0]
+        last = b + 1 >= R
+        next_i = jnp.where(last, i + 1, i)
+        next_a = jnp.where(last, 0, a + 1)
 
         def body(j, carry):
             run_min, run_arg = carry
             j = jnp.asarray(j, jnp.int32)  # fori_loop index may be int64 (x64)
-            k0 = jnp.minimum(k_first + j * cand_tile, R - cand_tile)
-            cmin, carg = chunk(pl.multiple_of(k0, _SUBLANES), cand_tile)
+            slot = (s0 + j) % 2
+            k0 = chunk_base(k_first, j)
+
+            @pl.when(j + 1 < n_chunks)
+            def _():
+                for cp in copies(i, a, chunk_base(k_first, j + 1), 1 - slot):
+                    cp.start()
+
+            @pl.when((j + 1 == n_chunks) & (next_i < B))
+            def _():
+                slot_ref[0] = 1 - slot
+                k0_next = chunk_base(band(next_a)[0], 0)
+                for cp in copies(next_i, next_a, k0_next, 1 - slot):
+                    cp.start()
+
+            for cp in copies(i, a, k0, slot):
+                cp.wait()
+            cmin, carg = chunk(slot, k0)
             improve = cmin < run_min
             return jnp.minimum(run_min, cmin), jnp.where(improve, carg, run_arg)
 
@@ -277,24 +380,42 @@ def wavefront_kernel(
             (jnp.full((1, S), big, dtype), jnp.zeros((1, S), jnp.int32)),
         )
 
-    val_ref[0, 0] = jnp.minimum(jnp.minimum(skip, det), cap)
-    cho_ref[0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
+        # -------------- skip(a, b, s) --------------------------------------
+        # T[a, b-1, min(s + x_b, S-1)] from the band's last row, still in the
+        # last chunk's slot: rotate it left by x_b lanes, then put row[S-1]
+        # wherever s + x_b ran past the end (the clamp).
+        j_last = n_chunks - 1
+        k_last = chunk_base(k_first, j_last)
+        row_bm1 = t_buf[(s0 + j_last) % 2, pl.ds(b - 1 - k_last, 1), :]  # [1, S]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        rolled = pltpu.roll(row_bm1, (S - x_b) % S, 1)
+        row_end = jnp.max(
+            jnp.where(lane == S - 1, row_bm1, -big), axis=1, keepdims=True
+        )
+        shifted = jnp.where(lane + x_b <= S - 1, rolled, row_end)
+        skip = _add_sat(
+            _add_sat(shifted, two * (r_b - r_bm1) * (svec + nl_a), big),
+            two * (l_b - r_bm1) * x_b.astype(dtype),
+            big,
+        )
+        val_ref[0, 0] = jnp.minimum(jnp.minimum(skip, det), cap)
+        cho_ref[0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
 
 
 #: scoped-VMEM limit Mosaic applies on v5e when a kernel asks for none.
 _DEFAULT_SCOPED_VMEM = 16 << 20
 
 
-def _vmem_limit_bytes(R: int, S: int, itemsize: int, cand_tile: int) -> int | None:
+def _vmem_limit_bytes(R: int, S: int, itemsize: int, H: int) -> int | None:
     """Scoped-VMEM request for one program, or ``None`` for the compiler's
     default where that suffices.
 
-    A program holds its row and column blocks, double-buffered
-    (``4 R S itemsize``), plus about four ``[cand_tile, S]`` int32 candidate
-    temporaries.  At ``(R, S) = (256, 4096)`` that is 24 MiB; compiling for
-    v5e refuses 20 MiB and accepts 22 MiB.
+    A program holds two slots of ``[H, S]`` band rows of each table
+    (``4 H S itemsize``), about four ``[H, S]`` int32 candidate temporaries,
+    its two ``(1, S)`` output blocks and its two ``[R, 1]`` candidate
+    columns (lane-padded to 128), each double-buffered.
     """
-    need = 4 * R * S * itemsize + 4 * min(R, cand_tile) * S * 4
+    need = 4 * H * S * itemsize + 4 * H * S * 4 + 2 * 2 * S * 4 + 2 * 2 * R * 128 * 4
     if need <= _DEFAULT_SCOPED_VMEM:
         return None
     return -(-need // (1 << 20)) << 20
@@ -318,33 +439,25 @@ def ltsp_dp_wavefront(
 ) -> tuple[jax.Array, jax.Array]:
     """One anti-diagonal for every instance: ``([B, R, S], [B, R, S])``.
 
-    ``d`` rides as a scalar-prefetch operand so the column BlockSpec can DMA
-    exactly the ``Tc[i, a + d]`` row each program reads; neither table is
-    ever mapped whole into VMEM.
+    ``T`` and ``Tc`` stay in HBM; each program copies only its band's rows
+    of ``T[i, a]`` and ``Tc[i, a + d]`` (module docstring).
     """
     B, R = left.shape
-    # the banded scan's chunk bases (last one clamped to R - cand_tile) must
-    # be sublane-aligned, which the kernel asserts to Mosaic
-    banded = R - 1 > cand_tile
-    if not interpret and banded and (cand_tile % _SUBLANES or R % _SUBLANES):
+    H = chunk_rows(R, span, cand_tile)
+    # chunk bases (the last one clamped to R - H) must be sublane-aligned,
+    # which the kernel asserts to Mosaic; a single chunk of R rows is at 0
+    if not interpret and H < R and (H % _SUBLANES or R % _SUBLANES):
         raise ValueError(
             f"compiled banded wavefront needs cand_tile and R multiples of "
             f"{_SUBLANES}, got cand_tile={cand_tile}, R={R}"
         )
-    kern = functools.partial(
-        wavefront_kernel, S=S, span=span, disjoint=disjoint, cand_tile=cand_tile
-    )
+    kern = functools.partial(wavefront_kernel, S=S, span=span, disjoint=disjoint)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,  # d, u, left, right, x, nl
         grid=(B, R),
         in_specs=[
-            # row a of T
-            pl.BlockSpec((1, 1, R, S), lambda i, a, d, *_: (i, a, 0, 0)),
-            # row b = min(a + d, R - 1) of Tc, i.e. column b of T
-            pl.BlockSpec(
-                (1, 1, R, S),
-                lambda i, a, d, *_: (i, jnp.minimum(a + d[0], R - 1), 0, 0),
-            ),
+            pl.BlockSpec(memory_space=pl.ANY),  # T, copied by band
+            pl.BlockSpec(memory_space=pl.ANY),  # Tc, copied by band
             pl.BlockSpec((1, R, 1), lambda i, a, *_: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i, a, *_: (i, 0, 0)),
         ],
@@ -352,15 +465,20 @@ def ltsp_dp_wavefront(
             pl.BlockSpec((1, 1, 1, S), lambda i, a, *_: (i, a, 0, 0)),
             pl.BlockSpec((1, 1, 1, S), lambda i, a, *_: (i, a, 0, 0)),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((2, H, S), T.dtype),
+            pltpu.VMEM((2, H, S), T.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
     )
     kwargs = {}
     if not interpret:
-        # dimension_semantics audit (see module docstring): both grid dims are
-        # data-parallel within one diagonal launch — disjoint writes, reads
-        # only of diagonals < d.
+        # dimension_semantics audit (see module docstring): the copies run
+        # ahead across programs in grid order, so neither dim is split
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=_vmem_limit_bytes(R, S, T.dtype.itemsize, cand_tile),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(R, S, T.dtype.itemsize, H),
         )
     nk = jnp.concatenate([nl[:, 1:], jnp.zeros_like(nl[:, :1])], axis=1)
     vals, chos = pl.pallas_call(
@@ -413,7 +531,7 @@ def ltsp_dp_tables(
     one-row-shifted copy the column reads use, see the module docstring);
     each iteration is one Pallas launch over the ``(instance, window-start)``
     grid plus in-place diagonal scatters (``mode="drop"`` discards the
-    clamped windows past the diagonal's end).  ``interpret`` has no default:
+    output blocks of the windows past the diagonal's end).  ``interpret`` has no default:
     every caller says whether it runs the compiled kernel or the interpreter.
     """
     B, R = left.shape

@@ -87,7 +87,7 @@ import numpy as np
 
 from ...core.instance import Instance, virtual_lb
 from ...core.warm import DenseStore, WarmState, WarmStats, align_warm, warm_from_instance
-from .ltsp_dp import DEFAULT_CAND_TILE, INT32_CAP, ltsp_dp_tables
+from .ltsp_dp import DEFAULT_CAND_TILE, INT32_CAP, band_copy_bytes, ltsp_dp_tables
 from .walk import traceback_device
 
 __all__ = [
@@ -498,6 +498,9 @@ def _solve_packed(
             h2d_bytes=h2d,
             d2h_bytes=d2h,
             walk_steps=int(steps.sum()),
+            operand_bytes=band_copy_bytes(
+                B, R, S, np.dtype(dtype).itemsize, span, disjoint, cand_tile
+            ),
         )
     return out, stores
 

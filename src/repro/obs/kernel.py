@@ -20,7 +20,10 @@ attached through ``ExecutionContext.obs`` records one
   detours, detour counts, root values and step counts, and both dense
   planes when captured);
 * ``walk_steps`` — the loop steps of the device traceback, summed over the
-  launch's rows (all-phantom padding rows included).
+  launch's rows (all-phantom padding rows included);
+* ``operand_bytes`` — the exact HBM bytes the wavefront's band copies of
+  its two table operands move over the launch's diagonals
+  (``ltsp_dp.band_copy_bytes``), counted from the launch's shape.
 
 Time comes from :meth:`KernelProfile.span`: the device path opens one
 ``jax.profiler.TraceAnnotation`` per phase (``ltsp.rescale`` …
@@ -52,6 +55,7 @@ class LaunchRecord:
     h2d_bytes: int
     d2h_bytes: int
     walk_steps: int = 0
+    operand_bytes: int = 0
 
     @property
     def waste(self) -> tuple[int, int]:
@@ -85,6 +89,7 @@ class KernelProfile:
         h2d_bytes: int,
         d2h_bytes: int,
         walk_steps: int = 0,
+        operand_bytes: int = 0,
     ) -> None:
         cold = signature not in self._seen
         self._seen.add(signature)
@@ -101,6 +106,7 @@ class KernelProfile:
                 h2d_bytes=h2d_bytes,
                 d2h_bytes=d2h_bytes,
                 walk_steps=walk_steps,
+                operand_bytes=operand_bytes,
             )
         )
 
@@ -125,6 +131,7 @@ class KernelProfile:
             "h2d_bytes": sum(r.h2d_bytes for r in self.launches),
             "d2h_bytes": sum(r.d2h_bytes for r in self.launches),
             "walk_steps": sum(r.walk_steps for r in self.launches),
+            "operand_bytes": sum(r.operand_bytes for r in self.launches),
         }
 
     def __len__(self) -> int:

@@ -5,9 +5,11 @@ interpret=False)`` against a described ``v5e:2x2`` topology and refuses what
 the chip would refuse (illegal block shapes, unaligned dynamic slices, scoped
 VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
 median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
-disjoint variants, and the banded scan at a 16-row candidate tile; and the
-paper's tail bucket (256, 8192), which the chip benchmark runs.  Each
-program must fit the chip's 16 GB of HBM.  The device traceback that walks
+disjoint variants, and the band in 16-row chunks; the paper's tail bucket
+(256, 8192), which the chip benchmark runs, exact and under a LOGDP span;
+and R = 128, whose bands are chunked like the large buckets'.  Each program
+must fit the chip's 16 GB of HBM, and the kernel's scoped VMEM what the
+wavefront asks for (``_vmem_limit_bytes``).  The device traceback that walks
 the argmin plane compiles at the median bucket too, holding no copy of it.
 
 The topology is described inside a module fixture, never at import time, so
@@ -16,12 +18,19 @@ file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+from repro.kernels.ltsp_dp.ltsp_dp import (
+    _DEFAULT_SCOPED_VMEM,
+    DEFAULT_CAND_TILE,
+    _vmem_limit_bytes,
+    chunk_rows,
+    ltsp_dp_tables,
+)
 from repro.kernels.ltsp_dp.walk import traceback_device
 
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
@@ -57,6 +66,8 @@ def one_chip():
         pytest.param(2, 64, 1024, {"disjoint": True}, id="simpledp-disjoint-B2-R64"),
         pytest.param(1, 256, 4096, {"span": 40}, id="logdp-span40-B1-R256"),
         pytest.param(1, 256, 8192, {}, id="paper-tail-B1-R256-S8192"),
+        pytest.param(1, 256, 8192, {"span": 5}, id="logdp-span5-B1-R256-S8192"),
+        pytest.param(1, 128, 4096, {}, id="banded-B1-R128-S4096"),
     ],
 )
 def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
@@ -76,6 +87,19 @@ def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
     # the outputs alone are T and C, B*R*R*S int32 each
     assert ma.output_size_in_bytes >= 2 * B * R * R * S * 4
     assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one v5e"
+    H = chunk_rows(R, kw.get("span"), kw.get("cand_tile", DEFAULT_CAND_TILE))
+    limit = _vmem_limit_bytes(R, S, 4, H) or _DEFAULT_SCOPED_VMEM
+    vmem = _kernel_scoped_vmem(compiled.as_text())
+    assert 0 < vmem <= limit, (vmem, limit)
+
+
+def _kernel_scoped_vmem(hlo: str) -> int:
+    """Scoped VMEM (memory space 1) the compiled wavefront uses, from its
+    custom call's backend config."""
+    [line] = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    configs = re.search(r'"used_scoped_memory_configs":\[(.*?)\]', line).group(1)
+    return sum(int(size) for size in re.findall(
+        r'"memory_space":"1","offset":"\d+","size":"(\d+)"', configs))
 
 
 def test_device_walk_compiles_for_v5e_without_a_copy_of_the_plane(one_chip):
