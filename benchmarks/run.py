@@ -6,8 +6,8 @@ Paper artefacts reproduced (on the synthetic IN2P3-calibrated dataset):
   * ``bench_performance_profiles``  — Figures 14/15/16: performance profiles
     of all registered policies at U in {0, seg/2, seg}.
   * ``bench_time_to_solution``      — §5.3 running-time table.
-  * ``bench_kernel_wavefront``      — wavefront DP device throughput (jnp ref
-    jitted + the single-trace Pallas wavefront in interpret mode).
+  * ``bench_kernel_wavefront``      — wavefront DP table builds (the NumPy
+    reference + the single-trace Pallas wavefront in interpret mode).
   * ``bench_solve_batch``           — padded multi-instance device launch vs
     per-instance python solving (parity-checked).
   * ``bench_hetero_batch``          — heterogeneous (mixed-size) batch: the
@@ -334,35 +334,31 @@ def _small_bench_instance(rng, R):
 
 
 def bench_kernel_wavefront(full: bool = False):
-    """Wavefront DP device throughput: jnp reference (jitted) and the
-    single-trace Pallas wavefront (interpret mode is correctness-only on
-    CPU, so its time measures one full table build, not TPU speed)."""
+    """Wavefront DP table builds: the NumPy reference and the single-trace
+    Pallas wavefront (interpret mode is correctness-only on CPU, so its time
+    measures one full table build, not TPU speed)."""
     import jax
-    import jax.numpy as jnp
 
     from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
-    from repro.kernels.ltsp_dp.ops import prepare_arrays
-    from repro.kernels.ltsp_dp.ref import ltsp_dp_table_ref
+    from repro.kernels.ltsp_dp.ops import prepare_batch
+    from repro.kernels.ltsp_dp.ref import ltsp_tables_ref
 
     rng = np.random.default_rng(0)
     R = 24 if not full else 48
     inst = _small_bench_instance(rng, R)
-    l, r, x, nl, S = prepare_arrays(inst)
+    packed = prepare_batch([inst])
+    S = packed[-1]
+    host = [np.asarray(v[0]) for v in packed[:-1]]
 
-    fn = jax.jit(lambda: ltsp_dp_table_ref(l, r, x, nl, float(inst.u_turn), S))
-    fn()  # compile
     t0 = time.perf_counter()
     n_rep = 3
     for _ in range(n_rep):
-        fn().block_until_ready()
+        ltsp_tables_ref(*host, S)
     dt = (time.perf_counter() - t0) / n_rep
     cells = R * R * S / 2
     _emit("kernel/wavefront_ref", dt * 1e6, f"R={R};S={S};cells_per_s={cells/dt:.3g}")
 
-    u = jnp.asarray([float(inst.u_turn)], l.dtype)
-    pf = lambda: ltsp_dp_tables(
-        l[None], r[None], x[None], nl[None], u, S=S, interpret=True
-    )
+    pf = lambda: ltsp_dp_tables(*packed[:-1], S=S, interpret=True)
     T, _ = pf()  # compile (single trace: one retrace total, not R)
     t0 = time.perf_counter()
     T, C = pf()

@@ -12,10 +12,11 @@ its sizes, the cuts made, compile and steady wall times, and its checks:
   must equal the detours' evaluated cost, pass the schedule oracle, and be no
   worse than NFGS and GS.
 * B: small seeded tapes from the same profile, several buckets with B > 1,
-  once per U-turn value of paper section 5.3 and per device-capable policy
-  (plus the exact DP with a 16-row candidate tile, so the banded scan runs),
-  each set in one ``solve_batch`` on ``"pallas"``; every ``(cost, detours)``
-  must equal the exact Python DP (``repro.core.dp``).
+  once per U-turn value of paper section 5.3 and per device-capable policy,
+  each set in one ``solve_batch`` on ``"pallas"``, plus the exact DP through
+  ``ops.ltsp_solve_batch`` with a 16-row candidate tile, so the banded scan
+  runs in more chunks; every ``(cost, detours)`` must equal the exact Python
+  DP (``repro.core.dp``).
 * C: ``serve_trace`` with the ``batched`` admission on a two-drive pool; the
   served timeline must equal the ``"python"`` run exactly, and the kernel
   profile must show device launches and none in interpret mode.
@@ -139,6 +140,7 @@ def _phase_b_tapes() -> list[int]:
 def phase_b() -> None:
     """Small tapes, B > 1 buckets, every U and device policy vs core/dp.py."""
     from repro.core import ExecutionContext, get_solver, list_solvers, solve_batch
+    from repro.kernels.ltsp_dp import ops
     from repro.kernels.ltsp_dp.ops import bucket_shape
 
     idxs = _phase_b_tapes()
@@ -161,17 +163,20 @@ def phase_b() -> None:
         insts = [tapes[i] for i in idxs]
         refs: dict[str, list] = {}
         for policy, tile in runs:
-            ctx = ExecutionContext(backend=BACKEND, cand_tile=tile)
-            dev, dt1 = _timed(lambda: solve_batch(insts, policy, context=ctx))
-            again, dt2 = _timed(lambda: solve_batch(insts, policy, context=ctx))
+            if tile is None:
+                ctx = ExecutionContext(backend=BACKEND)
+                run = lambda: [(r.cost, r.detours)
+                               for r in solve_batch(insts, policy, context=ctx)]
+            else:
+                run = lambda: ops.ltsp_solve_batch(insts, interpret=False, cand_tile=tile)
+            got, dt1 = _timed(run)
+            again, dt2 = _timed(run)
             t_first += dt1
             t_steady += dt2
             if policy not in refs:
-                refs[policy] = solve_batch(insts, policy)
-            got = [(r.cost, r.detours) for r in dev]
+                refs[policy] = [(r.cost, r.detours) for r in solve_batch(insts, policy)]
             check(
-                got == [(r.cost, r.detours) for r in refs[policy]]
-                and got == [(r.cost, r.detours) for r in again],
+                got == refs[policy] and got == again,
                 f"U={name}({u}) {policy}"
                 f"{'' if tile is None else f' cand_tile={tile}'}: "
                 f"{len(insts)} (cost, detours) == core/dp.py",

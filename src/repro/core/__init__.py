@@ -2,7 +2,7 @@
 
 Scheduling dispatch goes through the solver engine (:mod:`.solver`): pick a
 *policy* (algorithm) and an :class:`ExecutionContext` (backend, solve memo,
-bucketing/numeric options — see :mod:`.context`) via
+bucketing and budget options — see :mod:`.context`) via
 :func:`solve`/:func:`solve_batch`, or register new policies with
 :func:`repro.core.solver.register_solver`.  The legacy ``ALGORITHMS`` mapping
 is a thin read-only view over the registry; pre-context ``backend=``/
@@ -12,7 +12,6 @@ is a thin read-only view over the registry; pre-context ``backend=``/
 from .context import (
     DEFAULT_BUDGET,
     DEFAULT_CONTEXT,
-    NUMERIC_POLICIES,
     ComputeBudget,
     ExecutionContext,
     FleetOptions,
@@ -66,7 +65,6 @@ from .warm import WarmState, WarmStats
 __all__ = [
     "ExecutionContext",
     "DEFAULT_CONTEXT",
-    "NUMERIC_POLICIES",
     "ComputeBudget",
     "DEFAULT_BUDGET",
     "FleetOptions",

@@ -11,9 +11,9 @@ ship:
   journal on disk, so a restarted serving fleet rewarms its memo from prior
   runs instead of re-solving every cartridge from scratch.
 
-Every backend memoises *exact* results keyed by the canonicalized request
-multiset plus the result-affecting execution fingerprint (see the
-:mod:`repro.core.solver` docstring for the key layout), so swapping
+Every backend memoises *exact* results keyed by the policy, the backend and
+the canonicalized request multiset (see the :mod:`repro.core.solver`
+docstring for the key layout), so swapping
 backends — or bounding one below the working set — can change wall time but
 never a schedule.
 
@@ -29,8 +29,10 @@ cartridge.
 JSONL journal format: one object per line, ``{"k": [...], "cost": int,
 "det": [[c, b], ...]}`` with byte-valued key fields hex-encoded.  Appends
 are flushed per put; loading replays the journal in order (later lines win)
-into the LRU, and :meth:`JsonlCacheBackend.compact` rewrites the file to
-the live entries when restarts have piled up superseded lines.
+into the LRU, skipping torn lines and foreign ones (a key of another
+layout, such as an older journal's 9-field key, could never hit again), and
+:meth:`JsonlCacheBackend.compact` rewrites the file to the live entries when
+restarts have piled up superseded lines.
 """
 
 from __future__ import annotations
@@ -76,6 +78,10 @@ def _pid_alive(pid: int) -> bool:
     return True
 
 
+#: fields of a :meth:`SolveCache.key`: policy, backend, m, u_turn and the
+#: three arrays
+_KEY_FIELDS = 7
+
 #: journal paths (absolute) held open for writing by this process; guards
 #: the same-process two-writer case the pid probe cannot distinguish.
 _OPEN_JOURNALS: set[str] = set()
@@ -83,31 +89,13 @@ _OPEN_JOURNALS: set[str] = set()
 
 @runtime_checkable
 class CacheBackend(Protocol):
-    """Structural protocol every solve-memo backend implements.
+    """Structural protocol every solve-memo backend implements."""
 
-    ``numeric_policy``/``cand_tile`` default to the
-    :data:`~repro.core.context.DEFAULT_CONTEXT` values so pre-protocol
-    call sites (``cache.get(inst, policy, backend)``) keep working.
-    """
-
-    def get(
-        self,
-        inst: Instance,
-        policy: str,
-        backend: str,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
-    ) -> SolveResult | None:
+    def get(self, inst: Instance, policy: str, backend: str) -> SolveResult | None:
         """The memoised result for this key, or ``None`` (counts a miss)."""
 
     def put(
-        self,
-        inst: Instance,
-        policy: str,
-        backend: str,
-        res: SolveResult,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
+        self, inst: Instance, policy: str, backend: str, res: SolveResult
     ) -> None:
         """Memoise ``res`` under this key (evicting LRU entries if bounded)."""
 
@@ -177,20 +165,16 @@ class JsonlCacheBackend(SolveCache):
     def _decode_key(fields: list) -> tuple:
         # positional layout from SolveCache.key: the last three fields are
         # the hex-encoded left/right/mult array bytes
+        if len(fields) != _KEY_FIELDS:
+            raise ValueError(f"a key of {len(fields)} fields, not {_KEY_FIELDS}")
         head = [tuple(v) if isinstance(v, list) else v for v in fields[:-3]]
         return tuple(head) + tuple(bytes.fromhex(v) for v in fields[-3:])
 
     def put(
-        self,
-        inst: Instance,
-        policy: str,
-        backend: str,
-        res: SolveResult,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
+        self, inst: Instance, policy: str, backend: str, res: SolveResult
     ) -> None:
-        super().put(inst, policy, backend, res, numeric_policy, cand_tile)
-        key = self.key(inst, policy, backend, numeric_policy, cand_tile)
+        super().put(inst, policy, backend, res)
+        key = self.key(inst, policy, backend)
         row = {
             "k": self._encode_key(key),
             "cost": res.cost,

@@ -3,8 +3,8 @@
 The scheduling API used to thread ``backend=str`` and ``cache=SolveCache``
 positionally through every layer (solver engine → tape library → serving
 queue → checkpoint restore → benchmarks → launchers), and each new execution
-option (bucketing, numeric policy, …) meant another keyword replicated across
-a dozen signatures.  :class:`ExecutionContext` bundles all of it:
+option (bucketing, budgets, …) meant another keyword replicated across a
+dozen signatures.  :class:`ExecutionContext` bundles all of it:
 
 * ``backend`` — execution engine: ``"python"`` (exact CPU, default),
   ``"pallas"`` (compiled TPU wavefront), ``"pallas-interpret"`` (same kernel
@@ -17,13 +17,6 @@ a dozen signatures.  :class:`ExecutionContext` bundles all of it:
 * ``bucketed`` — whether device batches go through the size-bucketed launch
   planner (``False`` reproduces the seed's single maximally-padded launch,
   kept for A/B benchmarking);
-* ``cand_tile`` — chunk-height override for the wavefront's band copies and
-  candidate scan (``None`` = kernel default; a LOGDP span sets its own);
-* ``numeric_policy`` — what to do when an instance fails the int32 device
-  magnitude guard *after* gcd/shift rescaling: ``"strict"`` raises (default),
-  ``"f64"`` falls back to an exact float64 interpret-mode table for just the
-  failing instances (exact while every table value stays below 2**53;
-  under ``backend="pallas"`` such an instance raises instead);
 * ``budget`` — an optional :class:`ComputeBudget` making solver compute a
   *priced* resource for the serving loop: how much virtual time one DP cell
   costs (so dispatches charge their solve work into the timeline), the
@@ -40,11 +33,15 @@ a dozen signatures.  :class:`ExecutionContext` bundles all of it:
   over already-computed exact integers, so instrumented and uninstrumented
   runs are bit-identical.
 
+The device kernel's own choices — its int32 tables and the chunk height of
+its candidate band — are not options here: :mod:`repro.kernels.ltsp_dp`
+makes them.
+
 Contexts are frozen: derive variants with :meth:`ExecutionContext.replace`::
 
     ctx = ExecutionContext(backend="pallas-interpret", cache=SolveCache())
     res = solve(inst, policy="dp", context=ctx)
-    strict = ctx.replace(numeric_policy="strict")
+    compiled = ctx.replace(backend="pallas")
 
 Every public scheduling entry point (``solve``/``solve_batch``, ``Solver``
 implementations, ``TapeLibrary``, ``schedule_reads``, ``plan_restore``,
@@ -67,7 +64,6 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guard (solver imports us)
 __all__ = [
     "BACKENDS",
     "DEFAULT_BACKEND",
-    "NUMERIC_POLICIES",
     "ComputeBudget",
     "DEFAULT_BUDGET",
     "FleetOptions",
@@ -78,9 +74,6 @@ __all__ = [
 
 BACKENDS = ("python", "pallas", "pallas-interpret")
 DEFAULT_BACKEND = "python"
-
-#: int32-guard-failure handling: raise, or fall back to exact f64 interpret.
-NUMERIC_POLICIES = ("strict", "f64")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,8 +186,6 @@ class ExecutionContext:
     backend: str = DEFAULT_BACKEND
     cache: "CacheBackend | None" = None
     bucketed: bool = True
-    cand_tile: int | None = None
-    numeric_policy: str = "strict"
     budget: ComputeBudget | None = None
     fleet: FleetOptions | None = None
     obs: "Observability | None" = None
@@ -204,13 +195,6 @@ class ExecutionContext:
             raise KeyError(
                 f"unknown backend {self.backend!r}; choose from {BACKENDS}"
             )
-        if self.numeric_policy not in NUMERIC_POLICIES:
-            raise ValueError(
-                f"unknown numeric_policy {self.numeric_policy!r}; "
-                f"choose from {NUMERIC_POLICIES}"
-            )
-        if self.cand_tile is not None and self.cand_tile < 1:
-            raise ValueError("cand_tile must be >= 1 (or None for the default)")
         if self.budget is not None and not isinstance(self.budget, ComputeBudget):
             raise TypeError(f"budget must be a ComputeBudget, got {self.budget!r}")
         if self.fleet is not None and not isinstance(self.fleet, FleetOptions):
@@ -230,7 +214,7 @@ class ExecutionContext:
         return dataclasses.replace(self, **changes)
 
 
-#: The default context: python backend, no cache, bucketed, strict numerics.
+#: The default context: python backend, no cache, bucketed.
 DEFAULT_CONTEXT = ExecutionContext()
 
 

@@ -5,7 +5,7 @@ Every scheduling caller in the repo (``storage/tape.py``, ``serving/queue.py``,
 this module instead of a flat name→lambda dict.  A *policy* names an algorithm
 from the paper (``"dp"``, ``"simpledp"``, ``"logdp1"``, heuristics …); an
 :class:`~repro.core.context.ExecutionContext` says *how* to run it — which
-backend, which solve memo, bucketing and numeric options:
+backend, which solve memo, whether to bucket device launches:
 
 * ``"python"`` — exact Python-int CPU implementation (default, always
   available, arbitrary magnitudes);
@@ -58,28 +58,22 @@ previous runs.  Backends only ever memoise exact results, so swapping one
 for another (or bounding one below the working set) changes wall time, never
 a single schedule — asserted in the cache-eviction serving tests.
 
-The cache key is the **canonicalized request multiset** plus the full
-result-affecting execution fingerprint: ``(policy, backend, numeric_policy,
-cand_tile, m, u_turn, left.tobytes(), right.tobytes(), mult.tobytes())``.
-An :class:`~repro.core.instance.Instance` already stores requested files
-sorted by position with aggregated multiplicities, so two request batches
-that read the same files the same number of times on the same cartridge
-canonicalize to the same key regardless of arrival order.  The key captures
-array *contents* at call time and hits return a fresh :class:`SolveResult`
-(detours copied), so mutating an instance or a returned schedule never
-aliases into — or invalidates silently — a cached entry.  ``backend`` is
-part of the key because a hit reports the backend that actually computed
-it.  ``numeric_policy`` and ``cand_tile`` are part of the key for the same
-provenance reason, with a sharper edge: every backend/policy/tile
-combination is bit-identical *where it computes at all*, but their error
-domains differ — a strict-policy call must raise the int32-guard error on a
-wide instance, not silently consume a result an f64-policy call cached
-earlier, and a cached result must never claim it was computed under a tile
-configuration that never ran.  (Earlier revisions deliberately excluded
-both; the serving stack now distinguishes numeric configurations per
-cartridge, so the aliasing became an observable bug.)  Only ``bucketed``
-stays out of the key: it is launch *packing*, invisible in the result and
-carrying no error-domain of its own.
+The cache key is the policy, the backend and the **canonicalized request
+multiset**: ``(policy, backend, m, u_turn, left.tobytes(), right.tobytes(),
+mult.tobytes())``.  An :class:`~repro.core.instance.Instance` already stores
+requested files sorted by position with aggregated multiplicities, so two
+request batches that read the same files the same number of times on the
+same cartridge canonicalize to the same key regardless of arrival order.
+The key captures array *contents* at call time and hits return a fresh
+:class:`SolveResult` (detours copied), so mutating an instance or a returned
+schedule never aliases into — or invalidates silently — a cached entry.
+Every backend gives the same answer where it computes at all; ``backend``
+is in the key for provenance, because a hit reports the backend that
+actually computed it, and so that a device call still refuses what the
+int32 guard refuses instead of consuming a result the python backend cached.
+``bucketed`` stays out of the key: it is launch *packing*, invisible in the
+result.  The kernel's own choices (its int32 tables, the chunk height of its
+band) are not execution options at all.
 
 The legacy ``ALGORITHMS`` mapping is kept as a read-only view over the
 registry (name → ``inst -> detours`` callable) for downstream code that only
@@ -333,8 +327,8 @@ class SolveCache:
     """Bounded LRU memo of solved instances (see the module docstring).
 
     The reference :class:`~repro.core.cache.CacheBackend` implementation.
-    Keys canonicalize the request multiset plus ``(policy, backend,
-    numeric_policy, cand_tile)``; values are immutable snapshots (detours
+    Keys canonicalize the request multiset plus ``(policy, backend)``;
+    values are immutable snapshots (detours
     stored as tuples), re-materialised into a fresh :class:`SolveResult` on
     every hit.  ``hits``/``misses`` counters feed the benchmark summaries.
     A separate, independently bounded LRU side-table carries per-cartridge
@@ -356,19 +350,11 @@ class SolveCache:
         self.obs = None
 
     @staticmethod
-    def key(
-        inst: Instance,
-        policy: str,
-        backend: str,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
-    ) -> tuple:
+    def key(inst: Instance, policy: str, backend: str) -> tuple:
         """Canonical cache key; captures array contents at call time."""
         return (
             policy,
             backend,
-            numeric_policy,
-            cand_tile,
             inst.m,
             inst.u_turn,
             inst.left.tobytes(),
@@ -379,15 +365,8 @@ class SolveCache:
     def __len__(self) -> int:
         return len(self._store)
 
-    def get(
-        self,
-        inst: Instance,
-        policy: str,
-        backend: str,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
-    ) -> SolveResult | None:
-        key = self.key(inst, policy, backend, numeric_policy, cand_tile)
+    def get(self, inst: Instance, policy: str, backend: str) -> SolveResult | None:
+        key = self.key(inst, policy, backend)
         entry = self._store.get(key)
         if entry is None:
             self.misses += 1
@@ -402,15 +381,9 @@ class SolveCache:
         return SolveResult(policy, backend, cost, [tuple(d) for d in detours])
 
     def put(
-        self,
-        inst: Instance,
-        policy: str,
-        backend: str,
-        res: SolveResult,
-        numeric_policy: str = "strict",
-        cand_tile: int | None = None,
+        self, inst: Instance, policy: str, backend: str, res: SolveResult
     ) -> None:
-        key = self.key(inst, policy, backend, numeric_policy, cand_tile)
+        key = self.key(inst, policy, backend)
         self._store[key] = (
             res.cost,
             tuple((int(c), int(b)) for c, b in res.detours),
@@ -468,14 +441,9 @@ def _as_context(context: ExecutionContext | str) -> ExecutionContext:
 
 def _device_kwargs(ctx: ExecutionContext, disjoint: bool = False) -> dict:
     """Kernel options a device-backed solver derives from the context."""
-    kwargs: dict = {
-        "interpret": ctx.backend == "pallas-interpret",
-        "numeric_policy": ctx.numeric_policy,
-    }
+    kwargs: dict = {"interpret": ctx.backend == "pallas-interpret"}
     if disjoint:
         kwargs["disjoint"] = True
-    if ctx.cand_tile is not None:
-        kwargs["cand_tile"] = ctx.cand_tile
     if ctx.obs is not None and ctx.obs.kernel is not None:
         kwargs["profile"] = ctx.obs.kernel
     return kwargs
@@ -575,7 +543,7 @@ class DPSolver:
     ``span_policy`` maps ``n_req`` to the maximum detour span (``None`` =
     unrestricted = exact DP).  All three backends are available; the device
     backends batch by span value so one launch serves every instance that
-    shares a span, and honour the context's bucketing/numeric options.
+    shares a span, and honour the context's bucketing option.
     """
 
     name: str
@@ -807,8 +775,8 @@ def solve(
 ) -> SolveResult:
     """Solve one instance with a registered policy.
 
-    ``context`` carries the execution options (backend, memo cache, bucketing,
-    numeric policy); ``backend=``/``cache=`` are the deprecated pre-context
+    ``context`` carries the execution options (backend, memo cache,
+    bucketing); ``backend=``/``cache=`` are the deprecated pre-context
     spellings and forward into one (with a ``DeprecationWarning``).
     """
     ctx = resolve_context(context, backend=backend, cache=cache)
@@ -816,12 +784,12 @@ def solve(
     _check_backend(solver, ctx.backend)  # before the cache: no miss-count pollution
     memo = ctx.cache
     if memo is not None:
-        hit = memo.get(inst, policy, ctx.backend, ctx.numeric_policy, ctx.cand_tile)
+        hit = memo.get(inst, policy, ctx.backend)
         if hit is not None:
             return hit
     res = solver.solve(inst, ctx)
     if memo is not None:
-        memo.put(inst, policy, ctx.backend, res, ctx.numeric_policy, ctx.cand_tile)
+        memo.put(inst, policy, ctx.backend, res)
     return res
 
 
@@ -843,12 +811,11 @@ def solve_batch(
     An unsupported policy/backend combination raises
     :class:`UnsupportedBackendError` before any instance is solved or any
     cache entry is touched — a batch is all-or-nothing, never mid-flight.
-    One *bad instance* (e.g. an int32-guard overflow under the strict
-    numeric policy) is also all-or-nothing by default, but ``partial=True``
-    relaxes that: the good instances are solved (and cached) normally while
-    each failing one yields a typed :class:`FailedSolve` at its position —
-    failures never pollute the cache, so a later retry (on another backend
-    or numeric policy) starts clean.  ``backend=``/``cache=`` are
+    One *bad instance* (e.g. an int32-guard overflow on a device backend) is
+    also all-or-nothing by default, but ``partial=True`` relaxes that: the
+    good instances are solved (and cached) normally while each failing one
+    yields a typed :class:`FailedSolve` at its position — failures never
+    pollute the cache, so a later retry (on another backend) starts clean.  ``backend=``/``cache=`` are
     deprecation shims, as in :func:`solve`.
     """
     ctx = resolve_context(context, backend=backend, cache=cache)
@@ -858,7 +825,7 @@ def solve_batch(
     if memo is None and not partial:
         return solver.solve_batch(instances, ctx)
     results: list[SolveResult | FailedSolve | None] = [
-        memo.get(inst, policy, ctx.backend, ctx.numeric_policy, ctx.cand_tile)
+        memo.get(inst, policy, ctx.backend)
         if memo is not None
         else None
         for inst in instances
@@ -882,8 +849,7 @@ def solve_batch(
                         solved.append(FailedSolve(policy, ctx.backend, i, err))
         for i, res in zip(miss, solved):
             if isinstance(res, SolveResult) and memo is not None:
-                memo.put(instances[i], policy, ctx.backend, res,
-                         ctx.numeric_policy, ctx.cand_tile)
+                memo.put(instances[i], policy, ctx.backend, res)
             results[i] = res
     return results  # type: ignore[return-value]
 
@@ -912,7 +878,7 @@ def solve_warm(
     _check_backend(solver, ctx.backend)
     memo = ctx.cache
     if memo is not None:
-        hit = memo.get(inst, policy, ctx.backend, ctx.numeric_policy, ctx.cand_tile)
+        hit = memo.get(inst, policy, ctx.backend)
         if hit is not None:
             if ctx.obs is not None:
                 ctx.obs.inc(
@@ -927,7 +893,7 @@ def solve_warm(
             solver.solve(inst, ctx), None, WarmStats(mode="unsupported")
         )
     if memo is not None:
-        memo.put(inst, policy, ctx.backend, res, ctx.numeric_policy, ctx.cand_tile)
+        memo.put(inst, policy, ctx.backend, res)
     if ctx.obs is not None:
         ctx.obs.inc(
             "solves_total", policy=policy, backend=ctx.backend, mode=stats.mode
@@ -960,9 +926,7 @@ def solve_batch_warm(
     stats: list[WarmStats] = [WarmStats(mode="cache") for _ in instances]
     if memo is not None:
         for i, inst in enumerate(instances):
-            results[i] = memo.get(
-                inst, policy, ctx.backend, ctx.numeric_policy, ctx.cand_tile
-            )
+            results[i] = memo.get(inst, policy, ctx.backend)
     miss = [i for i, r in enumerate(results) if r is None]
     if miss:
         if getattr(solver, "supports_warm", False):
@@ -975,8 +939,7 @@ def solve_batch_warm(
             sts = [WarmStats(mode="unsupported") for _ in miss]
         for i, res, w, st in zip(miss, solved, ws, sts):
             if memo is not None:
-                memo.put(instances[i], policy, ctx.backend, res,
-                         ctx.numeric_policy, ctx.cand_tile)
+                memo.put(instances[i], policy, ctx.backend, res)
             results[i], new_warms[i], stats[i] = res, w, st
     if ctx.obs is not None:
         for st in stats:

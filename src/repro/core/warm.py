@@ -36,7 +36,7 @@ Two store layouts back a :class:`WarmState`:
 * :class:`DictStore` — the python DP's sparse ``memo``/``choice`` dicts,
   handed over by reference (no copy);
 * :class:`DenseStore` — the device wavefront's dense value/argmin planes,
-  kept in the kernel's gcd-rescaled int32 (or exact-f64) units together
+  kept in the kernel's gcd-rescaled int32 units together
   with the scale ``g``; lookups rescale to original units with python-int
   arithmetic, so no overflow guard is needed.  Dense cells outside the
   reachable envelope (``s`` too large for the padded skip axis) may hold
@@ -111,7 +111,7 @@ class DenseStore:
     """Dense store: device value/argmin planes in gcd-rescaled units.
 
     ``table``/``choice`` are the ``[R_pad, R_pad, S_pad]`` planes of *one*
-    instance (host numpy, int32 or f64); ``g`` is the
+    instance (host numpy, int32); ``g`` is the
     :func:`repro.kernels.ltsp_dp.ops.rescale_instance` scale, so the
     original-unit value is ``g * int(table[a, b, s])`` (python ints — exact
     at any magnitude).  ``prefix[i] = sum(mult[:i+1])`` bounds the admissible

@@ -115,7 +115,7 @@ def plan_restore(
     pods that need the shard before they can start their reshard step).
     ``policy`` selects any registered solver; ``context`` (an
     :class:`repro.core.ExecutionContext`, defaulting to the library's own)
-    selects backend/cache/numeric options — with the library context carrying
+    selects backend/cache/bucketing options — with the library context carrying
     a :class:`repro.core.SolveCache`, a restore re-planned against an
     unchanged archive is pure cache hits.  Device backends plan every
     cartridge in a few size-bucketed launches.  ``backend=``/``cache=`` are

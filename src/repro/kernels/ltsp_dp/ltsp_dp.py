@@ -94,12 +94,8 @@ Layout notes
 * ``cand_tile`` is the chunk height ``H`` (sublane axis) of the exact DP's
   band, at most ``R``: 32 by default, the fastest of 16, 32 and 64 on a
   v5e at the buckets (256, 4096) and (256, 8192).
-* ``dtype`` is ``float32`` (exact for values < 2**24, the oracle-comparison
-  path), ``int32`` (the solver path, exact under the guard of :mod:`.ops`,
-  see *Saturating sums* below), or ``float64`` (exact for values < 2**53 —
-  the interpret-only numeric fallback in :mod:`.ops` for instances whose
-  coprime byte-scale coordinates fail the int32 guard even after gcd/shift
-  rescaling).
+* The tables are ``int32``, exact under the guard of :mod:`.ops` (see
+  *Saturating sums* below); :func:`ltsp_dp_tables` refuses any other dtype.
 * The ``skip`` term needs the shifted gather ``row[min(s + x_b, S - 1)]``;
   ``x_b`` is a scalar per program, so it is a lane rotation by ``-x_b`` plus
   a mask that substitutes ``row[S - 1]`` where ``s + x_b`` passes the end.
@@ -125,8 +121,7 @@ exactly when its true sum is it, the smallest minimising ``c`` and the
 skip's tie win are unchanged, and the clip to ``INT32_CAP`` leaves the
 result alone.  The lanes past ``n`` of a padded launch may wrap and hold any
 value; they feed none of those cells.  ``big`` stays strictly above every
-cell a reader takes, so a masked candidate row never wins there.  Floats do
-not wrap: they add plainly and clip at infinity.
+cell a reader takes, so a masked candidate row never wins there.
 """
 
 from __future__ import annotations
@@ -161,21 +156,10 @@ _INT32_MAX = 2**31 - 1
 INT32_CAP = _INT32_MAX // 2
 
 
-def _limits(dtype):
-    """``(big, cap)``: where sums clip and where stored values clip."""
-    if dtype == jnp.int32:
-        return jnp.asarray(_INT32_MAX, dtype), jnp.asarray(INT32_CAP, dtype)
-    inf = jnp.asarray(jnp.inf, dtype)
-    return inf, inf
-
-
 def _add_sat(x, y, big):
-    """``x + y`` clipped at ``big``: ``x + min(y, big - x)`` in integers,
-    which never wraps for ``x`` in ``[0, big]`` and ``y >= 0``; plain
-    addition in floats."""
-    if jnp.issubdtype(x.dtype, jnp.integer):
-        return x + jnp.minimum(y, big - x)
-    return x + y
+    """``x + y`` clipped at ``big``: ``x + min(y, big - x)``, which never
+    wraps for ``x`` in ``[0, big]`` and ``y >= 0``."""
+    return x + jnp.minimum(y, big - x)
 
 
 def chunk_rows(R: int, span: int | None, cand_tile: int) -> int:
@@ -233,16 +217,16 @@ def band_copy_bytes(
 def wavefront_kernel(
     # scalar-prefetch inputs (SMEM)
     d_ref,  # [1] int32 — current anti-diagonal
-    u_ref,  # [B] dtype — U-turn penalty per instance
-    left_ref,  # [B * R] dtype
-    right_ref,  # [B * R] dtype
+    u_ref,  # [B] int32 — U-turn penalty per instance
+    left_ref,  # [B * R] int32
+    right_ref,  # [B * R] int32
     x_ref,  # [B * R] int32
-    nl_ref,  # [B * R] dtype
+    nl_ref,  # [B * R] int32
     # tensor inputs
     t_hbm,  # [B, R, R, S] in HBM — T
     tc_hbm,  # [B, R, R, S] in HBM — Tc; Tc[i, b, k] = T[i, k + 1, b]
-    rk_ref,  # [1, R, 1] dtype — right[i, k]   (= r_{c-1} at k = c - 1)
-    nk_ref,  # [1, R, 1] dtype — nl[i, k + 1]  (= nl_c   at k = c - 1)
+    rk_ref,  # [1, R, 1] int32 — right[i, k]   (= r_{c-1} at k = c - 1)
+    nk_ref,  # [1, R, 1] int32 — nl[i, k + 1]  (= nl_c   at k = c - 1)
     # outputs
     val_ref,  # [1, 1, 1, S] — new T[a, a+d, :]
     cho_ref,  # [1, 1, 1, S] int32 — argmin plane (-1 = skip, else c)
@@ -261,10 +245,11 @@ def wavefront_kernel(
     B, R = t_hbm.shape[0], t_hbm.shape[1]
     H = t_buf.shape[1]
     d = d_ref[0]
-    dtype = t_buf.dtype
+    dtype = jnp.int32
     # stored values are clipped to cap and sums to big, strictly above them,
     # so a masked row never beats a live one (module docstring)
-    big, cap = _limits(dtype)
+    big = jnp.asarray(_INT32_MAX, dtype)
+    cap = jnp.asarray(INT32_CAP, dtype)
     two = jnp.asarray(2, dtype)
 
     def band(win):
@@ -395,7 +380,7 @@ def wavefront_kernel(
         shifted = jnp.where(lane + x_b <= S - 1, rolled, row_end)
         skip = _add_sat(
             _add_sat(shifted, two * (r_b - r_bm1) * (svec + nl_a), big),
-            two * (l_b - r_bm1) * x_b.astype(dtype),
+            two * (l_b - r_bm1) * x_b,
             big,
         )
         val_ref[0, 0] = jnp.minimum(jnp.minimum(skip, det), cap)
@@ -533,15 +518,19 @@ def ltsp_dp_tables(
     grid plus in-place diagonal scatters (``mode="drop"`` discards the
     output blocks of the windows past the diagonal's end).  ``interpret`` has no default:
     every caller says whether it runs the compiled kernel or the interpreter.
+    The inputs are int32 (``ops.prepare_batch``); any other dtype raises
+    ``TypeError`` when the call is traced.
     """
-    B, R = left.shape
     dtype = left.dtype
+    if dtype != jnp.int32:
+        raise TypeError(f"the wavefront computes in int32 only, got {dtype}")
+    B, R = left.shape
     rr = jnp.arange(R)
-    # base diagonal T[b, b, s] = 2 s(b) (s + n_l(b)), batched (same op order
-    # as ref.base_diagonal so the f32 path stays bit-identical to the oracle)
+    # base diagonal T[b, b, s] = 2 s(b) (s + n_l(b)), batched, clipped like
+    # every stored value
     svec = jnp.arange(S, dtype=dtype)
     base = 2 * (right - left)[:, :, None] * (svec[None, None, :] + nl[:, :, None])
-    base = jnp.minimum(base, _limits(dtype)[1])
+    base = jnp.minimum(base, jnp.asarray(INT32_CAP, dtype))
     T = jnp.zeros((B, R, R, S), dtype)
     T = T.at[:, rr, rr, :].set(base)
     # Tc[i, b, a - 1] = T[i, a, b]; a = 0 never appears as a column term

@@ -9,36 +9,20 @@ traceback, so only the detours and root values cross to the host.
 :func:`traceback_detours` is the same walk on a host copy of a plane, the
 reference the device walk is tested against.
 
-Three numeric modes:
-
-* ``int32`` (solver default) — bit-exact when every cell a reader takes is
-  below ``2**30 - 1`` and every term the kernel forms at the lanes those
-  cells depend on below ``2**31 - 1`` (:func:`_int32_admits`): the kernel clips its tables and saturates its
-  sums instead of wrapping, so a candidate sum may pass int32 and still lose
-  the minimum exactly (see :mod:`.ltsp_dp`, *Saturating sums*).  Before the
-  :func:`_check_int32_safe` magnitude guard runs, :func:`rescale_instance`
-  shifts each instance to its leftmost requested byte and divides all
-  coordinates (and the U-turn penalty) by their gcd — every DP term is a
-  coordinate *difference*, so the whole table scales by exactly ``1/g`` and
-  the argmin structure (ties included) is untouched.  Real cartridge
-  layouts share the tape's block granularity, so byte coordinates far beyond
-  int32 rescale into range; the guard rejects only genuinely coprime
-  byte-scale layouts.
-* ``float64`` (``numeric_policy="f64"`` fallback, exact for values < 2**53) —
-  instances the int32 guard rejects are re-solved through the same wavefront
-  in float64 **interpret** mode (f64 is emulated on TPU VPUs, so the
-  compiled backend is not offered; the fallback is a CPU-side escape hatch
-  for the rare coprime layouts).  Integer table values below 2**53 are
-  exactly representable and rounding is monotone, so with the same two
-  bounds below 2**53 (:func:`_check_f64_safe`) the result is still
-  bit-identical to the python DP; beyond them the guard raises either way.
-  Selected via ``ExecutionContext.numeric_policy``; the default
-  ``"strict"`` keeps the old raise.  A compiled (``interpret=False``) solve
-  that would need the fallback raises instead of quietly running the
-  interpreter.
-* ``float32`` (oracle-comparison default, exact for values < 2**24) — used by
-  the seed-compatible :func:`ltsp_dp_table`/:func:`ltsp_opt` wrappers that the
-  kernel tests diff against :mod:`.ref`.
+The device DP computes in ``int32`` only.  It is bit-exact when every cell
+a reader takes is below ``2**30 - 1`` and every term the kernel forms at the
+lanes those cells depend on below ``2**31 - 1`` (:func:`_int32_admits`): the
+kernel clips its tables and saturates its sums instead of wrapping, so a
+candidate sum may pass int32 and still lose the minimum exactly (see
+:mod:`.ltsp_dp`, *Saturating sums*).  Before the :func:`_check_int32_safe`
+guard runs, :func:`rescale_instance` shifts each instance to its leftmost
+requested byte and divides all coordinates (and the U-turn penalty) by their
+gcd — every DP term is a coordinate *difference*, so the whole table scales
+by exactly ``1/g`` and the argmin structure (ties included) is untouched.
+Real cartridge layouts share the tape's block granularity, so byte
+coordinates far beyond int32 rescale into range.  The guard refuses the
+genuinely coprime byte-scale layouts before anything is launched; the exact
+``backend="python"`` solves them at any magnitude.
 
 ``disjoint=True`` routes SIMPLEDP through the same kernel: the candidate band
 is clipped to root-level cells (no detour may start inside another), which
@@ -81,7 +65,6 @@ from __future__ import annotations
 import contextlib
 import math
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -91,15 +74,11 @@ from .ltsp_dp import DEFAULT_CAND_TILE, INT32_CAP, band_copy_bytes, ltsp_dp_tabl
 from .walk import traceback_device
 
 __all__ = [
-    "prepare_arrays",
     "prepare_batch",
     "plan_buckets",
     "bucket_shape",
     "rescale_instance",
     "traceback_detours",
-    "ltsp_dp_table",
-    "ltsp_opt",
-    "ltsp_opt_instance",
     "ltsp_solve_instance",
     "ltsp_solve_batch",
     "ltsp_solve_instance_warm",
@@ -143,29 +122,13 @@ def plan_buckets(instances: list[Instance]) -> dict[tuple[int, int], list[int]]:
     return buckets
 
 
-def prepare_arrays(inst: Instance, S: int | None = None, dtype=jnp.float32):
-    """Instance → (left, right, x, nl, S) device arrays for the kernel.
-
-    S defaults to n+1 padded up to a multiple of 128 (TPU lane width).
-    """
-    if S is None:
-        S = inst.n + 1
-    S = _pad_s(S)
-    left = jnp.asarray(inst.left, dtype=dtype)
-    right = jnp.asarray(inst.right, dtype=dtype)
-    x = jnp.asarray(inst.mult, dtype=jnp.int32)
-    nl = jnp.asarray(inst.n_left(), dtype=dtype)
-    return left, right, x, nl, S
-
-
 def prepare_batch(
     instances: list[Instance],
-    dtype=jnp.int32,
     R_pad: int | None = None,
     S_pad: int | None = None,
     B_pad: int | None = None,
 ):
-    """Pack instances into padded ``[B, R]`` arrays + shared ``S``.
+    """Pack instances into padded ``[B, R]`` int32 arrays + shared ``S``.
 
     ``R_pad``/``S_pad``/``B_pad`` override the default tight padding (the
     batch maxima) — the bucket planner passes its power-of-two bucket shape so
@@ -199,11 +162,11 @@ def prepare_batch(
         [np.zeros((B, 1), np.int64), np.cumsum(x, axis=1)[:, :-1]], axis=1
     )
     return (
-        jnp.asarray(left, dtype),
-        jnp.asarray(right, dtype),
+        jnp.asarray(left, jnp.int32),
+        jnp.asarray(right, jnp.int32),
         jnp.asarray(x, jnp.int32),
-        jnp.asarray(nl, dtype),
-        jnp.asarray(u, dtype),
+        jnp.asarray(nl, jnp.int32),
+        jnp.asarray(u, jnp.int32),
         S,
     )
 
@@ -244,14 +207,6 @@ def rescale_instance(inst: Instance) -> tuple[Instance, int]:
     return scaled, g
 
 
-#: the int32 route's limits: cells below the value the kernel's int32
-#: tables clip to, terms below int32's largest value (``ltsp_dp`` docstring,
-#: *Saturating sums*)
-_INT32_LIMITS = (INT32_CAP, 2**31 - 1)
-#: float64 holds every integer below 2**53 exactly
-_F64_LIMITS = (2**53, 2**53)
-
-
 def _cell_bound(inst: Instance) -> int:
     """Bound on every cell value a reader takes: ``4 n m``.
 
@@ -290,19 +245,20 @@ def _term_bound(inst: Instance) -> int:
     return 4 * inst.n * (inst.m + inst.u_turn)
 
 
-def _failed_bounds(inst: Instance, limits: tuple[int, int]) -> str:
-    """The bounds of ``inst`` that reach their limit (``limits`` = cell
-    limit, term limit), named with their values; empty when the instance is
-    admitted."""
-    bounds = (("cell-value bound 4nm", _cell_bound(inst), limits[0]),
-              ("term bound 4n (m + U)", _term_bound(inst), limits[1]))
+def _failed_bounds(inst: Instance) -> str:
+    """The bounds of ``inst`` that reach their limit, named with their
+    values; empty when the instance is admitted.  Cells must stay below the
+    value the kernel's int32 tables clip to, terms below int32's largest
+    value (``ltsp_dp`` docstring, *Saturating sums*)."""
+    bounds = (("cell-value bound 4nm", _cell_bound(inst), INT32_CAP),
+              ("term bound 4n (m + U)", _term_bound(inst), 2**31 - 1))
     return "; ".join(f"{name} = {v}, not below {lim}"
                      for name, v, lim in bounds if v >= lim)
 
 
 def _int32_admits(inst: Instance) -> bool:
     """Whether the int32 wavefront solves ``inst`` exactly."""
-    return not _failed_bounds(inst, _INT32_LIMITS)
+    return not _failed_bounds(inst)
 
 
 def _check_int32_safe(instances: list[Instance]) -> None:
@@ -310,47 +266,13 @@ def _check_int32_safe(instances: list[Instance]) -> None:
     genuinely overflows even at tape-block granularity (after gcd/shift
     rescaling)."""
     for inst in instances:
-        failed = _failed_bounds(inst, _INT32_LIMITS)
+        failed = _failed_bounds(inst)
         if failed:
             raise ValueError(
                 f"instance too large for the int32 device DP even after gcd "
                 f"rescaling (m={inst.m}, n={inst.n}, R={inst.n_req}): "
-                f"{failed}; rescale coordinates to a "
-                f"coarser grain, use backend='python', or opt into the exact "
-                f"float64 interpret fallback with numeric_policy='f64'"
-            )
-
-
-def _guard(scaled: list[Instance], numeric_policy: str, interpret: bool) -> list[int]:
-    """Run the numeric-policy magnitude guards over rescaled instances and
-    return the indices that need the float64 route (empty unless
-    ``numeric_policy="f64"``).  The float64 route exists only in the
-    interpreter, so a compiled solve that would need it raises."""
-    if numeric_policy != "f64":
-        _check_int32_safe(scaled)
-        return []
-    wide = [i for i, s in enumerate(scaled) if not _int32_admits(s)]
-    _check_f64_safe([scaled[i] for i in wide])
-    if wide and not interpret:
-        s = scaled[wide[0]]
-        raise ValueError(
-            f"instance needs the float64 route (m={s.m}, n={s.n}, "
-            f"R={s.n_req}: {_failed_bounds(s, _INT32_LIMITS)}), "
-            f"which runs only in the interpreter: use "
-            f"backend='pallas-interpret' or backend='python'"
-        )
-    return wide
-
-
-def _check_f64_safe(instances: list[Instance]) -> None:
-    """Exactness-domain guard for the float64 fallback (< 2**53)."""
-    for inst in instances:
-        failed = _failed_bounds(inst, _F64_LIMITS)
-        if failed:
-            raise ValueError(
-                f"instance too large even for the exact float64 device DP "
-                f"(m={inst.m}, n={inst.n}, R={inst.n_req}): {failed} "
-                f"(2**53); use backend='python'"
+                f"{failed}; rescale coordinates to a coarser grain, or solve "
+                f"it exactly with backend='python'"
             )
 
 
@@ -383,7 +305,7 @@ def traceback_detours(choice: np.ndarray, mult: np.ndarray) -> list[tuple[int, i
 
 
 # ---------------------------------------------------------------------------
-# solver entry points (int32 exact; float64 interpret fallback)
+# solver entry points
 # ---------------------------------------------------------------------------
 def ltsp_solve_instance(
     inst: Instance,
@@ -392,13 +314,12 @@ def ltsp_solve_instance(
     interpret: bool,
     cand_tile: int = DEFAULT_CAND_TILE,
     disjoint: bool = False,
-    numeric_policy: str = "strict",
     profile=None,
 ) -> tuple[int, list[tuple[int, int]]]:
     """Device-solved ``(opt_cost, detours)`` for one instance (exact)."""
     return ltsp_solve_batch([inst], span=span, interpret=interpret,
                             cand_tile=cand_tile, disjoint=disjoint,
-                            numeric_policy=numeric_policy, profile=profile)[0]
+                            profile=profile)[0]
 
 
 def _solve_packed(
@@ -412,7 +333,6 @@ def _solve_packed(
     interpret: bool,
     cand_tile: int,
     disjoint: bool = False,
-    dtype=jnp.int32,
     capture: bool = False,
     profile=None,
 ) -> tuple[list[tuple[int, list[tuple[int, int]]]], list[DenseStore | None]]:
@@ -438,7 +358,7 @@ def _solve_packed(
     """
     with _span(profile, "ltsp.pack"):
         left, right, x, nl, u, S = prepare_batch(
-            scaled, dtype=dtype, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad
+            scaled, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad
         )
         if profile is not None:
             h2d = sum(a.nbytes for a in (left, right, x, nl, u))
@@ -486,9 +406,7 @@ def _solve_packed(
         del C_host, T_host, T, C, walked, left, right, x, nl, u
     if profile is not None:
         profile.record(
-            signature=(
-                R, S, B, np.dtype(dtype).name, interpret, span, disjoint, cand_tile,
-            ),
+            signature=(R, S, B, interpret, span, disjoint, cand_tile),
             n_instances=len(scaled),
             R_pad=R,
             S_pad=S,
@@ -499,7 +417,7 @@ def _solve_packed(
             d2h_bytes=d2h,
             walk_steps=int(steps.sum()),
             operand_bytes=band_copy_bytes(
-                B, R, S, np.dtype(dtype).itemsize, span, disjoint, cand_tile
+                B, R, S, np.dtype(np.int32).itemsize, span, disjoint, cand_tile
             ),
         )
     return out, stores
@@ -513,7 +431,6 @@ def ltsp_solve_batch(
     bucketed: bool = True,
     cand_tile: int = DEFAULT_CAND_TILE,
     disjoint: bool = False,
-    numeric_policy: str = "strict",
     capture: bool = False,
     profile=None,
 ) -> list[tuple[int, list[tuple[int, int]]]]:
@@ -533,11 +450,7 @@ def ltsp_solve_batch(
     ``interpret`` has no default: ``False`` runs the compiled Mosaic kernel,
     ``True`` the Pallas interpreter.
 
-    ``numeric_policy="f64"`` re-routes the (rare) instances that fail the
-    int32 magnitude guard after gcd/shift rescaling through an exact float64
-    **interpret** table instead of raising (see the module docstring); the
-    int32-safe majority still takes the int32 launches unchanged.  With
-    ``interpret=False`` such an instance raises before anything runs.
+    An instance that fails the guard raises before anything is launched.
 
     ``capture=True`` changes the return to ``(results, stores)`` where
     ``stores[i]`` is a :class:`~repro.core.warm.DenseStore` snapshot of
@@ -559,20 +472,18 @@ def ltsp_solve_batch(
         scaled = [p[0] for p in pairs]
         gs = [p[1] for p in pairs]
     with _span(profile, "ltsp.guard"):
-        wide = _guard(scaled, numeric_policy, interpret)
-    wide_set = set(wide)
-    narrow = [i for i in range(len(instances)) if i not in wide_set]
+        _check_int32_safe(scaled)
 
     stores: list[DenseStore | None] = [None] * len(instances)
 
-    def solve(idxs, R_pad, S_pad, B_pad, dtype=jnp.int32):
+    def solve(idxs, R_pad, S_pad, B_pad):
         out, subs = _solve_packed(
             [instances[i] for i in idxs],
             [scaled[i] for i in idxs],
             [gs[i] for i in idxs],
             R_pad, S_pad, B_pad, span,
             interpret, cand_tile,
-            disjoint=disjoint, dtype=dtype, capture=capture, profile=profile,
+            disjoint=disjoint, capture=capture, profile=profile,
         )
         for i, st in zip(idxs, subs):
             stores[i] = st
@@ -581,29 +492,13 @@ def ltsp_solve_batch(
     def done(results):
         return (results, stores) if capture else results
 
-    results: list[tuple[int, list[tuple[int, int]]] | None] = [None] * len(instances)
-    if wide:
-        # float64 is a correctness escape hatch for coprime byte-scale
-        # layouts, not a throughput path: interpret mode (_guard refuses it
-        # compiled), one tight launch per instance, under a scoped x64
-        # context (never enabled globally).
-        with jax.enable_x64(True):
-            for i in wide:
-                R_pad, S_pad = bucket_shape(scaled[i])
-                [results[i]] = solve([i], R_pad, S_pad, None, dtype=jnp.float64)
-    if not narrow:
-        return done(results)  # type: ignore[return-value]
     if not bucketed:  # seed behaviour: one launch padded to the batch maxima
-        for i, res in zip(narrow, solve(narrow, None, None, None)):
-            results[i] = res
-        return done(results)  # type: ignore[return-value]
-    if len(narrow) == 1:  # fast path: no planner, one tight launch
-        [i] = narrow
-        R_pad, S_pad = bucket_shape(scaled[i])
-        [results[i]] = solve([i], R_pad, S_pad, None)
-        return done(results)  # type: ignore[return-value]
-    for (R_pad, S_pad), sub in plan_buckets([scaled[i] for i in narrow]).items():
-        idxs = [narrow[j] for j in sub]
+        return done(solve(range(len(instances)), None, None, None))
+    if len(instances) == 1:  # fast path: no planner, one tight launch
+        R_pad, S_pad = bucket_shape(scaled[0])
+        return done(solve([0], R_pad, S_pad, None))
+    results: list[tuple[int, list[tuple[int, int]]] | None] = [None] * len(instances)
+    for (R_pad, S_pad), idxs in plan_buckets(scaled).items():
         for idx, res in zip(idxs, solve(idxs, R_pad, S_pad, _pow2(len(idxs)))):
             results[idx] = res
     return done(results)  # type: ignore[return-value]
@@ -619,13 +514,12 @@ def ltsp_solve_instance_warm(
     *,
     interpret: bool,
     cand_tile: int = DEFAULT_CAND_TILE,
-    numeric_policy: str = "strict",
     profile=None,
 ) -> tuple[int, list[tuple[int, int]], WarmState | None, WarmStats]:
     """Warm-startable single-instance solve (see :func:`ltsp_solve_batch_warm`)."""
     results, warms, stats = ltsp_solve_batch_warm(
         [inst], [warm], span=span, interpret=interpret,
-        cand_tile=cand_tile, numeric_policy=numeric_policy, profile=profile,
+        cand_tile=cand_tile, profile=profile,
     )
     (cost, dets) = results[0]
     return cost, dets, warms[0], stats[0]
@@ -639,7 +533,6 @@ def ltsp_solve_batch_warm(
     interpret: bool,
     bucketed: bool = True,
     cand_tile: int = DEFAULT_CAND_TILE,
-    numeric_policy: str = "strict",
     profile=None,
 ) -> tuple[
     list[tuple[int, list[tuple[int, int]]]],
@@ -661,10 +554,9 @@ def ltsp_solve_batch_warm(
     ``capture=True``, so each cold solve yields a dense
     :class:`~repro.core.warm.DenseStore` warm state for the next tick.
 
-    The numeric-policy magnitude guards run for *every* instance first —
-    including warm-aligned ones, which the guards' failure modes could
-    otherwise bypass — so strict-mode error behaviour matches the cold path
-    exactly.  Returns ``(results, new_warm_states, stats)``, all parallel to
+    The int32 guard runs for *every* instance first — including warm-aligned
+    ones, which would otherwise bypass it — so the error behaviour matches
+    the cold path exactly.  Returns ``(results, new_warm_states, stats)``, all parallel to
     ``instances``.
     """
     if not instances:
@@ -673,8 +565,7 @@ def ltsp_solve_batch_warm(
         warms = [None] * len(instances)
     # same guard discipline as the cold path (before any solving: a batch
     # never fails mid-flight)
-    scaled = [rescale_instance(inst)[0] for inst in instances]
-    _guard(scaled, numeric_policy, interpret)
+    _check_int32_safe([rescale_instance(inst)[0] for inst in instances])
 
     from ...core.dp import dp_schedule_warm
 
@@ -691,8 +582,7 @@ def ltsp_solve_batch_warm(
     if cold:
         solved, stores = ltsp_solve_batch(
             [instances[i] for i in cold], span=span, interpret=interpret,
-            bucketed=bucketed, cand_tile=cand_tile,
-            numeric_policy=numeric_policy, capture=True, profile=profile,
+            bucketed=bucketed, cand_tile=cand_tile, capture=True, profile=profile,
         )
         for i, res, store in zip(cold, solved, stores):
             results[i] = res
@@ -704,39 +594,3 @@ def ltsp_solve_batch_warm(
             # dense cell of the padded launch shape
             stats[i] = WarmStats(cells_evaluated=len(store) if store else 0)
     return results, new_warms, stats  # type: ignore[return-value]
-
-
-# ---------------------------------------------------------------------------
-# value-only f32 wrappers (seed-compatible API, diffed against ref.py)
-# ---------------------------------------------------------------------------
-def ltsp_dp_table(left, right, x, nl, u_turn: float, S: int, *, interpret: bool):
-    """Dense single-instance DP table (f32) via the single-trace wavefront."""
-    dtype = left.dtype
-    T, _ = ltsp_dp_tables(
-        left[None],
-        right[None],
-        x[None],
-        nl[None],
-        jnp.asarray([u_turn], dtype),
-        S=S,
-        interpret=interpret,
-    )
-    return T[0]
-
-
-def ltsp_opt(
-    left, right, x, nl, u_turn: float, m: float, S: int, *, interpret: bool
-):
-    """Optimal LTSP objective (float): ``T[0, R-1, 0] + VirtualLB``."""
-    T = ltsp_dp_table(left, right, x, nl, u_turn, S, interpret=interpret)
-    virt = jnp.sum(x.astype(jnp.float32) * (m - left + (right - left) + u_turn))
-    return T[0, left.shape[0] - 1, 0] + virt
-
-
-def ltsp_opt_instance(inst: Instance, *, interpret: bool) -> float:
-    """Convenience: exact-instance adapter (f32; exact for coords < 2**20)."""
-    left, right, x, nl, S = prepare_arrays(inst)
-    val = ltsp_opt(
-        left, right, x, nl, float(inst.u_turn), float(inst.m), S, interpret=interpret
-    )
-    return float(val)

@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the wavefront LTSP DP (float, bottom-up).
+"""Plain NumPy reference of the wavefront LTSP DP: dense int64 tables.
 
 The exact Python DP (:mod:`repro.core.dp`) memoises only reachable
 ``(a, b, n_skip)`` cells; the device formulation instead materialises the full
@@ -9,92 +9,72 @@ approximation is the clamped gather ``T[a, b-1, min(s + x_b, S-1)]``, which
 can only be hit from cells that are themselves unreachable from the root
 ``(0, R-1, 0)`` (a reachable chain keeps ``s + sum(x) <= n < S``).
 
-This file is the correctness oracle for the Pallas kernel; it mirrors its
-clamping semantics exactly.  With integer-valued inputs below 2**20 the f32
-arithmetic here is exact, so the oracle can additionally be compared 1:1
-against the exact integer DP.
+:func:`ltsp_tables_ref` computes the value table ``T`` *and* the argmin plane
+``C`` the same way, over every cell and lane, with the kernel's rules: the
+skip reads lane ``min(s + x_b, S - 1)``; the skip wins ties, then the
+smallest detour start ``c``; LOGDP spans and the SIMPLEDP ``disjoint`` clip
+narrow the candidates as they narrow the kernel's band.  It computes in
+int64 and neither clips nor saturates, so it equals the int32 kernel on
+every instance whose values stay below the kernel's clip — the small
+instances the kernel tests hold it to.
 """
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["ltsp_dp_table_ref", "ltsp_opt_ref", "base_diagonal"]
+from ...core.instance import Instance, virtual_lb
 
+__all__ = ["ltsp_tables_ref", "root_cost"]
 
-def base_diagonal(right, left, nl, S: int, dtype=jnp.float32):
-    """``T[b, b, s] = 2 s(b) (s + n_l(b))`` for all b, s."""
-    size = (right - left).astype(dtype)  # [R]
-    svec = jnp.arange(S, dtype=dtype)  # [S]
-    return 2.0 * size[:, None] * (svec[None, :] + nl[:, None].astype(dtype))
+#: what a masked candidate reads, as in the kernel
+_BIG = 2**31 - 1
 
 
-def _diagonal_update(T, d: int, left, right, x, nl, u_turn, S: int):
-    """Compute T[a, a+d, :] for every a via the skip/detour recurrence."""
-    R = T.shape[0]
-    dtype = T.dtype
-    n_a = R - d
-    a = jnp.arange(n_a)
-    b = a + d
-    svec = jnp.arange(S, dtype=dtype)
-
-    # ---- skip(a, b, s) = T[a, b-1, s + x_b] + 2 (r_b - r_{b-1})(s + nl_a)
-    #                      + 2 (l_b - r_{b-1}) x_b ---------------------------
-    rows_bm1 = T[a, b - 1, :]  # [n_a, S]
-    gather_idx = jnp.clip(svec[None, :].astype(jnp.int32) + x[b][:, None], 0, S - 1)
-    shifted = jnp.take_along_axis(rows_bm1, gather_idx, axis=1)
-    xb = x[b].astype(dtype)
-    skip = (
-        shifted
-        + 2.0 * (right[b] - right[b - 1]).astype(dtype)[:, None]
-        * (svec[None, :] + nl[a].astype(dtype)[:, None])
-        + (2.0 * (left[b] - right[b - 1]).astype(dtype) * xb)[:, None]
-    )
-
-    # ---- detour_c over c = a+k, k = 1..d --------------------------------
-    # candidates[k-1, a, s] = T[a, c-1, s] + T[c, b, s]
-    #   + 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c)
-    def one_k(k):
-        c = a + k
-        t_left = T[a, c - 1, :]  # [n_a, S]
-        t_right = T[c, b, :]  # [n_a, S]
-        term = (
-            t_left
-            + t_right
-            + 2.0 * (right[b] - right[c - 1]).astype(dtype)[:, None]
-            * (svec[None, :] + nl[a].astype(dtype)[:, None])
-            + 2.0 * u_turn * (svec[None, :] + nl[c].astype(dtype)[:, None])
-        )
-        return term
-
-    det = one_k(1)
-    for k in range(2, d + 1):
-        det = jnp.minimum(det, one_k(k))
-
-    new_diag = jnp.minimum(skip, det)  # [n_a, S]
-    return T.at[a, b, :].set(new_diag)
-
-
-def ltsp_dp_table_ref(left, right, x, nl, u_turn, S: int):
-    """Full dense DP table (reference implementation, per-diagonal loop)."""
-    R = left.shape[0]
-    dtype = jnp.float32
-    T = jnp.zeros((R, R, S), dtype=dtype)
-    T = T.at[jnp.arange(R), jnp.arange(R), :].set(
-        base_diagonal(right, left, nl, S, dtype)
-    )
+def ltsp_tables_ref(left, right, x, nl, u, S: int, span=None, disjoint=False):
+    """Dense ``(T, C, ties)`` of one instance (``[R]`` arrays, ``u`` the
+    U-turn penalty): ``T[a, b, s]`` the values, ``C[a, b, s]`` the argmin
+    (-1 = skip, else the detour start ``c``), and ``ties`` the number of
+    skip-detour and detour-detour ties met on the way."""
+    left, right, x, nl = (np.asarray(v, np.int64) for v in (left, right, x, nl))
+    u = int(u)
+    R, s = len(left), np.arange(S)
+    T = np.zeros((R, R, S), np.int64)
+    C = np.full((R, R, S), -1, np.int64)
+    rr = np.arange(R)
+    T[rr, rr] = 2 * (right - left)[:, None] * (s + nl[:, None])
+    ties = np.zeros(2, np.int64)
     for d in range(1, R):
-        T = _diagonal_update(T, d, left, right, x, nl, u_turn, S)
-    return T
+        a = np.arange(R - d)
+        b = a + d
+        idx = np.minimum(s[None] + x[b][:, None], S - 1)
+        skip = (np.take_along_axis(T[a, b - 1], idx, 1)
+                + 2 * (right[b] - right[b - 1])[:, None] * (s + nl[a][:, None])
+                + (2 * (left[b] - right[b - 1]) * x[b])[:, None])
+        c_min = a + 1
+        if span is not None:  # LOGDP restriction: b - c <= span
+            c_min = np.maximum(c_min, b - span)
+        if disjoint:  # SIMPLEDP restriction: detours only at the root level
+            c_min = np.where(a > 0, b + 1, c_min)
+        det = np.full((R - d, S), _BIG, np.int64)
+        arg = np.zeros((R - d, S), np.int64)
+        for k in range(1, d + 1):
+            c = a + k
+            cand = (T[a, c - 1] + T[c, b]
+                    + 2 * (right[b] - right[c - 1])[:, None] * (s + nl[a][:, None])
+                    + 2 * u * (s + nl[c][:, None]))
+            cand = np.where((c >= c_min)[:, None], cand, _BIG)
+            ties[1] += np.sum((cand == det) & (cand < _BIG))
+            better = cand < det
+            det = np.where(better, cand, det)
+            arg = np.where(better, c[:, None], arg)
+        ties[0] += np.sum(skip == det)
+        T[a, b] = np.minimum(skip, det)
+        C[a, b] = np.where(skip <= det, -1, arg)
+    return T, C, ties
 
 
-def ltsp_opt_ref(left, right, x, nl, u_turn, m, S: int):
-    """Optimal objective value: ``T[0, R-1, 0] + VirtualLB`` (float)."""
-    R = left.shape[0]
-    T = ltsp_dp_table_ref(left, right, x, nl, u_turn, S)
-    virt = jnp.sum(
-        x.astype(jnp.float32)
-        * (m - left + (right - left) + u_turn).astype(jnp.float32)
-    )
-    return T[0, R - 1, 0] + virt
+def root_cost(T, inst: Instance) -> int:
+    """The optimal LTSP objective from a dense table of ``inst`` (padded
+    files included): ``T[0, R - 1, 0] + VirtualLB``."""
+    return int(T[0, T.shape[0] - 1, 0]) + virtual_lb(inst)

@@ -13,7 +13,7 @@ Scheduling dispatches through the solver engine (:mod:`repro.core.solver`):
 ``policy`` names any registered solver (``repro.core.list_solvers()``) and an
 :class:`~repro.core.ExecutionContext` says how to run it — backend
 (``"python"`` exact CPU, ``"pallas"`` compiled TPU wavefront,
-``"pallas-interpret"``), solve memo, bucketing and numeric-guard policy.  A
+``"pallas-interpret"``), solve memo and bucketing.  A
 :class:`TapeLibrary` owns a context (constructor ``context=``): every
 :meth:`TapeLibrary.schedule` call uses it unless the call passes its own.
 On the device backends :meth:`TapeLibrary.schedule` packs every cartridge's
@@ -202,7 +202,7 @@ def schedule_reads(
 ) -> ReadPlan:
     """Order a batch of reads on one tape with an LTSP policy.
 
-    ``context`` selects backend/cache/numeric options;
+    ``context`` selects backend/cache/bucketing options;
     ``backend=``/``cache=`` are the deprecated spellings (see
     :mod:`repro.core.context`).
     """

@@ -138,83 +138,35 @@ def test_rescale_shift_handles_far_offset_layouts():
     assert res.cost == dp_schedule(inst)[0]
 
 
-def test_guard_still_rejects_unrescalable_instances():
-    """Coprime huge coordinates cannot be gcd-reduced: the strict guard must
-    raise with the rescaling + f64 hint."""
-    bad = make_instance(
-        [0, 2 * 10**9 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
-    )
-    with pytest.raises(ValueError, match="int32") as ei:
-        solve(bad, policy="dp", context=DEV)
-    assert "f64" in str(ei.value)  # the error teaches the escape hatch
-    # exact python backend still fine
-    py = solve(bad, policy="dp")
-    assert py.cost == evaluate_detours(bad, py.detours)
+#: a byte-scale coprime layout, which gcd/shift rescaling cannot bring into
+#: int32, and one beyond 2**53
+COPRIME = make_instance(
+    [0, 2 * 10**9 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
+)
+PAST_2_53 = make_instance(
+    [0, 2 * 10**15 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
+)
 
 
-# ---------------------------------------------------------------------------
-# numeric_policy="f64": exact interpret fallback past the int32 guard
-# ---------------------------------------------------------------------------
-def _coprime_instance():
-    """Byte-scale coprime layout: gcd/shift rescaling cannot save int32."""
-    return make_instance(
-        [0, 2 * 10**9 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
-    )
+@pytest.mark.parametrize("policy", ["dp", "simpledp"])
+@pytest.mark.parametrize("backend", ["pallas", "pallas-interpret"])
+def test_guard_still_rejects_unrescalable_instances(backend, policy, monkeypatch):
+    """Coprime huge coordinates cannot be gcd-reduced: the guard raises
+    before any launch and names the python backend, which solves them
+    exactly at any magnitude."""
+    from repro.kernels.ltsp_dp import ops
 
+    def no_launch(*args, **kwargs):
+        raise AssertionError("the guard let a launch through")
 
-def test_f64_fallback_is_bit_exact_in_domain():
-    """Within the < 2**53 exactness domain the f64 interpret table must be
-    bit-identical (cost AND detours) to the exact python DP, for the full DP
-    and for SIMPLEDP's disjoint clip."""
-    from repro.core import simpledp_schedule
-
-    bad = _coprime_instance()
-    f64 = DEV.replace(numeric_policy="f64")
-    for policy, oracle in (("dp", dp_schedule), ("simpledp", simpledp_schedule)):
-        res = solve(bad, policy=policy, context=f64)
-        assert (res.cost, res.detours) == oracle(bad), policy
-        assert evaluate_detours(bad, res.detours) == res.cost
-
-
-def test_f64_fallback_only_reroutes_guard_failures(rng):
-    """int32-safe instances must keep taking the int32 launches: an f64
-    context changes nothing for them (bit-identical batch, order kept)."""
-    import jax
-
-    good = [_hetero_instance(rng) for _ in range(3)]
-    bad = _coprime_instance()
-    batch = [good[0], bad, good[1], good[2]]
-    res = solve_batch(batch, policy="dp", context=DEV.replace(numeric_policy="f64"))
-    strict = solve_batch(good, policy="dp", context=DEV)
-    assert [(r.cost, r.detours) for r in (res[0], res[2], res[3])] == [
-        (r.cost, r.detours) for r in strict
-    ]
-    assert res[1].cost == dp_schedule(bad)[0]
-    # the scoped x64 context never leaks into global jax state
-    assert not jax.config.jax_enable_x64
-
-
-def test_f64_route_refused_by_compiled_backend(rng):
-    """The float64 route runs only in the interpreter: a compiled solve that
-    would need it raises before any launch, naming the two backends that can
-    run it, instead of quietly running the interpreter."""
-    batch = [_hetero_instance(rng), _coprime_instance()]
-    compiled = ExecutionContext(backend="pallas", numeric_policy="f64")
-    with pytest.raises(ValueError, match="pallas-interpret") as ei:
-        solve_batch(batch, policy="dp", context=compiled)
-    assert "python" in str(ei.value)
-
-
-def test_f64_guard_rejects_beyond_exactness_domain():
-    """Past 2**53 the float64 table could round: must raise, not lie."""
-    huge = make_instance(
-        [0, 2 * 10**15 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
-    )
-    with pytest.raises(ValueError, match="2\\*\\*53"):
-        solve(huge, policy="dp", context=DEV.replace(numeric_policy="f64"))
-    # python remains the unbounded-exactness backend
-    py = solve(huge, policy="dp")
-    assert py.cost == evaluate_detours(huge, py.detours)
+    monkeypatch.setattr(ops, "ltsp_dp_tables", no_launch)
+    for bad in (COPRIME, PAST_2_53):
+        with pytest.raises(ValueError, match="int32") as ei:
+            solve_batch([_hetero_instance(np.random.default_rng(0)), bad],
+                        policy=policy, context=ExecutionContext(backend=backend))
+        assert "backend='python'" in str(ei.value)
+        py = solve(bad, policy=policy)
+        assert py.cost == evaluate_detours(bad, py.detours)
 
 
 def test_rescale_is_exact_not_approximate(rng):
@@ -247,7 +199,7 @@ def test_partial_batch_solves_good_and_types_bad(rng):
     from repro.core.solver import FailedSolve
 
     good = [_hetero_instance(rng) for _ in range(3)]
-    bad = _coprime_instance()
+    bad = COPRIME
     batch = [good[0], bad, good[1], good[2]]
 
     # strict device policy: the bad instance trips the int32 guard

@@ -2,15 +2,14 @@
 
 Acceptance bars:
 
-* the solve-memo key includes every result-affecting execution option —
-  ``numeric_policy`` and ``cand_tile`` must never cross-serve hits
-  (regression: earlier revisions keyed on neither);
+* the solve-memo key holds the policy, the backend and the request
+  multiset — a memo filled by one backend never serves another;
 * a bounded LRU *smaller than the working set* on the seeded 240-request
   constrained-pool trace yields bit-identical schedules to an unbounded
   cache — eviction can only cost re-solves, never change a result;
 * :class:`~repro.core.JsonlCacheBackend` round-trips its journal across
   restarts (replay -> memo hits without re-solving), tolerates torn/foreign
-  lines, and ``compact()``/``clear()`` behave;
+  lines (keys of another layout included), and ``compact()``/``clear()`` behave;
 * both shipped backends satisfy the runtime-checkable
   :class:`~repro.core.CacheBackend` protocol;
 * the journal is single-writer: a second live writer on the same path is
@@ -60,41 +59,41 @@ def build_trace(n_requests=240):
 
 
 # ---------------------------------------------------------------------------
-# key regression: numeric_policy and cand_tile are part of the identity
+# key: the backend is part of the identity
 # ---------------------------------------------------------------------------
-def test_cache_key_separates_numeric_policy_and_cand_tile(rng):
-    """A memo populated under one (numeric_policy, cand_tile) must not serve
-    hits to another — the options change the execution (error domain,
-    launch shape), so a cross-hit would misreport provenance."""
+def test_cache_key_separates_backend(rng):
+    """A memo filled by the python backend must not serve a
+    ``pallas-interpret`` call: a hit reports the backend that computed it.
+    Each backend re-hits its own entry, and the answers are equal."""
     cache = SolveCache()
     inst = random_instance(rng, lo=3, hi=8)
-    ctx = DEV.replace(cache=cache)
-    r1 = solve(inst, policy="dp", context=ctx)
+    py_ctx = ExecutionContext(cache=cache)
+    dev_ctx = DEV.replace(cache=cache)
+    r1 = solve(inst, policy="dp", context=py_ctx)
     assert cache.stats()["misses"] == 1 and cache.stats()["entries"] == 1
-    r2 = solve(inst, policy="dp", context=ctx.replace(cand_tile=8))
-    assert cache.stats()["hits"] == 0, "cand_tile variant must not hit"
-    r3 = solve(inst, policy="dp", context=ctx.replace(numeric_policy="f64"))
-    assert cache.stats()["hits"] == 0, "numeric_policy variant must not hit"
+    r2 = solve(inst, policy="dp", context=dev_ctx)
+    assert cache.stats()["hits"] == 0, "another backend must not hit"
     assert cache.stats() == {
-        "hits": 0, "misses": 3, "entries": 3, "warm_entries": 0,
+        "hits": 0, "misses": 2, "entries": 2, "warm_entries": 0,
     }
-    # all three are exact solves of the same instance -> same answer
-    assert (r1.cost, r1.detours) == (r2.cost, r2.detours) == (r3.cost, r3.detours)
-    # and each variant re-hits itself
-    solve(inst, policy="dp", context=ctx)
-    solve(inst, policy="dp", context=ctx.replace(cand_tile=8))
-    solve(inst, policy="dp", context=ctx.replace(numeric_policy="f64"))
-    assert cache.stats()["hits"] == 3
+    assert (r1.backend, r2.backend) == ("python", "pallas-interpret")
+    # both are exact solves of the same instance -> same answer
+    assert (r1.cost, r1.detours) == (r2.cost, r2.detours)
+    # and each backend re-hits itself
+    assert solve(inst, policy="dp", context=py_ctx).backend == "python"
+    assert solve(inst, policy="dp", context=dev_ctx).backend == "pallas-interpret"
+    assert cache.stats()["hits"] == 2
 
 
 def test_positional_get_put_defaults_match_default_context(rng):
-    """Pre-protocol call sites (3-arg get/put) key as strict/None."""
+    """The 3-arg get/put form keys exactly as a solve under the context."""
     cache = SolveCache()
     inst = random_instance(rng, lo=2, hi=6)
     res = solve(inst, policy="dp", context=ExecutionContext(cache=cache))
-    hit = cache.get(inst, "dp", "python")  # legacy positional form
+    hit = cache.get(inst, "dp", "python")
     assert hit is not None and (hit.cost, hit.detours) == (res.cost, res.detours)
-    assert cache.get(inst, "dp", "python", "f64") is None
+    assert list(cache._store) == [SolveCache.key(inst, "dp", "python")]
+    assert cache.get(inst, "dp", "pallas-interpret") is None
 
 
 # ---------------------------------------------------------------------------
@@ -158,9 +157,14 @@ def test_jsonl_backend_skips_torn_and_foreign_lines(tmp_path, rng):
     with open(path, "a", encoding="utf-8") as fh:
         fh.write("not json at all\n")
         fh.write(json.dumps({"unrelated": True}) + "\n")
+        # a row of the 9-field key journals held before the kernel options
+        # left the key: it could never hit again
+        old_key = ["dp", "python", "strict", None, *JsonlCacheBackend._encode_key(
+            SolveCache.key(inst, "dp", "python"))[2:]]
+        fh.write(json.dumps({"k": old_key, "cost": 0, "det": []}) + "\n")
         fh.write('{"k": ["dp", "python"')  # torn mid-write
     reopened = JsonlCacheBackend(path)
-    assert reopened.loaded == 1
+    assert reopened.loaded == 1 and len(reopened) == 1
     hit = reopened.get(inst, "dp", "python")
     assert hit is not None and (hit.cost, hit.detours) == (res.cost, res.detours)
     reopened.close()

@@ -35,8 +35,9 @@ def test_context_defaults():
     assert ctx.backend == "python"
     assert ctx.cache is None
     assert ctx.bucketed is True
-    assert ctx.cand_tile is None
-    assert ctx.numeric_policy == "strict"
+    assert ctx.budget is None and ctx.fleet is None and ctx.obs is None
+    assert [f.name for f in dataclasses.fields(ctx)] == [
+        "backend", "cache", "bucketed", "budget", "fleet", "obs"]
     assert ctx == DEFAULT_CONTEXT
 
 
@@ -45,32 +46,34 @@ def test_context_is_immutable():
     with pytest.raises(dataclasses.FrozenInstanceError):
         ctx.backend = "pallas"
     with pytest.raises(dataclasses.FrozenInstanceError):
-        ctx.numeric_policy = "f64"
+        ctx.bucketed = False
 
 
 def test_context_replace_derives_without_mutating():
     base = ExecutionContext(cache=SolveCache())
-    derived = base.replace(backend="pallas-interpret", numeric_policy="f64")
+    derived = base.replace(backend="pallas-interpret", bucketed=False)
     assert derived.backend == "pallas-interpret"
-    assert derived.numeric_policy == "f64"
+    assert derived.bucketed is False
     assert derived.cache is base.cache  # shared memo, not copied
-    assert base.backend == "python" and base.numeric_policy == "strict"
+    assert base.backend == "python" and base.bucketed is True
 
 
 def test_context_validates_fields():
     with pytest.raises(KeyError, match="unknown backend"):
         ExecutionContext(backend="cuda")
-    with pytest.raises(ValueError, match="numeric_policy"):
-        ExecutionContext(numeric_policy="f16")
-    with pytest.raises(ValueError, match="cand_tile"):
-        ExecutionContext(cand_tile=0)
+    with pytest.raises(TypeError, match="budget"):
+        ExecutionContext(budget=3)
+    with pytest.raises(TypeError, match="fleet"):
+        ExecutionContext(fleet="two shards")
+    with pytest.raises(TypeError, match="obs"):
+        ExecutionContext(obs=object())
 
 
 def test_resolve_context_precedence():
     ctx = ExecutionContext(backend="pallas-interpret")
     assert resolve_context(ctx) is ctx
     assert resolve_context(None) == DEFAULT_CONTEXT
-    base = ExecutionContext(numeric_policy="f64")
+    base = ExecutionContext(bucketed=False)
     assert resolve_context(None, default=base) is base
     with pytest.raises(TypeError, match="not both"):
         resolve_context(ctx, backend="python")

@@ -2,8 +2,7 @@
 replays the argmin plane exactly as the host reference
 :func:`~repro.kernels.ltsp_dp.ops.traceback_detours` does: the same detours
 in the same order, for every row of a launch, on planes from the python DP
-and from the wavefront under each band layout, captured or not, in int32
-and on the float64 interpret route."""
+and from the wavefront under each band layout, captured or not."""
 
 import jax
 import jax.numpy as jnp
@@ -23,18 +22,17 @@ from repro.kernels.ltsp_dp.ops import (
 from repro.kernels.ltsp_dp.walk import traceback_device
 
 
-def _instance(n_req: int, seed: int, scale: int = 1):
-    """Random instance; ``scale > 1`` spreads it past the int32 guard with
-    coordinates that share no common factor."""
+def _instance(n_req: int, seed: int):
+    """Random instance of ``n_req`` files."""
     rng = np.random.default_rng(seed)
-    sizes = rng.integers(1, 50, size=n_req) * scale + rng.integers(0, 2, size=n_req)
-    gaps = rng.integers(0, 40, size=n_req + 1) * scale
+    sizes = rng.integers(1, 50, size=n_req) + rng.integers(0, 2, size=n_req)
+    gaps = rng.integers(0, 40, size=n_req + 1)
     left, pos = [], int(gaps[0]) + 1
     for i in range(n_req):
         left.append(pos)
         pos += int(sizes[i] + gaps[i + 1])
     mult = rng.integers(1, 8, size=n_req)
-    return make_instance(left, sizes, mult, m=pos, u_turn=int(rng.integers(5, 30)) * scale + 1)
+    return make_instance(left, sizes, mult, m=pos, u_turn=int(rng.integers(5, 30)) + 1)
 
 
 def _check(T, C, x, expected):
@@ -54,13 +52,13 @@ def _check(T, C, x, expected):
     assert any(host)  # the cases are chosen to have detours
 
 
-def _kernel_tables(insts, B_pad=None, span=None, disjoint=False, dtype=jnp.int32):
+def _kernel_tables(insts, B_pad=None, span=None, disjoint=False):
     """One interpreted wavefront launch over ``insts``' shared bucket."""
     scaled = [rescale_instance(i)[0] for i in insts]
     R_pad = max(bucket_shape(s)[0] for s in scaled)
     S_pad = max(bucket_shape(s)[1] for s in scaled)
     left, right, x, nl, u, S = prepare_batch(
-        scaled, dtype=dtype, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad)
+        scaled, R_pad=R_pad, S_pad=S_pad, B_pad=B_pad)
     T, C = ltsp_dp_tables(left, right, x, nl, u, S=S, span=span, disjoint=disjoint,
                           interpret=True)
     return T, C, x
@@ -112,18 +110,6 @@ def _capture():
         assert traceback_detours(store._choice, x) == dets
 
 
-def _f64_interpret():
-    insts = [_instance(8, 51, scale=10**7 + 3), _instance(6, 52, scale=10**7 + 3)]
-    with pytest.raises(ValueError, match="int32"):
-        ltsp_solve_batch(insts, interpret=True)
-    with jax.enable_x64(True):
-        T, C, x = _kernel_tables(insts, dtype=jnp.float64)
-        assert T.dtype == jnp.float64 and C.dtype == x.dtype == jnp.int32
-        _check(T, C, x, [dp_schedule(i)[1] for i in insts])
-    f64 = ltsp_solve_batch(insts, interpret=True, numeric_policy="f64")
-    assert f64 == [dp_schedule(i) for i in insts]
-
-
 @pytest.mark.parametrize("case", [
     # the first instance of each holds two pending frames at once, so the
     # order the walk resumes them in shows in the detours
@@ -134,7 +120,6 @@ def _f64_interpret():
     pytest.param(_logdp_span, id="wavefront-logdp-span2"),
     pytest.param(_simpledp_disjoint, id="wavefront-simpledp-disjoint"),
     pytest.param(_capture, id="solve-capture"),
-    pytest.param(_f64_interpret, id="float64-interpret"),
 ])
 def test_the_device_walk_replays_the_host_walk(case):
     case()

@@ -213,7 +213,7 @@ def test_what_the_old_candidate_bound_admitted_still_solves_in_int32(policy):
             assert (res.cost, res.detours) == ORACLES[policy](inst)
 
 
-def test_above_the_cell_bound_strict_raises_and_f64_routes():
+def test_above_the_cell_bound_the_guard_raises_and_python_solves():
     bad = make_instance(
         [0, 2 * 10**9 + 1], [10**6 + 1, 10**6 + 3], [3, 3], u_turn=10**7 + 1
     )
@@ -221,6 +221,7 @@ def test_above_the_cell_bound_strict_raises_and_f64_routes():
     assert _cell_bound(scaled) > INT32_MAX
     with pytest.raises(ValueError, match="cell-value bound 4nm") as ei:
         solve(bad, policy="dp", context=DEV)
-    assert "term bound" in str(ei.value) and "numeric_policy='f64'" in str(ei.value)
-    res = solve(bad, policy="dp", context=DEV.replace(numeric_policy="f64"))
+    assert "term bound" in str(ei.value) and "backend='python'" in str(ei.value)
+    res = solve(bad, policy="dp")
     assert (res.cost, res.detours) == dp_schedule(bad)
+    assert evaluate_detours(bad, res.detours) == res.cost
