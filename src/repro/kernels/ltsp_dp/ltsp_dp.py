@@ -10,37 +10,64 @@ sublane axis.
 
 The whole table is built in **one trace**: :func:`ltsp_dp_tables` runs a
 jitted ``lax.fori_loop`` over the diagonal index ``d`` whose carry is the
-table workspace; XLA donates the carry so each diagonal is an in-place
-scatter, and the kernel receives ``d`` as a scalar-prefetch operand, so the
-same compiled kernel serves every diagonal.  The same kernel runs compiled
+table workspace; each launch writes its diagonal into the carried tables in
+place (*Writing the diagonal in place* below), and the kernel receives ``d``
+as a scalar-prefetch operand, so the same compiled kernel serves every
+diagonal.  The same kernel runs compiled
 (Mosaic, ``interpret=False``) and through the Pallas interpreter
 (``interpret=True``); every construct below is one Mosaic lowers.
 
 Table layout
 ------------
+Every table is ``[B, R, R, S/128, 128]`` int32: the skip-count axis is split
+into tile rows of 128 lanes, so cell ``(a, b)`` of instance ``i`` is the
+``(S/128, 128)`` tile ``T[i, a, b]``, whose element ``(p, l)`` is skip count
+``s = 128 p + l``.  ``(i, a, b)`` are all leading, untiled dimensions, so a
+cell's row is one tile-aligned block (on ``[R, R, S]`` it would be one row
+of an ``(8, 128)`` tile, which no block or copy at a dynamic ``b`` can
+name).  Below ``S = 1024`` a tile pads to 8 rows in HBM; those tables are
+at most a few MiB.
+
 A program computing cell ``(a, b)`` reads row ``a`` of the value table,
-``T[a, :, s]``, and column ``b``, ``T[:, b, s]``.  A column of ``[R, R, S]`` is
-a strided ``(R, 1, S)`` slab, which no legal TPU block (last two block dims
-multiples of ``(8, 128)`` or equal to the array's) can name.  The wavefront
-therefore carries a second, transposed copy ``Tc[b, k, s] = T[k + 1, b, s]``
-in which column ``b`` is the contiguous row ``Tc[b]``.  The one-row shift
+``T[a, :]``, and column ``b``, ``T[:, b]``.  A column is a strided slab,
+which no copy can take whole.  The wavefront therefore carries a second,
+transposed copy ``Tc[b, k] = T[k + 1, b]`` in which column ``b`` is the
+contiguous row ``Tc[b]``.  The one-row shift
 aligns the two terms of candidate ``c``: ``T[a, c - 1, s]`` and
 ``T[c, b, s]`` both sit at index ``k = c - 1`` of their row, so one
 8-aligned sublane window of ``k`` serves both.
 
-Both tables stay in HBM (``memory_space=pl.ANY``).  A program copies only
-the rows of its band (below) from ``T[i, a]`` and ``Tc[i, b]`` into two
-VMEM slots of ``[H, S]`` each, with ``pltpu.make_async_copy``: while chunk
+All three tables stay in HBM (``memory_space=pl.ANY``).  A program copies
+only the rows of its band (below) from ``T[i, a]`` and ``Tc[i, b]`` into two
+VMEM slots of ``[H, S/128, 128]`` each, with ``pltpu.make_async_copy``, a
+slice of a leading dimension: while chunk
 ``j`` is scanned, chunk ``j + 1`` is in flight, and a program's last chunk
 starts the first chunk of the next live program in grid order, so no
 program waits for its first copy but the grid's first.  The skip's row
 ``T[a, b - 1]`` is the band's last row and needs no copy of its own.
 
 Per-file scalars (``left/right/x/nl`` flattened to ``[B * R]``, and ``u``)
-ride as scalar-prefetch operands in SMEM, read by ``program_id``.  The two
-per-candidate vectors ``right[c - 1]`` and ``nl[c]`` come as ``[B, R, 1]``
-VMEM columns.  Outputs are ``[B, R, 1, S]`` (one legal ``(1, S)`` block per
-program), reshaped to ``[B, R, S]`` outside the kernel.
+ride as scalar-prefetch operands in SMEM, read by ``program_id``; the two
+per-candidate scalars ``right[c - 1]`` and ``nl[c]`` are read there too, one
+pair per band row.
+
+Writing the diagonal in place
+-----------------------------
+``T``, ``Tc`` and ``C`` enter the launch as inputs aliased
+(``input_output_aliases``) to its three outputs, each in blocks of one
+cell's tile ``(1, 1, 1, S/128, 128)``: program ``(i, a)`` writes its value
+to ``T[i, a, a + d]`` and ``Tc[i, a + d, a - 1]`` and its argmin to ``C[i,
+a, a + d]``; nothing else touches the tables between launches.  At ``a = 0``
+the value is never a column term, and goes to ``Tc[i, d, R - 1]``, a row no
+live candidate reads (``k = c - 1 <= b - 1 <= R - 2``).  A launch reads only
+diagonals ``< d`` and the rows of its own window, so no program reads what
+another writes in it.  A dead program (``a + d >= R``) maps its three
+blocks to those of the diagonal's last live program, ``(i, R - d - 1)``,
+and writes nothing.  The dead programs of an instance follow that program
+in grid order, so the pipeline sees the same block index again, keeps the
+live program's output in place, and writes it back once, unchanged, when
+the index moves on (the accumulator pattern).  So no cell outside diagonal
+``d`` is written: the lower triangle keeps 0 in ``T`` and -1 in ``C``.
 
 Banded candidate scan
 ---------------------
@@ -54,25 +81,26 @@ is the rows ``k = c - 1`` of those candidates and of the skip's row
 sublane tile) to ``b - 1`` (:func:`band_chunks`).  The kernel walks it in
 chunks of ``H`` rows (:func:`chunk_rows`: ``cand_tile``, or under a LOGDP
 span the one chunk of ``span + 8`` rows, rounded up to 8, that holds any
-band whole): a ``fori_loop`` over the chunks, each masked to the live
-candidates and folded into a running ``(min, argmin)``.  The argmin of a
-chunk is ``min`` over ``where(cand == min, c, INT_MAX)`` — the smallest
-minimising ``c``.  Chunks ascend in ``c`` and the fold improves strictly, so
-the result is the *smallest* minimising ``c`` — identical tie-breaking to
-the exact Python DP (skip wins ties against detours; among detours the
-smallest ``c`` wins).  The last chunk base is clamped to ``R - H``; the
-overlap re-evaluates candidates the strict fold ignores.  A program with
-``a + d >= R`` lies past the diagonal's end: its band is empty, it copies
-and computes nothing, and the scatter of :func:`ltsp_dp_tables` drops its
-output blocks.  :func:`band_copy_bytes` counts the bytes the copies move.
+band whole): a ``fori_loop`` over the chunks, and within a chunk over its
+rows, each an ``(S/128, 128)`` slab folded elementwise into a running
+``(min, argmin)``; rows outside the live candidates are lifted to ``big``
+first.  Rows ascend in ``c`` and the fold improves strictly, so the result
+is the *smallest* minimising ``c`` — identical tie-breaking to the exact
+Python DP (skip wins ties against detours; among detours the smallest ``c``
+wins).  The last chunk base is clamped to ``R - H``; the overlap
+re-evaluates candidates the strict fold ignores.  A program with ``a + d >=
+R`` lies past the diagonal's end: its band is empty, and it copies, computes
+and writes nothing.  :func:`band_copy_bytes` counts the bytes the copies
+move and :func:`writeback_bytes` those the output blocks write.
 
 ``dimension_semantics`` audit of the ``(B, R)`` grid: the batch dimension
 indexes independent instances and the window-start dimension indexes cells of
 *one* anti-diagonal, which only read diagonals ``< d`` (frozen in this launch)
-and write disjoint output blocks — no program on the grid observes another's
-write.  The copies run ahead across programs in grid order, though, so both
-dimensions are declared ``"arbitrary"``: a chip that split the grid over
-cores would leave a core's first program waiting on a copy nobody started.
+and write disjoint output blocks (a dead program revisits a live one's block
+and writes nothing) — no program on the grid observes another's write.  The
+copies run ahead across programs in grid order, though, so both dimensions
+are declared ``"arbitrary"``: a chip that split the grid over cores would
+leave a core's first program waiting on a copy nobody started.
 Compiled mode only; the interpreter runs the grid in order.
 
 The kernel additionally emits a per-cell **argmin plane** ``C[a, b, s]``
@@ -97,8 +125,13 @@ Layout notes
 * The tables are ``int32``, exact under the guard of :mod:`.ops` (see
   *Saturating sums* below); :func:`ltsp_dp_tables` refuses any other dtype.
 * The ``skip`` term needs the shifted gather ``row[min(s + x_b, S - 1)]``;
-  ``x_b`` is a scalar per program, so it is a lane rotation by ``-x_b`` plus
-  a mask that substitutes ``row[S - 1]`` where ``s + x_b`` passes the end.
+  ``x_b`` is a scalar per program.  With ``q = x_b // 128`` and ``r = x_b %
+  128``, element ``(p, l)`` reads lane ``l + r`` of tile row ``p + q``, or
+  lane ``l + r - 128`` of row ``p + q + 1``: one lane rotation by ``-r``,
+  two reads of the rotated row from tile rows ``q`` and ``q + 1`` on (a
+  small VMEM buffer, as a dynamic sublane rotation of fewer than 8 rows does
+  not lower), a select on ``l + r < 128``, and a mask that substitutes
+  ``row[S - 1]`` where ``s + x_b`` passes the end.
 
 Saturating sums
 ---------------
@@ -127,6 +160,7 @@ cell a reader takes, so a masked candidate row never wins there.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -140,6 +174,7 @@ __all__ = [
     "ltsp_dp_tables",
     "band_chunks",
     "band_copy_bytes",
+    "writeback_bytes",
     "chunk_rows",
     "INT32_CAP",
 ]
@@ -149,6 +184,8 @@ DEFAULT_CAND_TILE = 32
 
 #: sublane tile height: dynamic row windows start at multiples of this.
 _SUBLANES = 8
+#: lane width: the skip-count axis is tiled as [S / 128, 128]
+_LANES = 128
 
 #: int32 sums saturate here, and masked candidate rows read it
 _INT32_MAX = 2**31 - 1
@@ -214,6 +251,14 @@ def band_copy_bytes(
     return int(n_chunks.sum()) * B * 2 * H * S * itemsize
 
 
+def writeback_bytes(B: int, R: int, S: int, itemsize: int) -> int:
+    """HBM bytes the wavefront writes in place in one launch of
+    :func:`ltsp_dp_tables`: one ``S``-word row of each of ``T``, ``Tc`` and
+    ``C`` per live program, ``B R (R - 1) / 2`` of them over the ``R - 1``
+    diagonals.  Dead programs write nothing, whatever the span or clip."""
+    return 3 * B * (R * (R - 1) // 2) * S * itemsize
+
+
 def wavefront_kernel(
     # scalar-prefetch inputs (SMEM)
     d_ref,  # [1] int32 — current anti-diagonal
@@ -222,28 +267,31 @@ def wavefront_kernel(
     right_ref,  # [B * R] int32
     x_ref,  # [B * R] int32
     nl_ref,  # [B * R] int32
-    # tensor inputs
-    t_hbm,  # [B, R, R, S] in HBM — T
-    tc_hbm,  # [B, R, R, S] in HBM — Tc; Tc[i, b, k] = T[i, k + 1, b]
-    rk_ref,  # [1, R, 1] int32 — right[i, k]   (= r_{c-1} at k = c - 1)
-    nk_ref,  # [1, R, 1] int32 — nl[i, k + 1]  (= nl_c   at k = c - 1)
-    # outputs
-    val_ref,  # [1, 1, 1, S] — new T[a, a+d, :]
-    cho_ref,  # [1, 1, 1, S] int32 — argmin plane (-1 = skip, else c)
+    # tensor inputs, in HBM
+    t_hbm,  # [B, R, R, L, 128] — T
+    tc_hbm,  # [B, R, R, L, 128] — Tc; Tc[i, b, k] = T[i, k + 1, b]
+    c_hbm,  # [B, R, R, L, 128] — C, never read: aliased to c_out
+    # outputs: one (L, 128) block of each table, aliased to the inputs
+    t_out,  # T[i, a, a + d]
+    tc_out,  # Tc[i, a + d, a - 1], or Tc[i, d, R - 1] at a = 0
+    c_out,  # C[i, a, a + d]: -1 = skip, else the detour start c
     # scratch
-    t_buf,  # [2, H, S] — two slots of band rows of T[i, a]
-    c_buf,  # [2, H, S] — the same rows of Tc[i, b]
+    t_buf,  # [2, H, L, 128] — two slots of band rows of T[i, a]
+    c_buf,  # [2, H, L, 128] — the same rows of Tc[i, b]
+    skip_buf,  # [2 L, 128] — the skip's row, rolled by its lane shift
     sem,  # DMA semaphores [2 tables, 2 slots]
     slot_ref,  # [1] int32 SMEM — slot of this program's first chunk
     *,
-    S: int,
     span: int | None,
     disjoint: bool,
 ):
+    del c_hbm
     i = pl.program_id(0)
     a = pl.program_id(1)
-    B, R = t_hbm.shape[0], t_hbm.shape[1]
+    B, R, L = t_hbm.shape[0], t_hbm.shape[1], t_hbm.shape[3]
+    S = L * _LANES
     H = t_buf.shape[1]
+    unroll = math.gcd(H, _SUBLANES)  # rows folded per step of the row loop
     d = d_ref[0]
     dtype = jnp.int32
     # stored values are clipped to cap and sums to big, strictly above them,
@@ -285,7 +333,8 @@ def wavefront_kernel(
     k_first, n_chunks = band(a)
 
     # a dead program (a + d >= R) has no chunk: it copies and computes
-    # nothing, and the host-side scatter drops its output blocks
+    # nothing and leaves its output blocks alone, which are the last live
+    # program's (module docstring, *Writing the diagonal in place*)
     @pl.when(n_chunks > 0)
     def _():
         b = a + d
@@ -296,38 +345,47 @@ def wavefront_kernel(
         r_b = right_ref[base + b]
         r_bm1 = right_ref[base + b - 1]
         l_b = left_ref[base + b]
-        svec = jax.lax.broadcasted_iota(dtype, (1, S), 1)
+        row_id = jax.lax.broadcasted_iota(dtype, (L, _LANES), 0)
+        lane = jax.lax.broadcasted_iota(dtype, (L, _LANES), 1)
+        svec = row_id * _LANES + lane  # the skip count s of each element
 
         # -------------- min over detour_c, banded to a < c <= b ------------
         # Live candidates: c in [c_min, b] (module docstring).  Band rows
-        # outside them hold other cells' values, so they compute harmless
-        # integers before the mask replaces them.
+        # outside them hold other cells' values; they compute harmless
+        # integers that the floor below lifts to big.
         c_min = _c_min(a, b, span, disjoint, jnp)
 
-        def chunk(slot, k0):
-            """Fold candidates ``c = k0 + 1 + j``, ``j in [0, H)``, masked to
-            the live band: ``(min, smallest minimising c)``, each ``[1, S]``."""
-            t_left = t_buf[slot]  # T[a, c-1, s]
-            t_right = c_buf[slot]  # T[c, b, s]
-            r_cm1 = rk_ref[0, pl.ds(k0, H), :]  # [H, 1]
-            nl_c = nk_ref[0, pl.ds(k0, H), :]  # [H, 1]
-            # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c)
-            # as one affine function of s per row: a multiply and an add per
-            # element
-            slope = two * (r_b - r_cm1 + u)
-            offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
-            cand = _add_sat(t_left + t_right, svec * slope + offset, big)
-            cvec = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) + (k0 + 1)
-            cand = jnp.where((cvec >= c_min) & (cvec <= b), cand, big)
-            cmin = jnp.min(cand, axis=0, keepdims=True)
-            # first minimiser == smallest c, matching the exact DP's
-            # ascending-c strict-improvement scan
-            carg = jnp.min(
-                jnp.where(cand == cmin, cvec, jnp.iinfo(jnp.int32).max),
-                axis=0,
-                keepdims=True,
-            )
-            return cmin, carg
+        def fold(slot, k0, carry):
+            """Fold candidates ``c = k0 + 1 + h``, ``h in [0, H)``, in
+            ascending ``c`` into the running ``(min, smallest minimising
+            c)``: each row is one ``(L, 128)`` slab, folded elementwise."""
+
+            def row(h, carry):
+                run_min, run_arg = carry
+                c = k0 + 1 + h
+                r_cm1 = right_ref[base + c - 1]
+                nl_c = nl_ref[base + jnp.minimum(c, R - 1)]
+                # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c)
+                # as one affine function of s: a multiply and an add per
+                # element
+                slope = two * (r_b - r_cm1 + u)
+                offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
+                cand = _add_sat(t_buf[slot, h] + c_buf[slot, h],  # T[a, c-1] + T[c, b]
+                                svec * slope + offset, big)
+                live = (c >= c_min) & (c <= b)
+                cand = jnp.maximum(cand, jnp.where(live, jnp.iinfo(dtype).min, big))
+                # strict improvement in ascending c keeps the smallest
+                # minimising c, as the exact DP's scan does
+                better = cand < run_min
+                return jnp.where(better, cand, run_min), jnp.where(better, c, run_arg)
+
+            def rows(g, carry):
+                g = jnp.asarray(g, jnp.int32)  # fori_loop index may be int64 (x64)
+                for h in range(unroll):
+                    carry = row(g * unroll + h, carry)
+                return carry
+
+            return jax.lax.fori_loop(0, H // unroll, rows, carry)
 
         s0 = slot_ref[0]
         last = b + 1 >= R
@@ -335,7 +393,6 @@ def wavefront_kernel(
         next_a = jnp.where(last, 0, a + 1)
 
         def body(j, carry):
-            run_min, run_arg = carry
             j = jnp.asarray(j, jnp.int32)  # fori_loop index may be int64 (x64)
             slot = (s0 + j) % 2
             k0 = chunk_base(k_first, j)
@@ -354,61 +411,75 @@ def wavefront_kernel(
 
             for cp in copies(i, a, k0, slot):
                 cp.wait()
-            cmin, carg = chunk(slot, k0)
-            improve = cmin < run_min
-            return jnp.minimum(run_min, cmin), jnp.where(improve, carg, run_arg)
+            return fold(slot, k0, carry)
 
         det, argc = jax.lax.fori_loop(
             0,
             n_chunks,
             body,
-            (jnp.full((1, S), big, dtype), jnp.zeros((1, S), jnp.int32)),
+            (jnp.full((L, _LANES), big, dtype), jnp.zeros((L, _LANES), jnp.int32)),
         )
 
         # -------------- skip(a, b, s) --------------------------------------
         # T[a, b-1, min(s + x_b, S-1)] from the band's last row, still in the
-        # last chunk's slot: rotate it left by x_b lanes, then put row[S-1]
-        # wherever s + x_b ran past the end (the clamp).
+        # last chunk's slot.  Element (p, l) holds s = 128 p + l and reads
+        # s + x_b = 128 (p + q) + l + r: lane l + r of tile row p + q, or
+        # lane l + r - 128 of row p + q + 1 where l + r passes the lanes.
+        # A lane roll by r puts both lanes in place; the rolled row, parked
+        # in skip_buf, is read back from rows q and q + 1 on.  Row[S-1] goes
+        # wherever s + x_b runs past the end (the clamp), which covers every
+        # element the roll wrapped around and every element read from the
+        # unwritten rows L .. 2L - 1 of skip_buf.
         j_last = n_chunks - 1
         k_last = chunk_base(k_first, j_last)
-        row_bm1 = t_buf[(s0 + j_last) % 2, pl.ds(b - 1 - k_last, 1), :]  # [1, S]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        rolled = pltpu.roll(row_bm1, (S - x_b) % S, 1)
-        row_end = jnp.max(
-            jnp.where(lane == S - 1, row_bm1, -big), axis=1, keepdims=True
+        row_bm1 = t_buf[(s0 + j_last) % 2, b - 1 - k_last]  # [L, 128]
+        q = jnp.minimum(x_b // _LANES, L - 1)
+        r = x_b % _LANES
+        skip_buf[pl.ds(0, L)] = pltpu.roll(row_bm1, (_LANES - r) % _LANES, 1)
+        lanes = jnp.where(
+            lane + r < _LANES, skip_buf[pl.ds(q, L)], skip_buf[pl.ds(q + 1, L)]
         )
-        shifted = jnp.where(lane + x_b <= S - 1, rolled, row_end)
+        row_end = jnp.max(
+            jnp.max(jnp.where(svec == S - 1, row_bm1, -big), axis=1, keepdims=True),
+            axis=0, keepdims=True,
+        )
+        shifted = jnp.where(svec + x_b <= S - 1, lanes, row_end)
         skip = _add_sat(
             _add_sat(shifted, two * (r_b - r_bm1) * (svec + nl_a), big),
             two * (l_b - r_bm1) * x_b,
             big,
         )
-        val_ref[0, 0] = jnp.minimum(jnp.minimum(skip, det), cap)
-        cho_ref[0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
+        val = jnp.minimum(jnp.minimum(skip, det), cap)
+        t_out[0, 0, 0] = val
+        tc_out[0, 0, 0] = val
+        c_out[0, 0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
 
 
 #: scoped-VMEM limit Mosaic applies on v5e when a kernel asks for none.
 _DEFAULT_SCOPED_VMEM = 16 << 20
 
 
-def _vmem_limit_bytes(R: int, S: int, itemsize: int, H: int) -> int | None:
+def _vmem_limit_bytes(S: int, itemsize: int, H: int) -> int | None:
     """Scoped-VMEM request for one program, or ``None`` for the compiler's
     default where that suffices.
 
     A program holds two slots of ``[H, S]`` band rows of each table
-    (``4 H S itemsize``), about four ``[H, S]`` int32 candidate temporaries,
-    its two ``(1, S)`` output blocks and its two ``[R, 1]`` candidate
-    columns (lane-padded to 128), each double-buffered.
+    (``4 H S itemsize``) and its three ``S``-word output blocks, each
+    double-buffered; its fold keeps a few ``S``-word rows live at a time.
+    The rows are ``(S/128, 128)`` tiles, padded to 8 sublanes below
+    ``S = 1024``.
     """
-    need = 4 * H * S * itemsize + 4 * H * S * 4 + 2 * 2 * S * 4 + 2 * 2 * R * 128 * 4
+    row = max(S, _SUBLANES * _LANES) * itemsize
+    need = 4 * H * row + 3 * 2 * row + 16 * row
     if need <= _DEFAULT_SCOPED_VMEM:
         return None
     return -(-need // (1 << 20)) << 20
 
 
 def ltsp_dp_wavefront(
-    T: jax.Array,  # [B, R, R, S]
-    Tc: jax.Array,  # [B, R, R, S] — Tc[i, b, k] = T[i, k + 1, b]
+    T: jax.Array,  # [B, R, R, L, 128]
+    Tc: jax.Array,  # [B, R, R, L, 128] — Tc[i, b, k] = T[i, k + 1, b]
+    C: jax.Array,  # [B, R, R, L, 128] int32
     left: jax.Array,  # [B, R]
     right: jax.Array,  # [B, R]
     x: jax.Array,  # [B, R] int32
@@ -416,18 +487,20 @@ def ltsp_dp_wavefront(
     u: jax.Array,  # [B]
     d: jax.Array,  # scalar int32 (traced — same kernel serves every diagonal)
     *,
-    S: int,
     span: int | None,
     disjoint: bool,
     interpret: bool,
     cand_tile: int,
-) -> tuple[jax.Array, jax.Array]:
-    """One anti-diagonal for every instance: ``([B, R, S], [B, R, S])``.
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One anti-diagonal for every instance, written in place: ``(T, Tc,
+    C)`` with diagonal ``d`` filled.
 
-    ``T`` and ``Tc`` stay in HBM; each program copies only its band's rows
-    of ``T[i, a]`` and ``Tc[i, a + d]`` (module docstring).
+    The three tables stay in HBM; each program copies only its band's rows
+    of ``T[i, a]`` and ``Tc[i, a + d]`` and writes its cell's row into each
+    table through an output block aliased to it (module docstring).
     """
     B, R = left.shape
+    L = T.shape[3]
     H = chunk_rows(R, span, cand_tile)
     # chunk bases (the last one clamped to R - H) must be sublane-aligned,
     # which the kernel asserts to Mosaic; a single chunk of R rows is at 0
@@ -436,23 +509,34 @@ def ltsp_dp_wavefront(
             f"compiled banded wavefront needs cand_tile and R multiples of "
             f"{_SUBLANES}, got cand_tile={cand_tile}, R={R}"
         )
-    kern = functools.partial(wavefront_kernel, S=S, span=span, disjoint=disjoint)
+    kern = functools.partial(wavefront_kernel, span=span, disjoint=disjoint)
+
+    def window(a, d_ref):
+        # a dead program (a + d >= R) revisits the last live program's block
+        return jnp.minimum(a, R - 1 - d_ref[0])
+
+    def cell(i, a, d_ref, *_):  # T[i, a, a + d] and C[i, a, a + d]
+        w = window(a, d_ref)
+        return i, w, w + d_ref[0], 0, 0
+
+    def column(i, a, d_ref, *_):  # Tc[i, a + d, a - 1]; row R - 1 at a = 0
+        w = window(a, d_ref)
+        return i, w + d_ref[0], jnp.where(w == 0, R - 1, w - 1), 0, 0
+
+    row_block = (1, 1, 1, L, _LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,  # d, u, left, right, x, nl
         grid=(B, R),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # T, copied by band
-            pl.BlockSpec(memory_space=pl.ANY),  # Tc, copied by band
-            pl.BlockSpec((1, R, 1), lambda i, a, *_: (i, 0, 0)),
-            pl.BlockSpec((1, R, 1), lambda i, a, *_: (i, 0, 0)),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,  # T, Tc, C
         out_specs=[
-            pl.BlockSpec((1, 1, 1, S), lambda i, a, *_: (i, a, 0, 0)),
-            pl.BlockSpec((1, 1, 1, S), lambda i, a, *_: (i, a, 0, 0)),
+            pl.BlockSpec(row_block, cell),
+            pl.BlockSpec(row_block, column),
+            pl.BlockSpec(row_block, cell),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, H, S), T.dtype),
-            pltpu.VMEM((2, H, S), T.dtype),
+            pltpu.VMEM((2, H, L, _LANES), T.dtype),
+            pltpu.VMEM((2, H, L, _LANES), T.dtype),
+            pltpu.VMEM((2 * L, _LANES), T.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
@@ -463,16 +547,14 @@ def ltsp_dp_wavefront(
         # ahead across programs in grid order, so neither dim is split
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit_bytes(R, S, T.dtype.itemsize, H),
+            vmem_limit_bytes=_vmem_limit_bytes(L * _LANES, T.dtype.itemsize, H),
         )
-    nk = jnp.concatenate([nl[:, 1:], jnp.zeros_like(nl[:, :1])], axis=1)
-    vals, chos = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, R, 1, S), T.dtype),
-            jax.ShapeDtypeStruct((B, R, 1, S), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (T, Tc, C)],
+        # operands 6, 7 and 8 (after the six scalars) are T, Tc and C
+        input_output_aliases={6: 0, 7: 1, 8: 2},
         interpret=interpret,
         name="ltsp_wavefront",
         **kwargs,
@@ -485,10 +567,8 @@ def ltsp_dp_wavefront(
         nl.reshape(-1),
         T,
         Tc,
-        right[:, :, None],
-        nk[:, :, None],
+        C,
     )
-    return vals.reshape(B, R, S), chos.reshape(B, R, S)
 
 
 @functools.partial(
@@ -509,49 +589,49 @@ def ltsp_dp_tables(
 ) -> tuple[jax.Array, jax.Array]:
     """Full batched DP tables ``(T, C)`` in a single jitted wavefront.
 
-    ``T[i, a, b, s]`` is the DP value table of instance ``i`` and
-    ``C[i, a, b, s]`` the argmin plane (-1 = skip, else detour start ``c``)
-    that the host traceback consumes.  One ``lax.fori_loop`` over the diagonal
-    index carries the ``(T, Tc, C)`` workspace (``Tc`` is the transposed,
-    one-row-shifted copy the column reads use, see the module docstring);
-    each iteration is one Pallas launch over the ``(instance, window-start)``
-    grid plus in-place diagonal scatters (``mode="drop"`` discards the
-    output blocks of the windows past the diagonal's end).  ``interpret`` has no default:
-    every caller says whether it runs the compiled kernel or the interpreter.
-    The inputs are int32 (``ops.prepare_batch``); any other dtype raises
-    ``TypeError`` when the call is traced.
+    Both are ``[B, R, R, S/128, 128]`` int32: cell ``(a, b)`` of instance
+    ``i`` is the ``(S/128, 128)`` tile ``T[i, a, b]``, whose element ``(p,
+    l)`` is skip count ``s = 128 p + l``.  ``T`` is the DP value table and
+    ``C`` the argmin plane (-1 = skip, else detour start ``c``) that the
+    traceback consumes; ``ops.host_table`` views either as ``[B, R, R, S]``
+    on the host.  Cells below the diagonal hold 0 in ``T`` and -1 in ``C``.
+    One ``lax.fori_loop`` over the diagonal index carries the ``(T, Tc, C)``
+    workspace (``Tc`` is the transposed, one-row-shifted copy the column
+    reads use, see the module docstring); each iteration is one Pallas
+    launch over the ``(instance, window-start)`` grid that writes its
+    diagonal into all three in place.  ``interpret`` has no default: every
+    caller says whether it runs the compiled kernel or the interpreter.  The
+    inputs are int32 (``ops.prepare_batch``) and ``S`` a multiple of 128;
+    any other dtype raises ``TypeError`` and any other ``S`` ``ValueError``
+    when the call is traced.
     """
     dtype = left.dtype
     if dtype != jnp.int32:
         raise TypeError(f"the wavefront computes in int32 only, got {dtype}")
+    if S % _LANES:
+        raise ValueError(f"S must be a multiple of {_LANES}, got {S}")
     B, R = left.shape
-    rr = jnp.arange(R)
+    L = S // _LANES
+    shape = (B, R, R, L, _LANES)
     # base diagonal T[b, b, s] = 2 s(b) (s + n_l(b)), batched, clipped like
     # every stored value
-    svec = jnp.arange(S, dtype=dtype)
-    base = 2 * (right - left)[:, :, None] * (svec[None, None, :] + nl[:, :, None])
-    base = jnp.minimum(base, jnp.asarray(INT32_CAP, dtype))
-    T = jnp.zeros((B, R, R, S), dtype)
-    T = T.at[:, rr, rr, :].set(base)
+    svec = jnp.arange(S, dtype=dtype).reshape(L, _LANES)
+    base = 2 * (right - left)[:, :, None, None] * (svec + nl[:, :, None, None])
+    base = jnp.minimum(base, jnp.asarray(INT32_CAP, dtype))[:, :, None]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    T = jnp.where(row == col, base, 0)
     # Tc[i, b, a - 1] = T[i, a, b]; a = 0 never appears as a column term
-    Tc = jnp.zeros((B, R, R, S), dtype)
-    Tc = Tc.at[:, rr[1:], rr[1:] - 1, :].set(base[:, 1:])
-    C = jnp.full((B, R, R, S), -1, jnp.int32)
+    Tc = jnp.where(col == row - 1, base, 0)
+    C = jnp.full(shape, -1, jnp.int32)
     if R == 1:
         return T, C
 
     def body(d, carry):
-        T, Tc, C = carry
-        vals, chos = ltsp_dp_wavefront(
-            T, Tc, left, right, x, nl, u, d,
-            S=S, span=span, disjoint=disjoint, interpret=interpret,
-            cand_tile=cand_tile,
+        return ltsp_dp_wavefront(
+            *carry, left, right, x, nl, u, d,
+            span=span, disjoint=disjoint, interpret=interpret, cand_tile=cand_tile,
         )
-        with jax.named_scope("ltsp_scatter"):
-            T = T.at[:, rr, rr + d, :].set(vals, mode="drop")
-            Tc = Tc.at[:, rr[1:] + d, rr[1:] - 1, :].set(vals[:, 1:], mode="drop")
-            C = C.at[:, rr, rr + d, :].set(chos, mode="drop")
-        return T, Tc, C
 
     T, _, C = jax.lax.fori_loop(1, R, body, (T, Tc, C))
     return T, C

@@ -3,10 +3,13 @@ traceback, and a size-bucketed batch planner.
 
 The device path is a **complete solver**: :func:`ltsp_dp_tables` (one jitted
 wavefront, see :mod:`.ltsp_dp`) returns the value table *and* per-cell argmin
-planes; :func:`~.walk.traceback_device` replays the argmin planes on the
-device to reconstruct the optimal detour list, exactly like the Python DP's
-traceback, so only the detours and root values cross to the host.
-:func:`traceback_detours` is the same walk on a host copy of a plane, the
+planes, each ``[B, R, R, S/128, 128]`` on the device, with every diagonal
+written in place by the kernel; :func:`~.walk.traceback_device` replays the
+argmin planes on the device to reconstruct the optimal detour list, exactly
+like the Python DP's traceback, so only the detours and root values cross to
+the host.  :func:`host_table` views a table copied to the host as ``[B, R,
+R, S]`` (no table is reshaped on the device, where that is a relayout copy),
+and :func:`traceback_detours` is the same walk on a host plane, the
 reference the device walk is tested against.
 
 The device DP computes in ``int32`` only.  It is bit-exact when every cell
@@ -70,13 +73,20 @@ import numpy as np
 
 from ...core.instance import Instance, virtual_lb
 from ...core.warm import DenseStore, WarmState, WarmStats, align_warm, warm_from_instance
-from .ltsp_dp import DEFAULT_CAND_TILE, INT32_CAP, band_copy_bytes, ltsp_dp_tables
+from .ltsp_dp import (
+    DEFAULT_CAND_TILE,
+    INT32_CAP,
+    band_copy_bytes,
+    ltsp_dp_tables,
+    writeback_bytes,
+)
 from .walk import traceback_device
 
 __all__ = [
     "prepare_batch",
     "plan_buckets",
     "bucket_shape",
+    "host_table",
     "rescale_instance",
     "traceback_detours",
     "ltsp_solve_instance",
@@ -276,6 +286,14 @@ def _check_int32_safe(instances: list[Instance]) -> None:
             )
 
 
+def host_table(table) -> np.ndarray:
+    """A ``[B, R, R, S/128, 128]`` table of :func:`ltsp_dp_tables` on the
+    host, viewed as ``[B, R, R, S]``: element ``(p, l)`` of a cell's tile is
+    skip count ``128 p + l``, so the view is a plain reshape of the copy."""
+    host = np.asarray(table)
+    return host.reshape(*host.shape[:3], -1)
+
+
 def traceback_detours(choice: np.ndarray, mult: np.ndarray) -> list[tuple[int, int]]:
     """Replay an argmin plane ``choice[R, R, S]`` into the detour list.
 
@@ -375,8 +393,8 @@ def _solve_packed(
         for a in walked:
             a.copy_to_host_async()
         dets_host, n_dets, steps = (np.asarray(walked[k]) for k in (0, 1, 3))
-        C_host = np.asarray(C) if capture else None
-        T_host = np.asarray(T) if capture else None
+        C_host = host_table(C) if capture else None
+        T_host = host_table(T) if capture else None
     with _span(profile, "ltsp.fetch_root"):
         T_root = np.asarray(walked[2])
     with _span(profile, "ltsp.traceback"):
@@ -419,6 +437,7 @@ def _solve_packed(
             operand_bytes=band_copy_bytes(
                 B, R, S, np.dtype(np.int32).itemsize, span, disjoint, cand_tile
             ),
+            writeback_bytes=writeback_bytes(B, R, S, np.dtype(np.int32).itemsize),
         )
     return out, stores
 

@@ -19,9 +19,11 @@ between the frame it pushes and the one it enters.  So every instance walks
 exactly ``R - 1`` steps, with at most ``R - 1`` detours and pushed frames.
 
 The walk reads ``C``, ``x`` and the root of ``T`` in place (single-cell
-dynamic slices, no copy of either plane) and is keyed by the arrays' shapes
-alone: the LOGDP span, the SIMPLEDP band clip and the policy leave their
-mark in ``C`` only.  All-phantom padding rows walk as ``R - 1`` skips.
+dynamic slices, no copy of either plane), in the wavefront's lane-tiled
+layout: skip count ``s`` of cell ``(a, b)`` is ``C[i, a, b, s // 128, s %
+128]``.  It is keyed by the arrays' shapes alone: the LOGDP span, the
+SIMPLEDP band clip and the policy leave their mark in ``C`` only.
+All-phantom padding rows walk as ``R - 1`` skips.
 """
 
 from __future__ import annotations
@@ -37,15 +39,16 @@ __all__ = ["traceback_device"]
 def traceback_device(T: jax.Array, C: jax.Array, x: jax.Array):
     """Walk each instance's argmin plane on the device.
 
-    ``T`` and ``C`` are the ``[B, R, R, S]`` tables of
+    ``T`` and ``C`` are the ``[B, R, R, S/128, 128]`` tables of
     :func:`.ltsp_dp.ltsp_dp_tables` (``T`` in any dtype), ``x`` the
     ``[B, R]`` int32 multiplicities.  Returns ``(detours, n_detours, root,
     steps)``: ``detours[i, :n_detours[i]]`` are instance ``i``'s ``(c, b)``
     pairs in emission order (int32 ``[B, R, 2]``, ``-1`` past the end),
-    ``root[i] = T[i, 0, R-1, 0]`` in ``T``'s dtype, and ``steps[i]`` the
-    walk's loop steps (int32 ``[B]``).
+    ``root[i] = T[i, 0, R-1, 0, 0]`` (skip count 0) in ``T``'s dtype, and
+    ``steps[i]`` the walk's loop steps (int32 ``[B]``).
     """
     B, R = x.shape
+    lanes = C.shape[-1]
     i32 = jnp.int32
 
     def walk(i):
@@ -59,7 +62,9 @@ def traceback_device(T: jax.Array, C: jax.Array, x: jax.Array):
             b = jnp.where(pop, top[1], b)
             s = jnp.where(pop, top[2], s)
             sp = sp - pop.astype(i32)
-            c = lax.dynamic_slice(C, (i, a, b, s), (1, 1, 1, 1))[0, 0, 0, 0]
+            c = lax.dynamic_slice(
+                C, (i, a, b, s // lanes, s % lanes), (1, 1, 1, 1, 1)
+            )[0, 0, 0, 0, 0]
             skip = c == -1
             # slot nd is still -1 here, so a skip writes it back unchanged
             dets = dets.at[nd].set(jnp.where(skip, -1, jnp.stack([c, b])))
@@ -86,4 +91,4 @@ def traceback_device(T: jax.Array, C: jax.Array, x: jax.Array):
         return dets, nd, steps
 
     dets, nd, steps = lax.map(walk, jnp.arange(B, dtype=i32))
-    return dets, nd, T[:, 0, R - 1, 0], steps
+    return dets, nd, T[:, 0, R - 1, 0, 0], steps

@@ -23,7 +23,10 @@ attached through ``ExecutionContext.obs`` records one
   launch's rows (all-phantom padding rows included);
 * ``operand_bytes`` — the exact HBM bytes the wavefront's band copies of
   its two table operands move over the launch's diagonals
-  (``ltsp_dp.band_copy_bytes``), counted from the launch's shape.
+  (``ltsp_dp.band_copy_bytes``), counted from the launch's shape;
+* ``writeback_bytes`` — the exact HBM bytes the wavefront writes in place
+  over the launch's diagonals: one ``S``-word row of each of its three
+  tables per live program (``ltsp_dp.writeback_bytes``, in closed form).
 
 Time comes from :meth:`KernelProfile.span`: the device path opens one
 ``jax.profiler.TraceAnnotation`` per phase (``ltsp.rescale`` …
@@ -56,6 +59,7 @@ class LaunchRecord:
     d2h_bytes: int
     walk_steps: int = 0
     operand_bytes: int = 0
+    writeback_bytes: int = 0
 
     @property
     def waste(self) -> tuple[int, int]:
@@ -90,6 +94,7 @@ class KernelProfile:
         d2h_bytes: int,
         walk_steps: int = 0,
         operand_bytes: int = 0,
+        writeback_bytes: int = 0,
     ) -> None:
         cold = signature not in self._seen
         self._seen.add(signature)
@@ -107,6 +112,7 @@ class KernelProfile:
                 d2h_bytes=d2h_bytes,
                 walk_steps=walk_steps,
                 operand_bytes=operand_bytes,
+                writeback_bytes=writeback_bytes,
             )
         )
 
@@ -132,6 +138,7 @@ class KernelProfile:
             "d2h_bytes": sum(r.d2h_bytes for r in self.launches),
             "walk_steps": sum(r.walk_steps for r in self.launches),
             "operand_bytes": sum(r.operand_bytes for r in self.launches),
+            "writeback_bytes": sum(r.writeback_bytes for r in self.launches),
         }
 
     def __len__(self) -> int:

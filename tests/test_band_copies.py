@@ -1,11 +1,13 @@
-"""The wavefront's band copies, counted on the host: ``band_copy_bytes`` and
-``LaunchRecord.operand_bytes`` against a plain loop over the live programs
-of every diagonal and the chunks of their bands."""
+"""The wavefront's band copies and in-place writes, counted on the host:
+``band_copy_bytes`` and ``LaunchRecord.operand_bytes`` against a plain loop
+over the live programs of every diagonal and the chunks of their bands, and
+``LaunchRecord.writeback_bytes`` against its closed form."""
 
 import numpy as np
 import pytest
 
 from repro.core import make_instance
+from repro.kernels.ltsp_dp import ltsp_dp
 from repro.kernels.ltsp_dp.ltsp_dp import DEFAULT_CAND_TILE, band_copy_bytes
 from repro.kernels.ltsp_dp.ops import bucket_shape, ltsp_solve_batch
 from repro.obs import KernelProfile
@@ -54,9 +56,8 @@ def test_band_copy_bytes_at_the_median_bucket():
     assert band_copy_bytes(1, 256, 4096, 4, 5, False, 16) == 2 * 522_240 * row
 
 
-@pytest.mark.parametrize("span, disjoint", [(None, False), (5, False), (None, True)],
-                         ids=["dp", "logdp-span5", "simpledp-disjoint"])
-def test_launch_record_counts_the_band_copies(span, disjoint):
+def _bucket_16_128():
+    """Two instances of the bucket (16, 128), so one launch of B = 2."""
     rng = np.random.default_rng(20261019)
     insts = []
     for n_req in (12, 15):
@@ -65,6 +66,13 @@ def test_launch_record_counts_the_band_copies(span, disjoint):
         insts.append(make_instance(left, size, rng.integers(1, 4, n_req),
                                    m=int(left[-1] + size[-1]), u_turn=3))
     assert {bucket_shape(i) for i in insts} == {(16, 128)}
+    return insts
+
+
+@pytest.mark.parametrize("span, disjoint", [(None, False), (5, False), (None, True)],
+                         ids=["dp", "logdp-span5", "simpledp-disjoint"])
+def test_launch_record_counts_the_band_copies(span, disjoint):
+    insts = _bucket_16_128()
     profile = KernelProfile()
     ltsp_solve_batch(insts, span=span, disjoint=disjoint, interpret=True,
                      profile=profile)
@@ -73,3 +81,38 @@ def test_launch_record_counts_the_band_copies(span, disjoint):
     assert rec.operand_bytes == loop_bytes(2, 16, 128, 4, span, disjoint,
                                            DEFAULT_CAND_TILE) > 0
     assert profile.summary()["operand_bytes"] == rec.operand_bytes
+
+
+@pytest.mark.parametrize("span, disjoint", [(None, False), (5, False), (None, True)],
+                         ids=["dp", "logdp-span5", "simpledp-disjoint"])
+def test_launch_record_counts_the_in_place_writes(span, disjoint, monkeypatch):
+    """Each live program writes one S-word row of T, Tc and C: 3 B R (R - 1)
+    / 2 rows of 4 S bytes a launch, whatever the policy.  Recording the
+    counts of a second launch of one shape counts no band again."""
+    insts = _bucket_16_128()
+    profile = KernelProfile()
+    ltsp_solve_batch(insts, span=span, disjoint=disjoint, interpret=True,
+                     profile=profile)
+    host_counts = []
+    real = ltsp_dp.band_chunks
+
+    def counted(*args, **kwargs):
+        if kwargs.get("xp") is np:
+            host_counts.append(args)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(ltsp_dp, "band_chunks", counted)
+    ltsp_solve_batch(insts, span=span, disjoint=disjoint, interpret=True,
+                     profile=profile)
+    assert host_counts == []
+    first, second = profile.launches
+    B, R, S = 2, 16, 128
+    assert first.writeback_bytes == second.writeback_bytes == 3 * B * R * (R - 1) // 2 * S * 4
+    assert first.operand_bytes == second.operand_bytes
+    assert profile.summary()["writeback_bytes"] == 2 * first.writeback_bytes
+
+
+def test_writeback_bytes_at_the_median_bucket():
+    """1.60 GB a launch at (1, 256, 4096): three 16 KiB rows for each of the
+    32,640 live programs."""
+    assert ltsp_dp.writeback_bytes(1, 256, 4096, 4) == 3 * 32_640 * 16_384 == 1_604_321_280

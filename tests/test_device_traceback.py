@@ -14,6 +14,7 @@ from repro.core.dp import dp_schedule_warm
 from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
 from repro.kernels.ltsp_dp.ops import (
     bucket_shape,
+    host_table,
     ltsp_solve_batch,
     prepare_batch,
     rescale_instance,
@@ -39,7 +40,7 @@ def _check(T, C, x, expected):
     """The device walk of ``(T, C, x)`` against the host walk of each row,
     and the real rows' host walks against the policy's own ``expected``."""
     dets, n_dets, root, steps = jax.device_get(traceback_device(T, C, x))
-    T, C, x = np.asarray(T), np.asarray(C), np.asarray(x)
+    T, C, x = host_table(T), host_table(C), np.asarray(x)
     B, R = x.shape
     host = [traceback_detours(C[i], x[i]) for i in range(B)]
     assert host[: len(expected)] == expected
@@ -66,7 +67,8 @@ def _kernel_tables(insts, B_pad=None, span=None, disjoint=False):
 
 def _core_dp_planes(*shapes):
     """Dense planes holding the python DP's recorded choices and root
-    values, instances of ``(n_req, seed)`` in one ``[B, R, R, S]``."""
+    values, instances of ``(n_req, seed)`` in one ``[B, R, R, S]``, laid
+    out as the wavefront lays its tables out, ``[B, R, R, S/128, 128]``."""
     insts = [_instance(n_req, seed) for n_req, seed in shapes]
     B, R, S = len(insts), max(i.n_req for i in insts), 128
     T = np.zeros((B, R, R, S), np.int32)
@@ -80,7 +82,9 @@ def _core_dp_planes(*shapes):
         T[i, 0, R - 1, 0] = warm.store._memo[(0, inst.n_req - 1, 0)]
         x[i, : inst.n_req] = inst.mult
         expected.append(dets)
-    _check(jnp.asarray(T), jnp.asarray(C), jnp.asarray(x), expected)
+    tiled = (B, R, R, S // 128, 128)
+    _check(jnp.asarray(T.reshape(tiled)), jnp.asarray(C.reshape(tiled)),
+           jnp.asarray(x), expected)
 
 
 def _phantom_rows():

@@ -30,6 +30,7 @@ from repro.kernels.ltsp_dp.ops import (
     _cell_bound,
     _int32_admits,
     _term_bound,
+    host_table,
     ltsp_solve_batch,
     prepare_batch,
     rescale_instance,
@@ -173,7 +174,7 @@ def test_sums_past_int32_saturate_and_every_stored_value_stays_in_range():
         assert _int32_admits(inst)
         left, right, x, nl, u, S = prepare_batch([inst], R_pad=4, S_pad=128)
         T, _ = ltsp_dp_tables(left, right, x, nl, u, S=S, interpret=True)
-        read = _envelope(np.asarray(T), inst)
+        read = _envelope(host_table(T), inst)
         assert read == _read_cells(inst)[0]
         assert 0 <= min(read.values()) and max(read.values()) <= INT32_CAP
     for inst in (FAR_LANES, WRAPPING_LANES):

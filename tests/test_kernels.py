@@ -1,15 +1,16 @@
 """Pallas LTSP-DP kernel against the dense NumPy reference (:mod:`.ref`,
 every cell and lane of ``T`` and ``C``) and the exact Python DP: shapes,
-chunked bands, LOGDP spans, SIMPLEDP's clip, skip-count padding, and the
-skip's clamp, all on the int32 path the solver runs."""
+chunked bands, LOGDP spans, SIMPLEDP's clip, skip-count padding, the skip's
+shift across the lane-tiled rows and its clamp, and the in-place writes of
+one diagonal, all on the int32 path the solver runs."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import dp_schedule, make_instance
-from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
-from repro.kernels.ltsp_dp.ops import prepare_batch, traceback_detours
+from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables, ltsp_dp_wavefront
+from repro.kernels.ltsp_dp.ops import host_table, prepare_batch, traceback_detours
 from repro.kernels.ltsp_dp.ref import ltsp_tables_ref, root_cost
 
 
@@ -32,7 +33,7 @@ def _kernel_and_ref(inst, S_pad=None, **kw):
     host = [np.asarray(v[0]) for v in (left, right, x, nl, u)]
     span, disjoint = kw.get("span"), kw.get("disjoint", False)
     T_ref, C_ref, _ = ltsp_tables_ref(*host, S, span, disjoint)
-    return (np.asarray(T[0]), np.asarray(C[0])), (T_ref, C_ref)
+    return (host_table(T)[0], host_table(C)[0]), (T_ref, C_ref)
 
 
 def _assert_tables_equal(got, want):
@@ -209,7 +210,7 @@ def test_band_only_kernel_matches_references(R, n_req, B, span, disjoint, cand_t
     left, right, x, nl, u, S = prepare_batch(insts, R_pad=R)
     T, C = ltsp_dp_tables(left, right, x, nl, u, S=S, span=span, disjoint=disjoint,
                           cand_tile=cand_tile, interpret=True)
-    T, C = np.asarray(T), np.asarray(C)
+    T, C = host_table(T), host_table(C)
     met = np.zeros(2, np.int64)
     for i, inst in enumerate(insts):
         host = [np.asarray(v[i], np.int64) for v in (left, right, x, nl)]
@@ -224,3 +225,78 @@ def test_band_only_kernel_matches_references(R, n_req, B, span, disjoint, cand_t
         assert sorted(got[1]) == sorted(want[1])
     if ties:
         assert met[0] > 0 and met[1] > 0, met
+
+
+@pytest.mark.parametrize(
+    "mult, S",
+    [
+        # x_b of 60 and 100 cross a 128-lane boundary; 130 and 129 start
+        # past one; s + x_b passes S - 1 on many lanes
+        pytest.param([60, 130, 1, 40, 20], 256, id="S256"),
+        pytest.param([200, 129, 3, 150, 27, 2], 512, id="S512"),
+        pytest.param([100, 260, 7, 128, 1, 3], 512, id="S512-q2"),
+    ],
+)
+def test_skip_shift_across_lane_tiles(mult, S):
+    """A row of S skip counts is S/128 tiles of 128 lanes: the skip's read
+    of ``s + x_b`` comes from tile row ``p + q`` or ``p + q + 1`` (``q =
+    x_b // 128``) and lane ``(l + x_b) % 128``, clamped to lane ``S - 1``.
+    Every cell and lane of the kernel tables equals the reference's."""
+    R = len(mult)
+    size = [5, 6, 9, 8, 3, 4][:R]
+    left = np.cumsum([0] + [sz + 2 for sz in size[:-1]]).tolist()
+    inst = make_instance(left, size, mult, m=left[-1] + size[-1], u_turn=3)
+    assert inst.n + 1 <= S and 2 * inst.n >= S  # the smallest bucket of n
+    kernel, ref = _kernel_and_ref(inst, S_pad=S)
+    assert kernel[0].shape[-1] == S
+    _assert_tables_equal(kernel, ref)
+    xs = np.asarray(mult[1:])
+    assert (xs >= 128).any() and ((xs % 128) > 0).any()
+
+
+def _tc_of(T):
+    """The transposed copy the wavefront reads columns from:
+    ``Tc[i, b, k] = T[i, k + 1, b]``, row ``R - 1`` left as it is."""
+    Tc = np.zeros_like(T)
+    Tc[:, :, :-1] = np.swapaxes(T, 1, 2)[:, :, 1:]
+    return Tc
+
+
+@pytest.mark.parametrize("d", [1, 6, 15])
+def test_one_diagonal_is_written_in_place_and_nothing_else(d):
+    """One launch at diagonal ``d`` over two instances, whose dead programs
+    ``a >= R - d`` revisit the last live program's blocks before the second
+    instance starts.  Diagonal ``d`` of ``T``, ``Tc`` and ``C`` equals the
+    reference; every other cell keeps the sentinel or the value it held,
+    the upper triangle past ``d`` and the base diagonal included."""
+    rng = np.random.default_rng(d)
+    R, S = 16, 256
+    insts = [_small_instance(rng, R) for _ in range(2)]
+    left, right, x, nl, u, S = prepare_batch(insts, S_pad=S)
+    refs = [ltsp_tables_ref(*(np.asarray(v[i]) for v in (left, right, x, nl, u)), S)
+            for i in range(2)]
+    T_ref = np.stack([r[0] for r in refs]).astype(np.int32)
+    C_ref = np.stack([r[1] for r in refs]).astype(np.int32)
+    a, b = np.meshgrid(np.arange(R), np.arange(R), indexing="ij")
+    before = (b - a < d)[None, :, :, None]  # diagonals already computed
+    T0 = np.where(before & (b >= a)[None, :, :, None], T_ref, 777_000).astype(np.int32)
+    C0 = np.where(before & (b > a)[None, :, :, None], C_ref, 555).astype(np.int32)
+    Tc0 = _tc_of(T0)
+    Tc0[:, :, -1] = 333
+    tiled = (2, R, R, S // 128, 128)
+    T1, Tc1, C1 = (
+        host_table(t) for t in ltsp_dp_wavefront(
+            *(jnp.asarray(t.reshape(tiled)) for t in (T0, Tc0, C0)),
+            left, right, x, nl, u, d,
+            span=None, disjoint=False, interpret=True, cand_tile=8,
+        )
+    )
+    on = (b - a == d)[None, :, :, None]
+    np.testing.assert_array_equal(T1, np.where(on, T_ref, T0))
+    np.testing.assert_array_equal(C1, np.where(on, C_ref, C0))
+    Tc_want = Tc0.copy()
+    for i in range(2):
+        for a0 in range(R - d):
+            k = a0 - 1 if a0 > 0 else R - 1  # a = 0 writes row R - 1
+            Tc_want[i, a0 + d, k] = T_ref[i, a0, a0 + d]
+    np.testing.assert_array_equal(Tc1, Tc_want)

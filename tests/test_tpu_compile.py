@@ -7,7 +7,9 @@ VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
 median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
 disjoint variants, and the band in 16-row chunks; the paper's tail bucket
 (256, 8192), which the chip benchmark runs, exact and under a LOGDP span;
-and R = 128, whose bands are chunked like the large buckets'.  Each program
+and R = 128, whose bands are chunked like the large buckets'.  At the
+cells' buckets the kernel writes each diagonal into the tables in place, so
+the optimised program holds no scatter and no copy of a table.  Each program
 must fit the chip's 16 GB of HBM, and the kernel's scoped VMEM what the
 wavefront asks for (``_vmem_limit_bytes``).  The device traceback that walks
 the argmin plane compiles at the median bucket too, holding no copy of it.
@@ -88,9 +90,37 @@ def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
     assert ma.output_size_in_bytes >= 2 * B * R * R * S * 4
     assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one v5e"
     H = chunk_rows(R, kw.get("span"), kw.get("cand_tile", DEFAULT_CAND_TILE))
-    limit = _vmem_limit_bytes(R, S, 4, H) or _DEFAULT_SCOPED_VMEM
+    limit = _vmem_limit_bytes(S, 4, H) or _DEFAULT_SCOPED_VMEM
     vmem = _kernel_scoped_vmem(compiled.as_text())
     assert 0 < vmem <= limit, (vmem, limit)
+
+
+@pytest.mark.parametrize(
+    "B, R, S, kw",
+    [
+        pytest.param(1, 256, 4096, {}, id="paper-median-B1-R256-S4096"),
+        pytest.param(1, 256, 4096, {"span": 5}, id="logdp-span5-B1-R256-S4096"),
+        pytest.param(1, 256, 8192, {}, id="paper-tail-B1-R256-S8192"),
+    ],
+)
+def test_wavefront_writes_each_diagonal_in_place(one_chip, B, R, S, kw):
+    """The kernel's output blocks are the tables themselves: the optimised
+    program holds no scatter and no copy of a table, and beside the
+    workspace ``Tc`` (carried by the loop, not returned) its temporaries
+    are under one table."""
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    args = [spec((B, R))] * 4 + [spec((B,))]
+    compiled = ltsp_dp_tables.lower(*args, S=S, interpret=False, **kw).compile()
+    hlo = compiled.as_text()
+    assert not re.search(r"\bscatter\(", hlo)
+    table = f"s32[{B},{R},{R},{S // 128},128]"
+    copies = [ln for ln in hlo.splitlines() if re.search(r"\bcopy(-start)?\(", ln)]
+    results = [ln.split("=", 1)[1].split("copy", 1)[0] for ln in copies]
+    assert not [r for r in results if table in r], copies
+    table_bytes = B * R * R * S * 4
+    assert compiled.memory_analysis().temp_size_in_bytes - table_bytes < table_bytes
 
 
 def _kernel_scoped_vmem(hlo: str) -> int:
@@ -104,7 +134,8 @@ def _kernel_scoped_vmem(hlo: str) -> int:
 
 def test_device_walk_compiles_for_v5e_without_a_copy_of_the_plane(one_chip):
     B, R, S = 1, 256, 4096
-    plane = jax.ShapeDtypeStruct((B, R, R, S), jnp.int32, sharding=one_chip)
+    plane = jax.ShapeDtypeStruct((B, R, R, S // 128, 128), jnp.int32,
+                                 sharding=one_chip)
     x = jax.ShapeDtypeStruct((B, R), jnp.int32, sharding=one_chip)
     ma = traceback_device.lower(plane, plane, x).compile().memory_analysis()
     plane_bytes = B * R * R * S * 4  # 1 GiB
