@@ -105,7 +105,7 @@ def phase_a() -> None:
     R, S = MEDIAN_BUCKET
     print(f"[A] paper median bucket: tape {idx} of PAPER_PROFILE, n_req="
           f"{inst.n_req}, n={inst.n}, U={u} -> B=1, R={R}, S={S} "
-          f"(T, Tc and C {R * R * S * 4 / 2**30:.3g} GiB each)")
+          f"(T {R * R * S * 4 / 2**30:.3g} GiB, C {R * R * S * 2 / 2**30:.3g} GiB)")
     print(f"[A] cuts: {CUT_GRAIN}; one tape (B=1)")
     ctx = ExecutionContext(backend=BACKEND)
     first, t_first = _timed(lambda: solve(inst, "dp", context=ctx))

@@ -19,28 +19,33 @@ diagonal.  The same kernel runs compiled
 
 Table layout
 ------------
-Every table is ``[B, R, R, S/128, 128]`` int32: the skip-count axis is split
-into tile rows of 128 lanes, so cell ``(a, b)`` of instance ``i`` is the
-``(S/128, 128)`` tile ``T[i, a, b]``, whose element ``(p, l)`` is skip count
-``s = 128 p + l``.  ``(i, a, b)`` are all leading, untiled dimensions, so a
-cell's row is one tile-aligned block (on ``[R, R, S]`` it would be one row
-of an ``(8, 128)`` tile, which no block or copy at a dynamic ``b`` can
-name).  Below ``S = 1024`` a tile pads to 8 rows in HBM; those tables are
-at most a few MiB.
+The value table ``T`` is ``[B, R, R, S/128, 128]`` int32 and the argmin
+plane ``C`` the same shape in int16: the skip-count axis is split into tile
+rows of 128 lanes, so cell ``(a, b)`` of instance ``i`` is the ``(S/128,
+128)`` tile ``T[i, a, b]``, whose element ``(p, l)`` is skip count ``s = 128
+p + l``.  ``(i, a, b)`` are all leading, untiled dimensions, so a cell's row
+is one tile-aligned block (on ``[R, R, S]`` it would be one row of an ``(8,
+128)`` tile, which no block or copy at a dynamic ``b`` can name).  Below ``S
+= 1024`` a tile pads to 8 rows in HBM (16 in ``C``); those tables are at
+most a few MiB.  ``C`` holds -1 .. R - 1, so 16 bits hold it up to R =
+2**15; the two tables take ``B R^2 S (4 + 2)`` bytes, 12.9 GB at the bucket
+(512, 8192).
 
 A program computing cell ``(a, b)`` reads row ``a`` of the value table,
-``T[a, :]``, and column ``b``, ``T[:, b]``.  A column is a strided slab,
-which no copy can take whole.  The wavefront therefore carries a second,
-transposed copy ``Tc[b, k] = T[k + 1, b]`` in which column ``b`` is the
-contiguous row ``Tc[b]``.  The one-row shift
-aligns the two terms of candidate ``c``: ``T[a, c - 1, s]`` and
-``T[c, b, s]`` both sit at index ``k = c - 1`` of their row, so one
-8-aligned sublane window of ``k`` serves both.
+``T[a, :]``, and column ``b``, ``T[:, b]``.  Both come from ``T`` itself:
+in this layout row ``c`` of column ``b`` is the tile ``T[i, c, b]``, so ``H``
+rows of a column are one strided copy of ``H`` tile-aligned blocks,
+``T.at[i, pl.ds(c0, H), b]``.  Candidate ``c`` reads ``T[a, c - 1, s]`` and
+``T[c, b, s]``: a chunk of the band at row ``k0`` of ``T[a]`` (``k = c - 1``)
+takes the column's rows from ``c0 = k0 + 1``, clamped to ``R - H``, so that
+the copy stays inside the table.  Where the clamp moves it (``k0 = R - H``),
+row ``h`` of the chunk sits at ``h + 1`` of the column's copy, and the
+chunk's last row (``c = R``) is no candidate.
 
-All three tables stay in HBM (``memory_space=pl.ANY``).  A program copies
-only the rows of its band (below) from ``T[i, a]`` and ``Tc[i, b]`` into two
-VMEM slots of ``[H, S/128, 128]`` each, with ``pltpu.make_async_copy``, a
-slice of a leading dimension: while chunk
+Both tables stay in HBM (``memory_space=pl.ANY``).  A program copies only
+the rows of its band (below) from ``T[i, a]`` and column ``T[i, :, b]`` into
+two VMEM slots of ``[H, S/128, 128]`` each, with ``pltpu.make_async_copy``:
+while chunk
 ``j`` is scanned, chunk ``j + 1`` is in flight, and a program's last chunk
 starts the first chunk of the next live program in grid order, so no
 program waits for its first copy but the grid's first.  The skip's row
@@ -53,21 +58,19 @@ pair per band row.
 
 Writing the diagonal in place
 -----------------------------
-``T``, ``Tc`` and ``C`` enter the launch as inputs aliased
-(``input_output_aliases``) to its three outputs, each in blocks of one
-cell's tile ``(1, 1, 1, S/128, 128)``: program ``(i, a)`` writes its value
-to ``T[i, a, a + d]`` and ``Tc[i, a + d, a - 1]`` and its argmin to ``C[i,
-a, a + d]``; nothing else touches the tables between launches.  At ``a = 0``
-the value is never a column term, and goes to ``Tc[i, d, R - 1]``, a row no
-live candidate reads (``k = c - 1 <= b - 1 <= R - 2``).  A launch reads only
-diagonals ``< d`` and the rows of its own window, so no program reads what
-another writes in it.  A dead program (``a + d >= R``) maps its three
-blocks to those of the diagonal's last live program, ``(i, R - d - 1)``,
-and writes nothing.  The dead programs of an instance follow that program
-in grid order, so the pipeline sees the same block index again, keeps the
-live program's output in place, and writes it back once, unchanged, when
-the index moves on (the accumulator pattern).  So no cell outside diagonal
-``d`` is written: the lower triangle keeps 0 in ``T`` and -1 in ``C``.
+``T`` and ``C`` enter the launch as inputs aliased
+(``input_output_aliases``) to its two outputs, each in blocks of one cell's
+tile ``(1, 1, 1, S/128, 128)``: program ``(i, a)`` writes its value to ``T[i,
+a, a + d]`` and its argmin, cast to 16 bits, to ``C[i, a, a + d]``; nothing
+else touches the tables between launches.  A launch reads only diagonals
+``< d`` and the rows of its own window, so no program reads what another
+writes in it.  A dead program (``a + d >= R``) maps its two blocks to those
+of the diagonal's last live program, ``(i, R - d - 1)``, and writes nothing.
+The dead programs of an instance follow that program in grid order, so the
+pipeline sees the same block index again, keeps the live program's output
+in place, and writes it back once, unchanged, when the index moves on (the
+accumulator pattern).  So no cell outside diagonal ``d`` is written: the
+lower triangle keeps 0 in ``T`` and -1 in ``C``.
 
 Banded candidate scan
 ---------------------
@@ -122,8 +125,9 @@ Layout notes
 * ``cand_tile`` is the chunk height ``H`` (sublane axis) of the exact DP's
   band, at most ``R``: 32 by default, the fastest of 16, 32 and 64 on a
   v5e at the buckets (256, 4096) and (256, 8192).
-* The tables are ``int32``, exact under the guard of :mod:`.ops` (see
-  *Saturating sums* below); :func:`ltsp_dp_tables` refuses any other dtype.
+* The value table is ``int32``, exact under the guard of :mod:`.ops` (see
+  *Saturating sums* below), and :func:`ltsp_dp_tables` refuses inputs of
+  any other dtype; the argmin plane is ``int16`` (:data:`ARGMIN_DTYPE`).
 * The ``skip`` term needs the shifted gather ``row[min(s + x_b, S - 1)]``;
   ``x_b`` is a scalar per program.  With ``q = x_b // 128`` and ``r = x_b %
   128``, element ``(p, l)`` reads lane ``l + r`` of tile row ``p + q``, or
@@ -177,6 +181,7 @@ __all__ = [
     "writeback_bytes",
     "chunk_rows",
     "INT32_CAP",
+    "ARGMIN_DTYPE",
 ]
 
 #: default chunk height of the band (sublane rows per copy and scan step).
@@ -191,6 +196,8 @@ _LANES = 128
 _INT32_MAX = 2**31 - 1
 #: the largest value an int32 table stores: two of them add without wrapping
 INT32_CAP = _INT32_MAX // 2
+#: the argmin plane's type: -1 .. R - 1 in 16 bits
+ARGMIN_DTYPE = jnp.int16
 
 
 def _add_sat(x, y, big):
@@ -240,9 +247,10 @@ def band_copy_bytes(
     B: int, R: int, S: int, itemsize: int, span: int | None, disjoint: bool,
     cand_tile: int,
 ) -> int:
-    """HBM bytes the wavefront's band copies of ``T`` and ``Tc`` move in one
-    launch of :func:`ltsp_dp_tables`: two ``[H, S]`` copies per chunk of
-    every live program, over the ``R - 1`` diagonals and ``B`` instances.
+    """HBM bytes the wavefront's band copies move in one launch of
+    :func:`ltsp_dp_tables`: two ``[H, S]`` copies of ``T`` per chunk of
+    every live program, one of its row and one of its column, over the
+    ``R - 1`` diagonals and ``B`` instances.
     Cached per launch shape: a profiled solve records it after every launch,
     and at R = 256 counting takes about a millisecond of host time."""
     H = chunk_rows(R, span, cand_tile)
@@ -251,12 +259,13 @@ def band_copy_bytes(
     return int(n_chunks.sum()) * B * 2 * H * S * itemsize
 
 
-def writeback_bytes(B: int, R: int, S: int, itemsize: int) -> int:
+def writeback_bytes(B: int, R: int, S: int) -> int:
     """HBM bytes the wavefront writes in place in one launch of
-    :func:`ltsp_dp_tables`: one ``S``-word row of each of ``T``, ``Tc`` and
-    ``C`` per live program, ``B R (R - 1) / 2`` of them over the ``R - 1``
-    diagonals.  Dead programs write nothing, whatever the span or clip."""
-    return 3 * B * (R * (R - 1) // 2) * S * itemsize
+    :func:`ltsp_dp_tables`: one ``S``-word row of ``T`` (int32) and one of
+    ``C`` (int16) per live program, ``B R (R - 1) / 2`` of them over the
+    ``R - 1`` diagonals.  Dead programs write nothing, whatever the span or
+    clip."""
+    return B * (R * (R - 1) // 2) * S * (4 + 2)
 
 
 def wavefront_kernel(
@@ -268,16 +277,14 @@ def wavefront_kernel(
     x_ref,  # [B * R] int32
     nl_ref,  # [B * R] int32
     # tensor inputs, in HBM
-    t_hbm,  # [B, R, R, L, 128] — T
-    tc_hbm,  # [B, R, R, L, 128] — Tc; Tc[i, b, k] = T[i, k + 1, b]
-    c_hbm,  # [B, R, R, L, 128] — C, never read: aliased to c_out
+    t_hbm,  # [B, R, R, L, 128] int32 — T
+    c_hbm,  # [B, R, R, L, 128] int16 — C, never read: aliased to c_out
     # outputs: one (L, 128) block of each table, aliased to the inputs
     t_out,  # T[i, a, a + d]
-    tc_out,  # Tc[i, a + d, a - 1], or Tc[i, d, R - 1] at a = 0
     c_out,  # C[i, a, a + d]: -1 = skip, else the detour start c
     # scratch
     t_buf,  # [2, H, L, 128] — two slots of band rows of T[i, a]
-    c_buf,  # [2, H, L, 128] — the same rows of Tc[i, b]
+    c_buf,  # [2, H, L, 128] — the column's rows T[i, c0 : c0 + H, b]
     skip_buf,  # [2 L, 128] — the skip's row, rolled by its lane shift
     sem,  # DMA semaphores [2 tables, 2 slots]
     slot_ref,  # [1] int32 SMEM — slot of this program's first chunk
@@ -308,14 +315,21 @@ def wavefront_kernel(
         # before, whose candidates the strict fold keeps
         return pl.multiple_of(jnp.minimum(k_first + j * H, R - H), _SUBLANES)
 
+    def column_base(k0):
+        # candidate c = k0 + 1 + h reads column row c; the copy is clamped
+        # into the table (module docstring, *Table layout*)
+        return jnp.minimum(k0 + 1, R - H)
+
     def copies(inst, win, k0, slot):
-        """The two copies of program ``(inst, win)``'s chunk at row ``k0``."""
+        """The two copies of program ``(inst, win)``'s chunk at row ``k0``:
+        rows ``k0 ..`` of its row ``T[inst, win]`` and rows ``column_base(k0)
+        ..`` of its column ``T[inst, :, win + d]``."""
         return (
             pltpu.make_async_copy(
                 t_hbm.at[inst, win, pl.ds(k0, H)], t_buf.at[slot], sem.at[0, slot]
             ),
             pltpu.make_async_copy(
-                tc_hbm.at[inst, win + d, pl.ds(k0, H)], c_buf.at[slot],
+                t_hbm.at[inst, pl.ds(column_base(k0), H), win + d], c_buf.at[slot],
                 sem.at[1, slot],
             ),
         )
@@ -360,9 +374,14 @@ def wavefront_kernel(
             ascending ``c`` into the running ``(min, smallest minimising
             c)``: each row is one ``(L, 128)`` slab, folded elementwise."""
 
+            # row h of the chunk is row h + shift of the column's copy; the
+            # one row past its end (c = R) is no candidate
+            shift = k0 + 1 - column_base(k0)
+
             def row(h, carry):
                 run_min, run_arg = carry
                 c = k0 + 1 + h
+                col = c_buf[slot, jnp.minimum(h + shift, H - 1)]
                 r_cm1 = right_ref[base + c - 1]
                 nl_c = nl_ref[base + jnp.minimum(c, R - 1)]
                 # the linear terms 2 (r_b - r_{c-1}) (s + nl_a) + 2 U (s + nl_c)
@@ -370,7 +389,7 @@ def wavefront_kernel(
                 # element
                 slope = two * (r_b - r_cm1 + u)
                 offset = two * ((r_b - r_cm1) * nl_a + u * nl_c)
-                cand = _add_sat(t_buf[slot, h] + c_buf[slot, h],  # T[a, c-1] + T[c, b]
+                cand = _add_sat(t_buf[slot, h] + col,  # T[a, c-1] + T[c, b]
                                 svec * slope + offset, big)
                 live = (c >= c_min) & (c <= b)
                 cand = jnp.maximum(cand, jnp.where(live, jnp.iinfo(dtype).min, big))
@@ -451,8 +470,8 @@ def wavefront_kernel(
         )
         val = jnp.minimum(jnp.minimum(skip, det), cap)
         t_out[0, 0, 0] = val
-        tc_out[0, 0, 0] = val
-        c_out[0, 0, 0] = jnp.where(skip <= det, jnp.int32(-1), argc)
+        arg = jnp.where(skip <= det, jnp.int32(-1), argc)
+        c_out[0, 0, 0] = arg.astype(c_out.dtype)
 
 
 #: scoped-VMEM limit Mosaic applies on v5e when a kernel asks for none.
@@ -463,23 +482,22 @@ def _vmem_limit_bytes(S: int, itemsize: int, H: int) -> int | None:
     """Scoped-VMEM request for one program, or ``None`` for the compiler's
     default where that suffices.
 
-    A program holds two slots of ``[H, S]`` band rows of each table
-    (``4 H S itemsize``) and its three ``S``-word output blocks, each
+    A program holds two slots of ``[H, S]`` band rows of its row and of its
+    column (``4 H S itemsize``) and its two ``S``-word output blocks, each
     double-buffered; its fold keeps a few ``S``-word rows live at a time.
     The rows are ``(S/128, 128)`` tiles, padded to 8 sublanes below
     ``S = 1024``.
     """
     row = max(S, _SUBLANES * _LANES) * itemsize
-    need = 4 * H * row + 3 * 2 * row + 16 * row
+    need = 4 * H * row + 2 * 2 * row + 16 * row
     if need <= _DEFAULT_SCOPED_VMEM:
         return None
     return -(-need // (1 << 20)) << 20
 
 
 def ltsp_dp_wavefront(
-    T: jax.Array,  # [B, R, R, L, 128]
-    Tc: jax.Array,  # [B, R, R, L, 128] — Tc[i, b, k] = T[i, k + 1, b]
-    C: jax.Array,  # [B, R, R, L, 128] int32
+    T: jax.Array,  # [B, R, R, L, 128] int32
+    C: jax.Array,  # [B, R, R, L, 128] int16
     left: jax.Array,  # [B, R]
     right: jax.Array,  # [B, R]
     x: jax.Array,  # [B, R] int32
@@ -491,13 +509,14 @@ def ltsp_dp_wavefront(
     disjoint: bool,
     interpret: bool,
     cand_tile: int,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One anti-diagonal for every instance, written in place: ``(T, Tc,
-    C)`` with diagonal ``d`` filled.
+) -> tuple[jax.Array, jax.Array]:
+    """One anti-diagonal for every instance, written in place: ``(T, C)``
+    with diagonal ``d`` filled.
 
-    The three tables stay in HBM; each program copies only its band's rows
-    of ``T[i, a]`` and ``Tc[i, a + d]`` and writes its cell's row into each
-    table through an output block aliased to it (module docstring).
+    Both tables stay in HBM; each program copies only its band's rows of
+    its row ``T[i, a]`` and its column ``T[i, :, a + d]`` and writes its
+    cell's row into each table through an output block aliased to it
+    (module docstring).
     """
     B, R = left.shape
     L = T.shape[3]
@@ -519,20 +538,12 @@ def ltsp_dp_wavefront(
         w = window(a, d_ref)
         return i, w, w + d_ref[0], 0, 0
 
-    def column(i, a, d_ref, *_):  # Tc[i, a + d, a - 1]; row R - 1 at a = 0
-        w = window(a, d_ref)
-        return i, w + d_ref[0], jnp.where(w == 0, R - 1, w - 1), 0, 0
-
     row_block = (1, 1, 1, L, _LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,  # d, u, left, right, x, nl
         grid=(B, R),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,  # T, Tc, C
-        out_specs=[
-            pl.BlockSpec(row_block, cell),
-            pl.BlockSpec(row_block, column),
-            pl.BlockSpec(row_block, cell),
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,  # T, C
+        out_specs=[pl.BlockSpec(row_block, cell)] * 2,
         scratch_shapes=[
             pltpu.VMEM((2, H, L, _LANES), T.dtype),
             pltpu.VMEM((2, H, L, _LANES), T.dtype),
@@ -552,9 +563,9 @@ def ltsp_dp_wavefront(
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (T, Tc, C)],
-        # operands 6, 7 and 8 (after the six scalars) are T, Tc and C
-        input_output_aliases={6: 0, 7: 1, 8: 2},
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (T, C)],
+        # operands 6 and 7 (after the six scalars) are T and C
+        input_output_aliases={6: 0, 7: 1},
         interpret=interpret,
         name="ltsp_wavefront",
         **kwargs,
@@ -566,7 +577,6 @@ def ltsp_dp_wavefront(
         x.reshape(-1),
         nl.reshape(-1),
         T,
-        Tc,
         C,
     )
 
@@ -589,21 +599,20 @@ def ltsp_dp_tables(
 ) -> tuple[jax.Array, jax.Array]:
     """Full batched DP tables ``(T, C)`` in a single jitted wavefront.
 
-    Both are ``[B, R, R, S/128, 128]`` int32: cell ``(a, b)`` of instance
-    ``i`` is the ``(S/128, 128)`` tile ``T[i, a, b]``, whose element ``(p,
-    l)`` is skip count ``s = 128 p + l``.  ``T`` is the DP value table and
-    ``C`` the argmin plane (-1 = skip, else detour start ``c``) that the
-    traceback consumes; ``ops.host_table`` views either as ``[B, R, R, S]``
-    on the host.  Cells below the diagonal hold 0 in ``T`` and -1 in ``C``.
-    One ``lax.fori_loop`` over the diagonal index carries the ``(T, Tc, C)``
-    workspace (``Tc`` is the transposed, one-row-shifted copy the column
-    reads use, see the module docstring); each iteration is one Pallas
-    launch over the ``(instance, window-start)`` grid that writes its
-    diagonal into all three in place.  ``interpret`` has no default: every
-    caller says whether it runs the compiled kernel or the interpreter.  The
-    inputs are int32 (``ops.prepare_batch``) and ``S`` a multiple of 128;
-    any other dtype raises ``TypeError`` and any other ``S`` ``ValueError``
-    when the call is traced.
+    Both are ``[B, R, R, S/128, 128]``, ``T`` int32 and ``C`` int16: cell
+    ``(a, b)`` of instance ``i`` is the ``(S/128, 128)`` tile ``T[i, a, b]``,
+    whose element ``(p, l)`` is skip count ``s = 128 p + l``.  ``T`` is the
+    DP value table and ``C`` the argmin plane (-1 = skip, else detour start
+    ``c``) that the traceback consumes; ``ops.host_table`` views either as
+    ``[B, R, R, S]`` on the host.  Cells below the diagonal hold 0 in ``T``
+    and -1 in ``C``.  One ``lax.fori_loop`` over the diagonal index carries
+    ``(T, C)``; each iteration is one Pallas launch over the ``(instance,
+    window-start)`` grid that writes its diagonal into both in place.
+    ``interpret`` has no default: every caller says whether it runs the
+    compiled kernel or the interpreter.  The inputs are int32
+    (``ops.prepare_batch``) and ``S`` a multiple of 128; any other dtype
+    raises ``TypeError`` and any other ``S`` ``ValueError`` when the call is
+    traced.
     """
     dtype = left.dtype
     if dtype != jnp.int32:
@@ -621,9 +630,7 @@ def ltsp_dp_tables(
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
     T = jnp.where(row == col, base, 0)
-    # Tc[i, b, a - 1] = T[i, a, b]; a = 0 never appears as a column term
-    Tc = jnp.where(col == row - 1, base, 0)
-    C = jnp.full(shape, -1, jnp.int32)
+    C = jnp.full(shape, -1, ARGMIN_DTYPE)
     if R == 1:
         return T, C
 
@@ -633,5 +640,4 @@ def ltsp_dp_tables(
             span=span, disjoint=disjoint, interpret=interpret, cand_tile=cand_tile,
         )
 
-    T, _, C = jax.lax.fori_loop(1, R, body, (T, Tc, C))
-    return T, C
+    return jax.lax.fori_loop(1, R, body, (T, C))

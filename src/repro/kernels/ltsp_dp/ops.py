@@ -3,14 +3,15 @@ traceback, and a size-bucketed batch planner.
 
 The device path is a **complete solver**: :func:`ltsp_dp_tables` (one jitted
 wavefront, see :mod:`.ltsp_dp`) returns the value table *and* per-cell argmin
-planes, each ``[B, R, R, S/128, 128]`` on the device, with every diagonal
-written in place by the kernel; :func:`~.walk.traceback_device` replays the
-argmin planes on the device to reconstruct the optimal detour list, exactly
-like the Python DP's traceback, so only the detours and root values cross to
-the host.  :func:`host_table` views a table copied to the host as ``[B, R,
-R, S]`` (no table is reshaped on the device, where that is a relayout copy),
-and :func:`traceback_detours` is the same walk on a host plane, the
-reference the device walk is tested against.
+planes, each ``[B, R, R, S/128, 128]`` on the device (int32 and int16), with
+every diagonal written in place by the kernel;
+:func:`~.walk.traceback_device` replays the argmin planes on the device to
+reconstruct the optimal detour list, exactly like the Python DP's traceback,
+so only the detours and root values cross to the host.  :func:`host_table`
+views a table copied to the host as ``[B, R, R, S]`` (no table is reshaped
+on the device, where that is a relayout copy), and :func:`traceback_detours`
+is the same walk on a host plane, the reference the device walk is tested
+against.
 
 The device DP computes in ``int32`` only.  It is bit-exact when every cell
 a reader takes is below ``2**30 - 1`` and every term the kernel forms at the
@@ -25,7 +26,10 @@ by exactly ``1/g`` and the argmin structure (ties included) is untouched.
 Real cartridge layouts share the tape's block granularity, so byte
 coordinates far beyond int32 rescale into range.  The guard refuses the
 genuinely coprime byte-scale layouts before anything is launched; the exact
-``backend="python"`` solves them at any magnitude.
+``backend="python"`` solves them at any magnitude.  A second guard,
+:func:`_check_tables_fit`, refuses a launch whose tables
+(:func:`table_bytes`) exceed the device's memory, before anything is
+launched, in place of an out-of-memory error from the device.
 
 ``disjoint=True`` routes SIMPLEDP through the same kernel: the candidate band
 is clipped to root-level cells (no detour may start inside another), which
@@ -68,12 +72,14 @@ from __future__ import annotations
 import contextlib
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...core.instance import Instance, virtual_lb
 from ...core.warm import DenseStore, WarmState, WarmStats, align_warm, warm_from_instance
 from .ltsp_dp import (
+    ARGMIN_DTYPE,
     DEFAULT_CAND_TILE,
     INT32_CAP,
     band_copy_bytes,
@@ -86,6 +92,7 @@ __all__ = [
     "prepare_batch",
     "plan_buckets",
     "bucket_shape",
+    "table_bytes",
     "host_table",
     "rescale_instance",
     "traceback_detours",
@@ -95,6 +102,9 @@ __all__ = [
     "ltsp_solve_batch_warm",
 ]
 
+
+#: device memory assumed where the device reports no limit: one TPU v5e's HBM
+DEFAULT_DEVICE_BYTES = 16 * 10**9
 
 #: the context every phase opens when no profile is attached: one shared
 #: object, so an unprofiled solve builds no span
@@ -286,6 +296,34 @@ def _check_int32_safe(instances: list[Instance]) -> None:
             )
 
 
+def table_bytes(B: int, R: int, S: int) -> int:
+    """HBM bytes of one launch's tables: the int32 value table ``T`` and the
+    int16 argmin plane ``C``, ``B R^2 S`` words each."""
+    itemsize = np.dtype(np.int32).itemsize + np.dtype(ARGMIN_DTYPE).itemsize
+    return B * R * R * S * itemsize
+
+
+def device_bytes() -> int:
+    """Memory of the device the tables go to: its ``bytes_limit``, or
+    :data:`DEFAULT_DEVICE_BYTES` where it reports none."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEFAULT_DEVICE_BYTES))
+
+
+def _check_tables_fit(shapes: list[tuple[int, int, int]]) -> None:
+    """Memory guard: raise where the tables of a launch of padded shape
+    ``(B, R, S)`` exceed the device's memory, before anything is launched."""
+    limit = device_bytes()
+    for B, R, S in shapes:
+        need = table_bytes(B, R, S)
+        if need > limit:
+            raise ValueError(
+                f"the DP tables of a launch (B={B}, R={R}, S={S}) take {need} "
+                f"bytes, more than the device's {limit}; solve fewer "
+                f"cartridges per call, or solve it exactly with backend='python'"
+            )
+
+
 def host_table(table) -> np.ndarray:
     """A ``[B, R, R, S/128, 128]`` table of :func:`ltsp_dp_tables` on the
     host, viewed as ``[B, R, R, S]``: element ``(p, l)`` of a cell's tile is
@@ -344,9 +382,9 @@ def _solve_packed(
     originals: list[Instance],
     scaled: list[Instance],
     gs: list[int],
-    R_pad: int | None,
-    S_pad: int | None,
-    B_pad: int | None,
+    R_pad: int,
+    S_pad: int,
+    B_pad: int,
     span: int | None,
     interpret: bool,
     cand_tile: int,
@@ -437,7 +475,8 @@ def _solve_packed(
             operand_bytes=band_copy_bytes(
                 B, R, S, np.dtype(np.int32).itemsize, span, disjoint, cand_tile
             ),
-            writeback_bytes=writeback_bytes(B, R, S, np.dtype(np.int32).itemsize),
+            writeback_bytes=writeback_bytes(B, R, S),
+            table_bytes=table_bytes(B, R, S),
         )
     return out, stores
 
@@ -469,7 +508,9 @@ def ltsp_solve_batch(
     ``interpret`` has no default: ``False`` runs the compiled Mosaic kernel,
     ``True`` the Pallas interpreter.
 
-    An instance that fails the guard raises before anything is launched.
+    An instance that fails the guard, or a launch whose tables exceed the
+    device's memory (:func:`table_bytes`), raises before anything is
+    launched.
 
     ``capture=True`` changes the return to ``(results, stores)`` where
     ``stores[i]`` is a :class:`~repro.core.warm.DenseStore` snapshot of
@@ -490,12 +531,24 @@ def ltsp_solve_batch(
         pairs = [rescale_instance(inst) for inst in instances]
         scaled = [p[0] for p in pairs]
         gs = [p[1] for p in pairs]
+    # the launches: (instance indices, R_pad, S_pad, B_pad)
+    if not bucketed:  # seed behaviour: one launch padded to the batch maxima
+        launches = [(range(len(instances)),
+                     max(s.n_req for s in scaled),
+                     _pad_s(max(s.n for s in scaled) + 1),
+                     len(instances))]
+    elif len(instances) == 1:  # fast path: no planner, one tight launch
+        launches = [([0], *bucket_shape(scaled[0]), 1)]
+    else:
+        launches = [(idxs, R_pad, S_pad, _pow2(len(idxs)))
+                    for (R_pad, S_pad), idxs in plan_buckets(scaled).items()]
     with _span(profile, "ltsp.guard"):
         _check_int32_safe(scaled)
+        _check_tables_fit([(B, R, S) for _, R, S, B in launches])
 
     stores: list[DenseStore | None] = [None] * len(instances)
-
-    def solve(idxs, R_pad, S_pad, B_pad):
+    results: list[tuple[int, list[tuple[int, int]]] | None] = [None] * len(instances)
+    for idxs, R_pad, S_pad, B_pad in launches:
         out, subs = _solve_packed(
             [instances[i] for i in idxs],
             [scaled[i] for i in idxs],
@@ -504,23 +557,9 @@ def ltsp_solve_batch(
             interpret, cand_tile,
             disjoint=disjoint, capture=capture, profile=profile,
         )
-        for i, st in zip(idxs, subs):
-            stores[i] = st
-        return out
-
-    def done(results):
-        return (results, stores) if capture else results
-
-    if not bucketed:  # seed behaviour: one launch padded to the batch maxima
-        return done(solve(range(len(instances)), None, None, None))
-    if len(instances) == 1:  # fast path: no planner, one tight launch
-        R_pad, S_pad = bucket_shape(scaled[0])
-        return done(solve([0], R_pad, S_pad, None))
-    results: list[tuple[int, list[tuple[int, int]]] | None] = [None] * len(instances)
-    for (R_pad, S_pad), idxs in plan_buckets(scaled).items():
-        for idx, res in zip(idxs, solve(idxs, R_pad, S_pad, _pow2(len(idxs)))):
-            results[idx] = res
-    return done(results)  # type: ignore[return-value]
+        for i, res, st in zip(idxs, out, subs):
+            results[i], stores[i] = res, st
+    return (results, stores) if capture else results  # type: ignore[return-value]
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,8 @@
 
 :func:`traceback_device` replays the argmin plane ``C`` where the wavefront
 left it, so only the detour list (a few KB) crosses to the host instead of
-the ``B·R²·S`` int32 plane (1 GiB at the paper's median bucket).  It is the
+the ``B·R²·S`` int16 plane (512 MiB at the paper's median bucket, 4 GiB at
+the bucket (512, 8192)).  It is the
 same pre-order walk as the host reference :func:`.ops.traceback_detours`,
 step for step, so it emits the same detours in the same order:
 
@@ -21,9 +22,10 @@ exactly ``R - 1`` steps, with at most ``R - 1`` detours and pushed frames.
 The walk reads ``C``, ``x`` and the root of ``T`` in place (single-cell
 dynamic slices, no copy of either plane), in the wavefront's lane-tiled
 layout: skip count ``s`` of cell ``(a, b)`` is ``C[i, a, b, s // 128, s %
-128]``.  It is keyed by the arrays' shapes alone: the LOGDP span, the
-SIMPLEDP band clip and the policy leave their mark in ``C`` only.
-All-phantom padding rows walk as ``R - 1`` skips.
+128]``, a 16-bit word widened to int32 as it is read.  It is keyed by the
+arrays' shapes alone: the LOGDP span, the SIMPLEDP band clip and the policy
+leave their mark in ``C`` only.  All-phantom padding rows walk as ``R - 1``
+skips.
 """
 
 from __future__ import annotations
@@ -40,7 +42,8 @@ def traceback_device(T: jax.Array, C: jax.Array, x: jax.Array):
     """Walk each instance's argmin plane on the device.
 
     ``T`` and ``C`` are the ``[B, R, R, S/128, 128]`` tables of
-    :func:`.ltsp_dp.ltsp_dp_tables` (``T`` in any dtype), ``x`` the
+    :func:`.ltsp_dp.ltsp_dp_tables` (``T`` in any dtype, ``C`` in any
+    integer dtype), ``x`` the
     ``[B, R]`` int32 multiplicities.  Returns ``(detours, n_detours, root,
     steps)``: ``detours[i, :n_detours[i]]`` are instance ``i``'s ``(c, b)``
     pairs in emission order (int32 ``[B, R, 2]``, ``-1`` past the end),
@@ -64,7 +67,7 @@ def traceback_device(T: jax.Array, C: jax.Array, x: jax.Array):
             sp = sp - pop.astype(i32)
             c = lax.dynamic_slice(
                 C, (i, a, b, s // lanes, s % lanes), (1, 1, 1, 1, 1)
-            )[0, 0, 0, 0, 0]
+            )[0, 0, 0, 0, 0].astype(i32)
             skip = c == -1
             # slot nd is still -1 here, so a skip writes it back unchanged
             dets = dets.at[nd].set(jnp.where(skip, -1, jnp.stack([c, b])))

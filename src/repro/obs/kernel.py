@@ -25,8 +25,12 @@ attached through ``ExecutionContext.obs`` records one
   its two table operands move over the launch's diagonals
   (``ltsp_dp.band_copy_bytes``), counted from the launch's shape;
 * ``writeback_bytes`` — the exact HBM bytes the wavefront writes in place
-  over the launch's diagonals: one ``S``-word row of each of its three
-  tables per live program (``ltsp_dp.writeback_bytes``, in closed form).
+  over the launch's diagonals: one ``S``-word row of its int32 value table
+  and one of its int16 argmin plane per live program
+  (``ltsp_dp.writeback_bytes``, in closed form);
+* ``table_bytes`` — the HBM bytes the launch's two tables take on the
+  device, ``B R^2 S (4 + 2)`` (``ops.table_bytes``): what the memory guard
+  holds to the device's memory.
 
 Time comes from :meth:`KernelProfile.span`: the device path opens one
 ``jax.profiler.TraceAnnotation`` per phase (``ltsp.rescale`` …
@@ -60,6 +64,7 @@ class LaunchRecord:
     walk_steps: int = 0
     operand_bytes: int = 0
     writeback_bytes: int = 0
+    table_bytes: int = 0
 
     @property
     def waste(self) -> tuple[int, int]:
@@ -95,6 +100,7 @@ class KernelProfile:
         walk_steps: int = 0,
         operand_bytes: int = 0,
         writeback_bytes: int = 0,
+        table_bytes: int = 0,
     ) -> None:
         cold = signature not in self._seen
         self._seen.add(signature)
@@ -113,6 +119,7 @@ class KernelProfile:
                 walk_steps=walk_steps,
                 operand_bytes=operand_bytes,
                 writeback_bytes=writeback_bytes,
+                table_bytes=table_bytes,
             )
         )
 

@@ -1,7 +1,8 @@
 """The wavefront's band copies and in-place writes, counted on the host:
 ``band_copy_bytes`` and ``LaunchRecord.operand_bytes`` against a plain loop
 over the live programs of every diagonal and the chunks of their bands, and
-``LaunchRecord.writeback_bytes`` against its closed form."""
+``LaunchRecord.writeback_bytes`` and ``LaunchRecord.table_bytes`` against
+their closed forms."""
 
 import numpy as np
 import pytest
@@ -86,9 +87,10 @@ def test_launch_record_counts_the_band_copies(span, disjoint):
 @pytest.mark.parametrize("span, disjoint", [(None, False), (5, False), (None, True)],
                          ids=["dp", "logdp-span5", "simpledp-disjoint"])
 def test_launch_record_counts_the_in_place_writes(span, disjoint, monkeypatch):
-    """Each live program writes one S-word row of T, Tc and C: 3 B R (R - 1)
-    / 2 rows of 4 S bytes a launch, whatever the policy.  Recording the
-    counts of a second launch of one shape counts no band again."""
+    """Each live program writes one S-word row of T (int32) and one of C
+    (int16): B R (R - 1) / 2 rows of 4 S + 2 S bytes a launch, whatever the
+    policy.  Recording the counts of a second launch of one shape counts no
+    band again."""
     insts = _bucket_16_128()
     profile = KernelProfile()
     ltsp_solve_batch(insts, span=span, disjoint=disjoint, interpret=True,
@@ -107,12 +109,24 @@ def test_launch_record_counts_the_in_place_writes(span, disjoint, monkeypatch):
     assert host_counts == []
     first, second = profile.launches
     B, R, S = 2, 16, 128
-    assert first.writeback_bytes == second.writeback_bytes == 3 * B * R * (R - 1) // 2 * S * 4
+    assert first.writeback_bytes == second.writeback_bytes == B * R * (R - 1) // 2 * S * 6
     assert first.operand_bytes == second.operand_bytes
+    assert first.table_bytes == B * R * R * S * 6
     assert profile.summary()["writeback_bytes"] == 2 * first.writeback_bytes
 
 
 def test_writeback_bytes_at_the_median_bucket():
-    """1.60 GB a launch at (1, 256, 4096): three 16 KiB rows for each of the
-    32,640 live programs."""
-    assert ltsp_dp.writeback_bytes(1, 256, 4096, 4) == 3 * 32_640 * 16_384 == 1_604_321_280
+    """0.80 GB a launch at (1, 256, 4096): a 16 KiB row of T and an 8 KiB
+    row of C for each of the 32,640 live programs."""
+    assert ltsp_dp.writeback_bytes(1, 256, 4096) == 32_640 * 24_576 == 802_160_640
+
+
+def test_band_copies_and_writes_at_the_bucket_512_8192():
+    """At (1, 512, 8192), in 32-row chunks: 1,629.9 GB of band copies a
+    launch, a row and a column band of T for every chunk, and 6.43 GB of
+    in-place writes, half of what three int32 tables wrote."""
+    assert band_copy_bytes(1, 512, 8192, 4, None, False, 32) == loop_bytes(
+        1, 512, 8192, 4, None, False, 32)
+    assert round(band_copy_bytes(1, 512, 8192, 4, None, False, 32) / 1e8) == 16_299
+    assert ltsp_dp.writeback_bytes(1, 512, 8192) == 130_816 * 8192 * 6
+    assert 2 * ltsp_dp.writeback_bytes(1, 512, 8192) == 3 * 130_816 * 8192 * 4

@@ -11,7 +11,7 @@ import pytest
 
 from repro.core import dp_schedule, make_instance, simpledp_schedule
 from repro.core.dp import dp_schedule_warm
-from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables
+from repro.kernels.ltsp_dp.ltsp_dp import ARGMIN_DTYPE, ltsp_dp_tables
 from repro.kernels.ltsp_dp.ops import (
     bucket_shape,
     host_table,
@@ -139,3 +139,27 @@ def test_the_walk_compiles_once_per_shape():
     outs = [jax.device_get(traceback_device(*t)) for t in tables]
     assert traceback_device._cache_size() - before <= 1
     assert len({o[0].tobytes() for o in outs}) > 1  # the walks differ
+
+
+@pytest.mark.parametrize("skip_odd", [False, True], ids=["detours", "detours-and-skips"])
+def test_the_16_bit_plane_stores_and_decodes_every_argmin_of_r512(skip_odd):
+    """The argmin plane holds -1 .. R - 1 in 16 bits: every value of the
+    bucket R = 512 survives the kernel's store (an int32 cast to
+    ``ARGMIN_DTYPE``) and the walk's decode, which widens what it reads.  A
+    plane whose cells ``(0, b)`` take the detour ``(b, b)`` walks through
+    ``c = 511 .. 1``; with every odd ``b`` skipped it reads -1 too.  The
+    device walk equals the host walk."""
+    values = np.arange(-1, 512, dtype=np.int32)
+    stored = jnp.asarray(values).astype(ARGMIN_DTYPE)
+    assert stored.dtype == jnp.int16
+    np.testing.assert_array_equal(np.asarray(stored.astype(jnp.int32)), values)
+
+    R, S = 512, 128
+    b = np.arange(R)
+    C = np.full((1, R, R, S), -1, np.int16)
+    C[0, 0, b] = np.where(skip_odd & (b % 2 == 1), -1, b)[:, None]
+    want = [(int(c), int(c)) for c in range(R - 1, 0, -1)
+            if not (skip_odd and c % 2 == 1)]
+    tiled = (1, R, R, S // 128, 128)
+    _check(jnp.zeros(tiled, jnp.int32), jnp.asarray(C.reshape(tiled)),
+           jnp.zeros((1, R), jnp.int32), [want])

@@ -98,11 +98,12 @@ def test_the_byte_counters_equal_the_shapes_arithmetic(capture):
     [rec] = profile.launches  # one bucket: R 8, S 128 or 256, B 4
     B, R, S = rec.B_pad, rec.R_pad, rec.S_pad
     assert (B, R) == (4, 8)
-    # int32 throughout: left, right, x, nl as [B, R] and u as [B] to the
-    # device; the device walk's detours [B, R, 2], detour counts, root values
-    # and step counts [B] back, and both planes [B, R, R, S] too when captured
+    # int32: left, right, x, nl as [B, R] and u as [B] to the device; the
+    # device walk's detours [B, R, 2], detour counts, root values and step
+    # counts [B] back, and when captured both planes [B, R, R, S] too, T in
+    # int32 and C in int16
     assert rec.h2d_bytes == 4 * (4 * B * R + B)
-    assert rec.d2h_bytes == 4 * (2 * B * R + 3 * B + capture * 2 * B * R * R * S)
+    assert rec.d2h_bytes == 4 * (2 * B * R + 3 * B) + capture * (4 + 2) * B * R * R * S
     # each walk step consumes one file of 1..R-1, by a skip or a detour's
     # start, so every row (the all-phantom one too) walks R - 1 steps
     assert rec.walk_steps == B * (R - 1)

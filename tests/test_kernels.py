@@ -10,7 +10,7 @@ import pytest
 
 from repro.core import dp_schedule, make_instance
 from repro.kernels.ltsp_dp.ltsp_dp import ltsp_dp_tables, ltsp_dp_wavefront
-from repro.kernels.ltsp_dp.ops import host_table, prepare_batch, traceback_detours
+from repro.kernels.ltsp_dp.ops import bucket_shape, host_table, prepare_batch, traceback_detours
 from repro.kernels.ltsp_dp.ref import ltsp_tables_ref, root_cost
 
 
@@ -254,21 +254,15 @@ def test_skip_shift_across_lane_tiles(mult, S):
     assert (xs >= 128).any() and ((xs % 128) > 0).any()
 
 
-def _tc_of(T):
-    """The transposed copy the wavefront reads columns from:
-    ``Tc[i, b, k] = T[i, k + 1, b]``, row ``R - 1`` left as it is."""
-    Tc = np.zeros_like(T)
-    Tc[:, :, :-1] = np.swapaxes(T, 1, 2)[:, :, 1:]
-    return Tc
-
-
 @pytest.mark.parametrize("d", [1, 6, 15])
 def test_one_diagonal_is_written_in_place_and_nothing_else(d):
     """One launch at diagonal ``d`` over two instances, whose dead programs
     ``a >= R - d`` revisit the last live program's blocks before the second
-    instance starts.  Diagonal ``d`` of ``T``, ``Tc`` and ``C`` equals the
-    reference; every other cell keeps the sentinel or the value it held,
-    the upper triangle past ``d`` and the base diagonal included."""
+    instance starts.  Diagonal ``d`` of ``T`` and of the 16-bit ``C``
+    equals the reference; every other cell keeps the sentinel or the value
+    it held, the upper triangle past ``d`` and the base diagonal included.
+    The columns come from ``T`` itself, so what lies below its diagonal
+    (here a sentinel, not 0) reaches no live candidate."""
     rng = np.random.default_rng(d)
     R, S = 16, 256
     insts = [_small_instance(rng, R) for _ in range(2)]
@@ -276,27 +270,38 @@ def test_one_diagonal_is_written_in_place_and_nothing_else(d):
     refs = [ltsp_tables_ref(*(np.asarray(v[i]) for v in (left, right, x, nl, u)), S)
             for i in range(2)]
     T_ref = np.stack([r[0] for r in refs]).astype(np.int32)
-    C_ref = np.stack([r[1] for r in refs]).astype(np.int32)
+    C_ref = np.stack([r[1] for r in refs]).astype(np.int16)
     a, b = np.meshgrid(np.arange(R), np.arange(R), indexing="ij")
     before = (b - a < d)[None, :, :, None]  # diagonals already computed
     T0 = np.where(before & (b >= a)[None, :, :, None], T_ref, 777_000).astype(np.int32)
-    C0 = np.where(before & (b > a)[None, :, :, None], C_ref, 555).astype(np.int32)
-    Tc0 = _tc_of(T0)
-    Tc0[:, :, -1] = 333
+    C0 = np.where(before & (b > a)[None, :, :, None], C_ref, 555).astype(np.int16)
     tiled = (2, R, R, S // 128, 128)
-    T1, Tc1, C1 = (
-        host_table(t) for t in ltsp_dp_wavefront(
-            *(jnp.asarray(t.reshape(tiled)) for t in (T0, Tc0, C0)),
-            left, right, x, nl, u, d,
-            span=None, disjoint=False, interpret=True, cand_tile=8,
-        )
+    T1, C1 = ltsp_dp_wavefront(
+        *(jnp.asarray(t.reshape(tiled)) for t in (T0, C0)),
+        left, right, x, nl, u, d,
+        span=None, disjoint=False, interpret=True, cand_tile=8,
     )
+    assert C1.dtype == jnp.int16
+    T1, C1 = host_table(T1), host_table(C1)
     on = (b - a == d)[None, :, :, None]
     np.testing.assert_array_equal(T1, np.where(on, T_ref, T0))
     np.testing.assert_array_equal(C1, np.where(on, C_ref, C0))
-    Tc_want = Tc0.copy()
-    for i in range(2):
-        for a0 in range(R - d):
-            k = a0 - 1 if a0 > 0 else R - 1  # a = 0 writes row R - 1
-            Tc_want[i, a0 + d, k] = T_ref[i, a0, a0 + d]
-    np.testing.assert_array_equal(Tc1, Tc_want)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_solve_batch_on_the_interpreter_equals_the_exact_dp(seed):
+    """The whole device path on the interpreter, ``solve_batch(...,
+    "pallas-interpret")``: rescale, both guards, one bucketed launch per
+    shape bucket of one table ``T`` and a 16-bit ``C``, and the device walk.
+    Seeded random tapes over several buckets; cost and detours equal
+    ``core/dp.py``'s."""
+    from conftest import random_instance
+
+    from repro.core import ExecutionContext, solve_batch
+
+    rng = np.random.default_rng(20261022 + seed)
+    insts = [random_instance(rng, 2, 40) for _ in range(6)]
+    assert len({bucket_shape(i) for i in insts}) > 1
+    got = solve_batch(insts, "dp", context=ExecutionContext(backend="pallas-interpret"))
+    assert [(r.cost, list(r.detours)) for r in got] == [
+        (c, list(d)) for c, d in map(dp_schedule, insts)]

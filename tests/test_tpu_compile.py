@@ -7,12 +7,14 @@ VMEM overflow).  The cases are the buckets ``chip_smoke.py`` runs: the paper
 median bucket, the small-tape buckets with B > 1, the LOGDP span and SIMPLEDP
 disjoint variants, and the band in 16-row chunks; the paper's tail bucket
 (256, 8192), which the chip benchmark runs, exact and under a LOGDP span;
-and R = 128, whose bands are chunked like the large buckets'.  At the
+R = 128, whose bands are chunked like the large buckets'; and the bucket
+(512, 8192), whose two tables take 12 GiB of the chip's 16 GB.  At the
 cells' buckets the kernel writes each diagonal into the tables in place, so
 the optimised program holds no scatter and no copy of a table.  Each program
 must fit the chip's 16 GB of HBM, and the kernel's scoped VMEM what the
 wavefront asks for (``_vmem_limit_bytes``).  The device traceback that walks
-the argmin plane compiles at the median bucket too, holding no copy of it.
+the 16-bit argmin plane compiles at the median bucket and at (512, 8192)
+too, holding no copy of it.
 
 The topology is described inside a module fixture, never at import time, so
 every test worker collects the same tests and only the worker that runs this
@@ -28,6 +30,7 @@ import pytest
 
 from repro.kernels.ltsp_dp.ltsp_dp import (
     _DEFAULT_SCOPED_VMEM,
+    ARGMIN_DTYPE,
     DEFAULT_CAND_TILE,
     _vmem_limit_bytes,
     chunk_rows,
@@ -70,6 +73,7 @@ def one_chip():
         pytest.param(1, 256, 8192, {}, id="paper-tail-B1-R256-S8192"),
         pytest.param(1, 256, 8192, {"span": 5}, id="logdp-span5-B1-R256-S8192"),
         pytest.param(1, 128, 4096, {}, id="banded-B1-R128-S4096"),
+        pytest.param(1, 512, 8192, {}, id="r512-B1-R512-S8192"),
     ],
 )
 def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
@@ -86,8 +90,8 @@ def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
         + ma.temp_size_in_bytes
         - ma.alias_size_in_bytes
     )
-    # the outputs alone are T and C, B*R*R*S int32 each
-    assert ma.output_size_in_bytes >= 2 * B * R * R * S * 4
+    # the outputs alone are T and C, B*R*R*S words of int32 and int16
+    assert ma.output_size_in_bytes >= B * R * R * S * (4 + 2)
     assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB does not fit one v5e"
     H = chunk_rows(R, kw.get("span"), kw.get("cand_tile", DEFAULT_CAND_TILE))
     limit = _vmem_limit_bytes(S, 4, H) or _DEFAULT_SCOPED_VMEM
@@ -101,13 +105,15 @@ def test_wavefront_compiles_for_v5e(one_chip, B, R, S, kw):
         pytest.param(1, 256, 4096, {}, id="paper-median-B1-R256-S4096"),
         pytest.param(1, 256, 4096, {"span": 5}, id="logdp-span5-B1-R256-S4096"),
         pytest.param(1, 256, 8192, {}, id="paper-tail-B1-R256-S8192"),
+        pytest.param(1, 512, 8192, {}, id="r512-B1-R512-S8192"),
     ],
 )
 def test_wavefront_writes_each_diagonal_in_place(one_chip, B, R, S, kw):
-    """The kernel's output blocks are the tables themselves: the optimised
-    program holds no scatter and no copy of a table, and beside the
-    workspace ``Tc`` (carried by the loop, not returned) its temporaries
-    are under one table."""
+    """The kernel's output blocks are the tables themselves, and the columns
+    are read from ``T``: the optimised program holds no scatter and no copy
+    of a table, its temporaries are a few MiB, with no workspace table
+    beside ``T`` and ``C``, and all it holds is within 13 GiB at the bucket
+    (512, 8192)."""
     def spec(shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
@@ -115,12 +121,15 @@ def test_wavefront_writes_each_diagonal_in_place(one_chip, B, R, S, kw):
     compiled = ltsp_dp_tables.lower(*args, S=S, interpret=False, **kw).compile()
     hlo = compiled.as_text()
     assert not re.search(r"\bscatter\(", hlo)
-    table = f"s32[{B},{R},{R},{S // 128},128]"
+    tables = [f"s32[{B},{R},{R},{S // 128},128]", f"s16[{B},{R},{R},{S // 128},128]"]
     copies = [ln for ln in hlo.splitlines() if re.search(r"\bcopy(-start)?\(", ln)]
     results = [ln.split("=", 1)[1].split("copy", 1)[0] for ln in copies]
-    assert not [r for r in results if table in r], copies
-    table_bytes = B * R * R * S * 4
-    assert compiled.memory_analysis().temp_size_in_bytes - table_bytes < table_bytes
+    assert not [r for r in results if any(t in r for t in tables)], copies
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 4 << 20, ma.temp_size_in_bytes
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used <= 13 * 2**30, used
 
 
 def _kernel_scoped_vmem(hlo: str) -> int:
@@ -132,14 +141,20 @@ def _kernel_scoped_vmem(hlo: str) -> int:
         r'"memory_space":"1","offset":"\d+","size":"(\d+)"', configs))
 
 
-def test_device_walk_compiles_for_v5e_without_a_copy_of_the_plane(one_chip):
-    B, R, S = 1, 256, 4096
-    plane = jax.ShapeDtypeStruct((B, R, R, S // 128, 128), jnp.int32,
-                                 sharding=one_chip)
+@pytest.mark.parametrize("B, R, S", [(1, 256, 4096), (1, 512, 8192)],
+                         ids=["paper-median-B1-R256-S4096", "r512-B1-R512-S8192"])
+def test_device_walk_compiles_for_v5e_without_a_copy_of_the_plane(one_chip, B, R, S):
+    shape = (B, R, R, S // 128, 128)
+    T = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    C = jax.ShapeDtypeStruct(shape, ARGMIN_DTYPE, sharding=one_chip)
     x = jax.ShapeDtypeStruct((B, R), jnp.int32, sharding=one_chip)
-    ma = traceback_device.lower(plane, plane, x).compile().memory_analysis()
-    plane_bytes = B * R * R * S * 4  # 1 GiB
-    assert ma.argument_size_in_bytes >= 2 * plane_bytes
+    compiled = traceback_device.lower(T, C, x).compile()
+    ma = compiled.memory_analysis()
+    plane_bytes = B * R * R * S * 2  # C: 512 MiB at the median bucket, 4 GiB at R = 512
+    assert ma.argument_size_in_bytes >= 3 * plane_bytes
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if re.search(r"\bcopy(-start)?\(", ln) and "s16[" in ln]
+    assert not copies, copies
     # detours [B, R, 2] and three [B] vectors: what crosses to the host
     assert ma.output_size_in_bytes < 2**16
     # the walk reads T and C in place: its temporaries are a few stack
